@@ -17,10 +17,6 @@ _lib: ctypes.CDLL | None = None
 _tried = False
 
 
-def _so_path() -> str:
-    return os.path.join(os.path.dirname(__file__), "libseaweed_native.so")
-
-
 def _host_simd_tier() -> int:
     """Best sw_gf_impl tier this host can run: 3 interleaved GFNI+AVX512,
     1 SSSE3, 0 scalar — the heal target for stale/portable builds."""
@@ -44,14 +40,12 @@ def _load() -> ctypes.CDLL | None:
         if _tried:
             return _lib
         _tried = True
-        path = _so_path()
-        if not os.path.exists(path):
-            try:
-                from . import build
+        try:
+            from . import build
 
-                build.build()
-            except Exception:
-                return None
+            path = build.build()  # no-op when this host's object exists
+        except Exception:
+            return None
         try:
             lib = ctypes.CDLL(path)
         except OSError:

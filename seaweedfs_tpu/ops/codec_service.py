@@ -8,9 +8,8 @@ dispatches the batch as a single compute call:
 
 * **device mode**: batches are stacked into ``(V, S, W)`` blocks, padded
   to the mesh geometry, and run through the NamedSharding'd vmap GF
-  matmul from ``parallel.mesh`` (the 16-volume batched encode shape
-  verified in MULTICHIP_r05) — volumes shard over ``dp``, columns over
-  ``sp``.  Up to two batches stay in flight: while batch *k* computes,
+  matmul from ``parallel.mesh`` — volumes shard over ``dp``, columns
+  over ``sp``.  Up to two batches stay in flight: while batch *k* computes,
   batch *k+1* is assembled and dispatched, and *k*'s readback overlaps
   *k+1*'s compute — replacing the encoder's one-async-slice rule with
   true H2D/compute/D2H double buffering.
@@ -62,8 +61,8 @@ from ..stats.metrics import (
     EC_SERVICE_QUEUE_DEPTH,
     EC_SERVICE_STAGE,
 )
-from . import device_probe
 from .codec import DEVICE_CODEC_NAMES as _DEVICE_CODECS
+from .codec import resolve_codec_name
 from .rs_cpu import ReedSolomon
 
 DATA_SHARDS = 10
@@ -124,9 +123,9 @@ class CodecService:
     """Batched GF(2⁸) dispatch behind a bounded queue.
 
     ``mode``: ``host`` (SIMD), ``device`` (mesh-sharded jax), or ``auto``
-    (device iff ``codec_name`` names a device codec AND the fast probe
-    reports a reachable accelerator — an unreachable device degrades to
-    host in probe-timeout seconds, never minutes).
+    (device iff ``codec_name`` names a device codec).  A device-mode
+    service whose jax backend cannot initialise fails its jobs — it never
+    degrades to the host codec.
     """
 
     def __init__(self, mode: str = "auto", codec_name: str = "cpu",
@@ -139,18 +138,8 @@ class CodecService:
                  mesh=None):
         if mode not in ("auto", "host", "device"):
             raise ValueError(f"unknown codec service mode {mode!r}")
-        self.fallback_reason = ""
         if mode == "auto":
-            if codec_name in _DEVICE_CODECS:
-                pr = device_probe.probe()
-                if pr.accelerator:
-                    mode = "device"
-                else:
-                    mode = "host"
-                    self.fallback_reason = (
-                        pr.error or f"no accelerator ({pr.platform or 'none'})")
-            else:
-                mode = "host"
+            mode = "device" if codec_name in _DEVICE_CODECS else "host"
         self.mode = mode
         self.codec_name = codec_name
         self.data_shards = data_shards
@@ -329,6 +318,13 @@ class CodecService:
         batch = [head]
         s = head.rows.shape[1]
         nbytes = head.width * s
+        # device mode stacks the batch into one (V, S, w_pad) block, so the
+        # byte cap must count what that block occupies in HBM — every job
+        # padded to the widest one's bucket — not the sum of real widths
+        # (16 jobs around one wide slice would otherwise pad 64MB of input
+        # into a >1GB block whose XOR-network temps do not fit the chip)
+        device = self.mode == "device"
+        w_max = head.width
         reason = "ready"
         if self.max_batch > 1 and self._q:
             kept: deque[_Job] = deque()
@@ -342,12 +338,16 @@ class CodecService:
                     kept.append(job)
                     reason = "full"
                     break
-                if nbytes + jb > self.max_batch_bytes:
+                w_new = max(w_max, job.width)
+                cost = ((len(batch) + 1) * s * self._pad_width(w_new, 1)
+                        if device else nbytes + jb)
+                if cost > self.max_batch_bytes:
                     kept.append(job)
                     reason = "bytes"
                     break
                 batch.append(job)
                 nbytes += jb
+                w_max = w_new
             kept.extend(self._q)
             self._q = kept
         self._depth_child.set(len(self._q))
@@ -587,16 +587,20 @@ def get_service(codec_name: str = "cpu") -> "CodecService | None":
 
 
 def service_for_codec(codec_name: str) -> "CodecService | None":
-    """Default routing for the bulk encode/rebuild pipelines: device
-    codecs go through the service ONLY when the fast probe confirms a
-    reachable accelerator (otherwise the direct host paths — mmap encode,
-    inline SIMD rebuild — are already optimal for one volume and the
-    per-volume device path keeps its tested direct dispatch).  Callers
-    that KNOW they are concurrent (bench --service, batch flows) pass an
+    """Default routing for the bulk encode/rebuild pipelines: a device
+    codec goes through the (device-mode) service when THIS process's jax
+    holds an accelerator; on a CPU backend the per-volume device path
+    keeps its direct dispatch, and host codecs their mmap/inline-SIMD
+    paths.  The backend is asked in process (ops.device.held_device): one
+    that cannot initialise raises here and the rpc fails.  Callers that
+    KNOW they are concurrent (bench --service, batch flows) pass an
     explicit service instead."""
+    codec_name = resolve_codec_name(codec_name)
     if not enabled() or codec_name not in _DEVICE_CODECS:
         return None
-    if not device_probe.probe().accelerator:
+    from .device import held_device
+
+    if held_device()["platform"] == "cpu":
         return None
     return get_service(codec_name)
 

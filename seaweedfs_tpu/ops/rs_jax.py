@@ -120,20 +120,13 @@ def _impl_fn(rows: tuple[tuple[int, ...], ...], impl: str):
     raise ValueError(f"unknown jax codec impl {impl!r}")
 
 
-def apply_matrix(
-    matrix: np.ndarray, data: jax.Array, impl: str = "xor"
-) -> jax.Array:
-    """GF matmul: (R, S) constant matrix x (S, B) device data -> (R, B)."""
-    return _impl_fn(_rows_of(matrix), impl)(data)
-
-
 class ReedSolomonTPU:
     """RS(data, parity) codec running the GF matmul on the accelerator.
 
     API mirrors ops.rs_cpu.ReedSolomon (encode / reconstruct /
     reconstruct_data over lists of equal-length uint8 numpy arrays), plus
-    device-resident entry points (encode_device) used by the streaming file
-    encoder and the multi-volume mesh pipeline.
+    async dispatch entries (encode_device / apply_rows_device) used by the
+    streaming file encoder and rebuild pipelines.
     """
 
     def __init__(
@@ -147,57 +140,38 @@ class ReedSolomonTPU:
         self.total_shards = data_shards + parity_shards
         self.impl = impl
         self.matrix = gf256.rs_matrix(data_shards, self.total_shards)
-        self._parity_rows = _rows_of(self.matrix[data_shards:])
 
-    # -- device-resident --------------------------------------------------
+    # -- async device entries ---------------------------------------------
 
-    def encode_device(self, data: jax.Array) -> jax.Array:
-        """(data_shards, B) uint8 on device -> (parity_shards, B) parity."""
-        return _impl_fn(self._parity_rows, self.impl)(data)
+    def apply_rows_device(self, rows: np.ndarray, inputs: np.ndarray):
+        """Arbitrary (R, S) GF matrix x (S, B) uint8 HOST bytes, dispatched
+        asynchronously; ``np.asarray`` of the result blocks and yields the
+        (R, B) uint8 rows.  Every caller holds its bytes on the host, so
+        the Pallas impl packs them into uint32 lane tiles there (a free
+        view, rs_pallas.pack_lane_tiles) and the device program is exactly
+        the kernel; the XLA impls are plain jitted functions, which move
+        a numpy argument to the device themselves."""
+        return _impl_fn(_rows_of(rows), self.impl)(inputs)
 
-    def encode_device_u32(self, d32: jax.Array) -> jax.Array | None:
-        """(data_shards, B/4) uint32 -> (parity_shards, B/4) parity words.
+    def encode_device(self, data: np.ndarray):
+        """(data_shards, B) uint8 host bytes -> async (parity_shards, B)."""
+        return self.apply_rows_device(self.matrix[self.data_shards:], data)
 
-        Zero-relayout entry for bulk pipelines: the host views its uint8
-        buffers as little-endian uint32 (free) and the kernel works on packed
-        words directly — no device-side bitcast.  Returns None when the
-        active impl has no packed entry (caller falls back to uint8).
-        """
-        fn = _impl_fn(self._parity_rows, self.impl)
-        as_u32 = getattr(fn, "as_u32", None)
-        return None if as_u32 is None else as_u32(d32)
-
-    def encode_device_u32_3d(self, d3: jax.Array) -> jax.Array | None:
-        """(data_shards, R, 128) uint32 lane tiles -> (parity_shards, R, 128).
-
-        The zero-reshape bulk entry (rs_pallas apply32_3d): the jitted
-        program is exactly the kernel, so XLA cannot choose a transposed
-        parameter layout that pads the shard dim 10->128 in HBM.
-        """
-        fn = _impl_fn(self._parity_rows, self.impl)
-        as_3d = getattr(fn, "as_u32_3d", None)
-        return None if as_3d is None else as_3d(d3)
-
-    def apply_rows_device(self, rows: np.ndarray, inputs: jax.Array) -> jax.Array:
-        """Arbitrary GF matrix application (used for decode/rebuild)."""
-        return apply_matrix(rows, inputs, self.impl)
+    def _apply_blocking(self, rows: np.ndarray, data: np.ndarray) -> np.ndarray:
+        """apply_rows_device + readback, spanned separately so a slow
+        rebuild is attributable to transfer-in + compute vs transfer-out."""
+        with trace.child_span("ec.device_compute", impl=self.impl,
+                              bytes=int(data.nbytes)):
+            # dispatch is async: block here so transfer-in + compute land
+            # in THIS span, not misattributed to the device_get transfer
+            dev = self.apply_rows_device(rows, data).block_until_ready()
+        with trace.child_span("ec.device_get", impl=self.impl):
+            return np.asarray(dev)
 
     def parity_of(self, data: np.ndarray) -> np.ndarray:
-        """(data_shards, B) -> (parity_shards, B), the bulk-pipeline entry.
-
-        The three hops are spanned separately so a slow rebuild is
-        attributable to transfer vs compute (behind a thin tunnel the
-        device put dominates; on a pod host the kernel does)."""
+        """(data_shards, B) -> (parity_shards, B), the bulk-pipeline entry."""
         assert data.shape[0] == self.data_shards
-        with trace.child_span("ec.device_put", impl=self.impl,
-                              bytes=int(data.nbytes)):
-            dev = jnp.asarray(data)
-        with trace.child_span("ec.device_compute", impl=self.impl):
-            # jit dispatch is async: block here so compute time lands in
-            # THIS span, not misattributed to the device_get transfer
-            parity = jax.block_until_ready(self.encode_device(dev))
-        with trace.child_span("ec.device_get", impl=self.impl):
-            return np.asarray(parity)
+        return self._apply_blocking(self.matrix[self.data_shards:], data)
 
     # -- numpy convenience (same shapes as rs_cpu) ------------------------
 
@@ -217,20 +191,12 @@ class ReedSolomonTPU:
             raise ValueError("too few shards to reconstruct")
         sub = present[: self.data_shards]
         stacked = np.stack([shards[i] for i in sub])
-        with trace.child_span("ec.device_put", impl=self.impl,
-                              bytes=int(stacked.nbytes)):
-            inputs = jnp.asarray(stacked)
         out = list(shards)
         missing_data = [i for i in range(self.data_shards) if shards[i] is None]
         if missing_data:
             rows = gf256.decode_plan_for(
                 self.matrix, self.data_shards, present, tuple(missing_data))
-            with trace.child_span("ec.device_compute", impl=self.impl):
-                dev = jax.block_until_ready(
-                    self.apply_rows_device(rows, inputs))
-            with trace.child_span("ec.device_get", impl=self.impl):
-                rec = np.asarray(dev)
-            for i, r in zip(missing_data, rec):
+            for i, r in zip(missing_data, self._apply_blocking(rows, stacked)):
                 out[i] = r
         if not data_only:
             missing_parity = [
@@ -238,12 +204,11 @@ class ReedSolomonTPU:
                 if shards[i] is None
             ]
             if missing_parity:
-                data = jnp.asarray(
-                    np.stack([np.asarray(out[i]) for i in range(self.data_shards)])
-                )
+                data = np.stack(
+                    [np.asarray(out[i]) for i in range(self.data_shards)])
                 rows = self.matrix[np.asarray(missing_parity)]
-                par = np.asarray(self.apply_rows_device(rows, data))
-                for i, p in zip(missing_parity, par):
+                for i, p in zip(missing_parity,
+                                self._apply_blocking(rows, data)):
                     out[i] = p
         return out
 
@@ -254,8 +219,7 @@ class ReedSolomonTPU:
         return self._reconstruct(shards, data_only=True)
 
     def verify(self, shards: list[np.ndarray]) -> bool:
-        data = np.stack(shards[: self.data_shards])
-        parity = np.asarray(self.encode_device(jnp.asarray(data)))
+        parity = self.parity_of(np.stack(shards[: self.data_shards]))
         return all(
             np.array_equal(parity[i], shards[self.data_shards + i])
             for i in range(self.parity_shards)

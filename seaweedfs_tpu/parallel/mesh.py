@@ -26,6 +26,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops import gf256
@@ -100,8 +101,10 @@ def batch_encode_sharded(
 
     V shards over ``dp``, B over ``sp``; the stripe axis stays local.
     """
-    fn = _sharded_encoder(mesh, data_shards, parity_shards)
-    return fn(jnp.asarray(volumes))
+    # host arrays go in as they are: the jit's in_shardings then move each
+    # device's slice straight to it (jnp.asarray first would land the
+    # whole block on device 0 and reshard from there)
+    return _sharded_encoder(mesh, data_shards, parity_shards)(volumes)
 
 
 @functools.lru_cache(maxsize=None)
@@ -126,8 +129,7 @@ def batch_apply_sharded(
     ``batch_encode_sharded`` to arbitrary matrices (decode plans,
     survivor->wanted rebuild rows); dispatch is async, so the caller can
     keep a second batch in flight while this one computes."""
-    return _sharded_apply(mesh, _rows_of(np.asarray(matrix)))(
-        jnp.asarray(batch))
+    return _sharded_apply(mesh, _rows_of(np.asarray(matrix)))(batch)
 
 
 # ---------------------------------------------------------------------------
@@ -153,6 +155,33 @@ def _bit_pack(pbits: jax.Array) -> jax.Array:
     return out
 
 
+@functools.lru_cache(maxsize=None)
+def _reconstruct_program(mesh: Mesh):
+    """One jitted psum-decode per mesh.  The bit matrix is an ARGUMENT, so
+    every decode plan of a shape shares the compiled program — a jit
+    wrapper rebuilt per call would recompile on every rebuild slice."""
+
+    def local_fn(a_local: jax.Array, x_local: jax.Array) -> jax.Array:
+        # a_local: (S/dp, 8R, 8), x_local: (S/dp, B/sp)
+        s_loc, r8, _ = a_local.shape
+        bits = _bit_unpack(x_local)  # (8*S/dp, B/sp)
+        a_flat = a_local.transpose(1, 0, 2).reshape(r8, 8 * s_loc)
+        partial = jax.lax.dot_general(
+            a_flat, bits, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.int32,
+        )
+        total = jax.lax.psum(partial, axis_name="dp")  # ICI collective
+        return _bit_pack(total & 1)
+
+    in_specs = (P("dp", None, None), P("dp", "sp"))
+    return jax.jit(
+        shard_map(local_fn, mesh=mesh, in_specs=in_specs,
+                  out_specs=P(None, "sp")),
+        # explicit, so host inputs are split on their way to the devices
+        in_shardings=tuple(NamedSharding(mesh, spec) for spec in in_specs),
+    )
+
+
 def distributed_reconstruct(
     mesh: Mesh,
     matrix: np.ndarray,
@@ -163,37 +192,13 @@ def distributed_reconstruct(
 
     S must be divisible by the dp axis size (10 and 2 in practice).
     """
-    try:
-        from jax import shard_map  # jax >= 0.8
-    except ImportError:  # older jax kept it under experimental
-        from jax.experimental.shard_map import shard_map
-
     r, s = matrix.shape
     dp = mesh.shape["dp"]
     if s % dp:
         raise ValueError(f"shard axis {s} not divisible by dp={dp}")
     a = gf256.bit_matrix(np.asarray(matrix, dtype=np.uint8)).astype(np.int8)
     a = a.reshape(8 * r, s, 8).transpose(1, 0, 2)  # (S, 8R, 8) per-shard slices
-
-    def local_fn(a_local: jax.Array, x_local: jax.Array) -> jax.Array:
-        # a_local: (S/dp, 8R, 8), x_local: (S/dp, B/sp)
-        s_loc = x_local.shape[0]
-        bits = _bit_unpack(x_local)  # (8*S/dp, B/sp)
-        a_flat = a_local.transpose(1, 0, 2).reshape(8 * r, 8 * s_loc)
-        partial = jax.lax.dot_general(
-            a_flat, bits, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32,
-        )
-        total = jax.lax.psum(partial, axis_name="dp")  # ICI collective
-        return _bit_pack(total & 1)
-
-    fn = shard_map(
-        local_fn,
-        mesh=mesh,
-        in_specs=(P("dp", None, None), P("dp", "sp")),
-        out_specs=P(None, "sp"),
-    )
-    return jax.jit(fn)(jnp.asarray(a), jnp.asarray(inputs))
+    return _reconstruct_program(mesh)(a, inputs)
 
 
 # ---------------------------------------------------------------------------

@@ -90,9 +90,10 @@ def generate_ec_files(
     service (ops.codec_service): slices become queued jobs the scheduler
     coalesces with OTHER concurrent volumes' slices into device-resident
     (or slab-SIMD) batches.  Default: the service engages automatically
-    for device codecs when the fast probe confirms a reachable
-    accelerator; host encodes keep the direct mmap path unless a caller
-    that knows it is concurrent passes a service explicitly."""
+    for device codecs when this process holds an accelerator
+    (codec_service.service_for_codec); host encodes keep the direct mmap
+    path unless a caller that knows it is concurrent passes a service
+    explicitly."""
     codec = get_codec(codec_name)
     if service is None:
         service = codec_service.service_for_codec(codec_name)
@@ -319,16 +320,14 @@ def _encode_stream_pipelined(
       * while slice k+1 computes, slice k's data shards are written and its
         parity is read back (the only blocking point) and written.
 
-    Slices are pre-packed as little-endian uint32 on the host (a free
-    ndarray view) so the Pallas SWAR kernel gets its native word layout with
-    no device-side bitcast (rs_pallas.make_apply_pallas .as_u32).
+    The codec's ``encode_device`` takes the slice as host bytes and packs
+    it for its kernel itself (rs_pallas.pack_lane_tiles: a free uint32
+    view, so the device program is exactly the kernel).
     """
     import queue
     import threading
 
     is_device_codec = hasattr(codec, "encode_device")
-    if is_device_codec and service is None:  # host-only codecs need no jax
-        import jax.numpy as jnp
 
     q: queue.Queue = queue.Queue(maxsize=2)
     stop = threading.Event()
@@ -360,42 +359,16 @@ def _encode_stream_pipelined(
     t = threading.Thread(target=reader, name="ec-encode-prefetch", daemon=True)
     t.start()
 
-    # lane-tile geometry for the fully-prepacked path: width must split into
-    # whole (SUBLANES, LANES)-uint32 tiles so the jit sees only the
-    # pallas_call.  Gated: this import pulls in jax, which host-only
-    # encodes must not pay for.
-    lane_tile_bytes = 0
-    if is_device_codec and service is None:
-        try:
-            from ...ops.rs_pallas import LANES, SUBLANES
-            lane_tile_bytes = SUBLANES * LANES * 4
-        except ImportError:
-            pass  # no pallas — 3d path never taken
-
     def dispatch(data: np.ndarray):
-        """-> (device parity future, packed?) — async on the device;
-        synchronous parity for host-only codecs."""
+        """-> parity future — async on the device (or in the codec
+        service); synchronous parity for host-only codecs."""
         if service is not None:
             # the codec service owns device transfer + double buffering;
             # slices become jobs it may coalesce with other volumes'
-            return service.submit_parity(data), False
+            return service.submit_parity(data)
         if not is_device_codec:
-            return codec.parity_of(data), False
-        width = data.shape[1]
-        if (
-            lane_tile_bytes
-            and width % lane_tile_bytes == 0
-            and hasattr(codec, "encode_device_u32_3d")
-        ):
-            d3 = data.view(np.uint32).reshape(DATA_SHARDS, -1, LANES)
-            out3 = codec.encode_device_u32_3d(jnp.asarray(d3))
-            if out3 is not None:
-                return out3, True
-        if width % 4 == 0 and hasattr(codec, "encode_device_u32"):
-            out32 = codec.encode_device_u32(jnp.asarray(data.view(np.uint32)))
-            if out32 is not None:
-                return out32, True
-        return codec.encode_device(jnp.asarray(data)), False
+            return codec.parity_of(data)
+        return codec.encode_device(data)
 
     # writer thread: shard appends overlap the next slice's compute (the
     # write side is 1.4x the read side, so on write-bound disks this is
@@ -431,7 +404,7 @@ def _encode_stream_pipelined(
     wt.start()
 
     def drain(pending) -> None:
-        data, parity_dev, packed = pending
+        data, parity_dev = pending
         if hasattr(parity_dev, "result"):  # codec-service future
             with _STAGE_DECODE.time():  # wait = batch compute completion
                 parity = parity_dev.result()
@@ -440,8 +413,6 @@ def _encode_stream_pipelined(
         else:
             with _STAGE_DECODE.time():  # device readback = compute completion
                 parity = np.ascontiguousarray(np.asarray(parity_dev))
-        if packed:
-            parity = parity.view(np.uint8).reshape(parity.shape[0], -1)
         wq.put((data, parity))
         if write_err:
             raise write_err[0]
@@ -464,11 +435,10 @@ def _encode_stream_pipelined(
             if not async_mode:
                 # synchronous codec: compute here, overlap only the writes
                 with _STAGE_DECODE.time():
-                    parity, packed = dispatch(item)
-                drain((item, parity, packed))
+                    parity = dispatch(item)
+                drain((item, parity))
                 continue
-            parity_dev, packed = dispatch(item)
-            pending_q.append((item, parity_dev, packed))
+            pending_q.append((item, dispatch(item)))
             if len(pending_q) > max_pending:
                 drain(pending_q.popleft())
         while pending_q:
@@ -744,10 +714,7 @@ def rebuild_ec_files(base_name: str, codec_name: str = "cpu",
                    for lab in ("local", "rack", "dc")}
     if service is None:
         service = codec_service.service_for_codec(codec_name)
-    is_device_codec = hasattr(codec, "apply_rows_device") and hasattr(
-        codec, "encode_device")
-    if is_device_codec and service is None:
-        import jax.numpy as jnp
+    is_device_codec = hasattr(codec, "apply_rows_device")
 
     # everything that creates on-disk or OS state is populated INSIDE the
     # guarded try below: the finally owns closing handles and removing
@@ -938,7 +905,7 @@ def rebuild_ec_files(base_name: str, codec_name: str = "cpu",
             if service is not None:
                 dev = service.submit_apply(plan_mtx, list(view))
             else:
-                dev = codec.apply_rows_device(plan_mtx, jnp.asarray(view))
+                dev = codec.apply_rows_device(plan_mtx, view)
             pending_q.append((buf, dev, off, width, part))
             if len(pending_q) > max_pending:
                 drain(pending_q.popleft())  # k reads back while k+1 computes

@@ -10,9 +10,8 @@ from __future__ import annotations
 import os
 import threading
 
-from ..ops.codec import effective_codec
+from ..ops.codec import resolve_codec_name
 from ..pb import master_pb2
-from ..util import glog
 from .disk_location import DiskLocation
 from .ec import constants as ecc
 from .ec.encoder import (
@@ -56,6 +55,9 @@ class Store:
         self.public_url = public_url or f"{ip}:{port}"
         self.data_center = data_center
         self.rack = rack
+        # `auto` is decided once, here, from this process's own jax — every
+        # EC volume and rpc of this store then names a concrete codec
+        codec_name = resolve_codec_name(codec_name)
         self.codec_name = codec_name
         disk_types = disk_types or []
         self.locations = [
@@ -466,13 +468,7 @@ class Store:
         # compact offsets into the shards — mutual exclusion both ways
         v._ec_encode_in_progress = True
         try:
-            requested = codec_name or self.codec_name
-            effective, reason = effective_codec(requested)
-            if reason:
-                glog.warning(
-                    "ec.encode vol=%d: codec %s unreachable (%s), using %s",
-                    vid, requested, reason, effective)
-            write_ec_files(base, codec_name=requested)
+            write_ec_files(base, codec_name=codec_name or self.codec_name)
             write_sorted_file_from_idx(base)
             save_volume_info(base + ".vif", v.version,
                              dat_file_size=os.path.getsize(base + ".dat"))
@@ -513,14 +509,8 @@ class Store:
             # the holder map — it must never trust a TTL-cached view
             # that predates the loss (or the repair becomes a no-op)
             partial.invalidate()
-        requested = codec_name or self.codec_name
-        effective, reason = effective_codec(requested)
-        if reason:
-            glog.warning(
-                "ec.rebuild vol=%d: codec %s unreachable (%s), using %s",
-                vid, requested, reason, effective)
         return rebuild_ec_files(
-            base, codec_name=requested,
+            base, codec_name=codec_name or self.codec_name,
             remote_fetch=remote_fetch, shard_size=shard_size,
             partial=partial)
 

@@ -6,7 +6,7 @@ net/http.Transport (keep-alive, per-host idle pools) across every
 internal hop, so a small-file write costs zero TCP handshakes after
 warm-up.  The seed paid a fresh connect per hop via
 urllib.request.urlopen; at ~3k reqs/s the SYN/ACK round trips and slow
-starts dominated the serving plane (see ISSUE 3 / BENCH_r05).
+starts dominated the serving plane (ISSUE 3).
 
 Design:
 

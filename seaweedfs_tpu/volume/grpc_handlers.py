@@ -290,8 +290,7 @@ class VolumeGrpcService:
 
     # -- erasure coding ---------------------------------------------------
 
-    @staticmethod
-    def _log_ec_dispatch(op: str, vid: int, codec: str) -> None:
+    def _log_ec_dispatch(self, op: str, vid: int, codec: str) -> None:
         """One glog line naming the codec and codec-service mode this EC
         rpc will run under — the operator-facing answer to "did my
         -ec.codec=tpu request actually reach a device, and is it going
@@ -299,9 +298,9 @@ class VolumeGrpcService:
         from ..ops import codec_service
         from ..util import glog
 
-        svc = codec_service.service_for_codec(codec) if codec else None
-        glog.info("rpc %s vol=%d codec=%s dispatch=%s", op, vid,
-                  codec or "(server default)",
+        effective = codec or self.store.codec_name
+        svc = codec_service.service_for_codec(effective)
+        glog.info("rpc %s vol=%d codec=%s dispatch=%s", op, vid, effective,
                   svc.mode + "-service" if svc is not None else "direct")
 
     def VolumeEcShardsGenerate(self, request, context):

@@ -111,7 +111,9 @@ class VolumeHttpHandler(BufferedResponseMixin, BaseHTTPRequestHandler):
     def _do_get(self):
         path = urllib.parse.urlparse(self.path)
         if path.path in ("/status", "/healthz"):
-            return self._send_json(200, {"Version": "seaweedfs-tpu", **self.store.status()})
+            return self._send_json(200, {
+                "Version": "seaweedfs-tpu", **self.store.status(),
+                "ec": self.volume_server.ec_status()})
         if serve_debug_http(self, path.path):
             return
         if path.path == "/debug/scrub":

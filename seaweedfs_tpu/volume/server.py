@@ -13,6 +13,12 @@ import urllib.error
 
 import grpc
 
+from ..ops.codec import DEVICE_CODEC_NAMES
+from ..ops.device import (
+    compile_cache_stats,
+    enable_compile_cache,
+    held_device,
+)
 from ..pb import master_pb2
 from ..pb import rpc as rpclib
 from ..security import Guard
@@ -93,6 +99,16 @@ class VolumeServer:
             codec_name=codec_name,
             disk_types=disk_types,
         )
+        # a server with a device codec is the ONE owner of the chip while
+        # it lives: initialise the backend now, so a runtime that cannot
+        # start fails the server here rather than its first EC rpc, and
+        # /status can say which device did the work
+        self.ec_device: dict | None = None
+        if self.store.codec_name in DEVICE_CODEC_NAMES:
+            enable_compile_cache()  # before this process's first compile
+            self.ec_device = held_device()
+            glog.info("ec codec %s holds %s", self.store.codec_name,
+                      self.ec_device)
         if max_volume_count:
             counts: dict[str, int] = {}
             for loc in self.store.locations:
@@ -202,6 +218,20 @@ class VolumeServer:
         # daemon scheduler, and codec_service.shutdown_all() exists for
         # owners that do want an explicit drain.
         self.store.close()
+
+    def ec_status(self) -> dict:
+        """The EC codec this server runs and the device it holds — the
+        `/status` answer to "which implementation does the GF work"."""
+        out: dict = {"codec": self.store.codec_name}
+        if self.ec_device is not None:
+            import jax
+
+            out["device"] = self.ec_device
+            out["compileCache"] = compile_cache_stats()
+            stats = jax.devices()[0].memory_stats() or {}
+            if "peak_bytes_in_use" in stats:
+                out["hbmPeakBytes"] = stats["peak_bytes_in_use"]
+        return out
 
     def update_gauges(self) -> None:
         """Refresh volume/EC gauges from the store (stats/metrics.go

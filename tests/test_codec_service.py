@@ -1,6 +1,7 @@
-"""Codec service + device probe: byte identity (host/device/batched vs
+"""Codec service + device choice: byte identity (host/device/batched vs
 single), fairness under a saturating producer, clean shutdown with jobs
-in flight, and probe-driven fallback when devices are unreachable."""
+in flight, and NO fallback to the host codec when a device codec's
+backend is unusable (it raises)."""
 
 from __future__ import annotations
 
@@ -11,7 +12,8 @@ import time
 import numpy as np
 import pytest
 
-from seaweedfs_tpu.ops import codec_service, device_probe, gf256
+from seaweedfs_tpu.ops import codec as codec_mod
+from seaweedfs_tpu.ops import codec_service, device, gf256
 from seaweedfs_tpu.ops.codec import get_codec
 from seaweedfs_tpu.ops.codec_service import CodecService
 from seaweedfs_tpu.ops.rs_cpu import ReedSolomon
@@ -21,7 +23,7 @@ from seaweedfs_tpu.ops.rs_cpu import ReedSolomon
 def _clean_service_state():
     yield
     codec_service.shutdown_all(timeout=10)
-    device_probe.reset_cache()
+    codec_mod._AUTO_CHOICE.clear()
 
 
 def _rand_block(rng, width):
@@ -32,48 +34,70 @@ def _as2d(result):
     return np.stack([np.asarray(r) for r in result])
 
 
-# -- device probe -----------------------------------------------------------
+# -- device choice: in process, never degraded ------------------------------
 
 
-def test_probe_ok_on_this_host_and_cached(monkeypatch):
-    device_probe.reset_cache()
-    pr = device_probe.probe()
-    assert pr.ok and pr.devices >= 1
-    assert pr.platform == "cpu"  # conftest pins the cpu backend
-    assert not pr.accelerator
+def _no_backend(*_a, **_k):
+    raise RuntimeError("Unable to initialize backend 'tpu': test says no")
 
-    # second call must come from the cache — a subprocess here would fail
+
+def test_held_device_reports_this_process_backend(monkeypatch):
     import subprocess
 
     def boom(*a, **k):
-        raise AssertionError("probe re-ran despite cache")
+        raise AssertionError("the device question must not start a child")
 
     monkeypatch.setattr(subprocess, "run", boom)
-    assert device_probe.probe() is pr
+    monkeypatch.setattr(subprocess, "Popen", boom)
+    dev = device.held_device()
+    assert set(dev) == {"platform", "kind", "count"}
+    assert dev["platform"] == "cpu"  # conftest pins the cpu backend
+    assert dev["count"] == 8
 
 
-def test_probe_hard_deadline_reports_unreachable():
-    device_probe.reset_cache()
-    pr = device_probe.probe(timeout_s=0.001, refresh=True)
-    assert not pr.ok
-    assert "timed out" in pr.error
-    assert pr.seconds < 5.0
+def test_held_device_raises_when_backend_cannot_initialise(monkeypatch):
+    import jax
+
+    monkeypatch.setattr(jax, "devices", _no_backend)
+    with pytest.raises(RuntimeError, match="Unable to initialize"):
+        device.held_device()
 
 
-def test_get_codec_degrades_to_cpu_when_probe_fails():
-    device_probe.reset_cache()
-    device_probe.probe(timeout_s=0.001, refresh=True)  # poison the cache
+def test_device_codec_never_degrades_to_host_codec(monkeypatch):
+    """A device codec whose backend is unusable RAISES — from the codec's
+    first device call, from the service routing the rpcs take, and from
+    the volume server's start — instead of serving from cpu_simd."""
+    import jax
+    import jax.numpy as jnp
+
     codec = get_codec("tpu")
-    assert codec._impl == "cpu"  # InstrumentedCodec label
+    assert codec._impl == "pallas"  # InstrumentedCodec label: not "cpu"
+    monkeypatch.setattr(jax, "devices", _no_backend)
+    monkeypatch.setattr(jnp, "asarray", _no_backend)
+    with pytest.raises(RuntimeError, match="Unable to initialize"):
+        codec.parity_of(np.zeros((10, 512), np.uint8))
+    with pytest.raises(RuntimeError, match="Unable to initialize"):
+        codec_service.service_for_codec("tpu")
+    with pytest.raises(RuntimeError, match="Unable to initialize"):
+        get_codec("auto")
+
+    import tempfile
+
+    from seaweedfs_tpu.volume.server import VolumeServer
+
+    with tempfile.TemporaryDirectory() as td:
+        with pytest.raises(RuntimeError, match="Unable to initialize"):
+            VolumeServer([td], ["127.0.0.1:1"], codec_name="tpu_xor")
 
 
-def test_effective_codec_passthrough_when_probe_ok():
-    from seaweedfs_tpu.ops.codec import effective_codec
-
-    device_probe.reset_cache()
-    assert effective_codec("cpu") == ("cpu", "")
-    name, reason = effective_codec("tpu_xor")
-    assert name == "tpu_xor" and reason == ""  # cpu-jax answers the probe
+def test_auto_codec_decided_in_process(monkeypatch):
+    assert codec_mod.resolve_codec_name("tpu_xor") == "tpu_xor"
+    assert codec_mod.resolve_codec_name("auto") == "cpu"  # cpu backend
+    codec_mod._AUTO_CHOICE.clear()
+    monkeypatch.setattr(
+        device, "held_device",
+        lambda: {"platform": "tpu", "kind": "TPU v5 lite", "count": 1})
+    assert codec_mod.resolve_codec_name("auto") == "tpu"
 
 
 # -- host-mode byte identity ------------------------------------------------
@@ -180,12 +204,13 @@ def test_device_mode_identity_parity_and_apply():
     svc.close()
 
 
-def test_auto_mode_falls_back_to_host_without_accelerator():
-    # cpu-jax answers the probe but is no accelerator -> host mode
-    device_probe.reset_cache()
+def test_auto_mode_with_device_codec_is_device_mode():
+    # a device codec name means the jax mesh program, whatever backend
+    # this process holds — there is no host-mode fallback to name
     svc = CodecService(mode="auto", codec_name="tpu")
-    assert svc.mode == "host"
-    assert svc.fallback_reason  # names why the device path was refused
+    assert svc.mode == "device"
+    assert not hasattr(svc, "fallback_reason")
+    assert CodecService(mode="auto", codec_name="cpu").mode == "host"
     rng = np.random.default_rng(7)
     block = _rand_block(rng, 1024)
     assert np.array_equal(
@@ -319,12 +344,18 @@ def test_get_service_shared_and_recreated_after_shutdown(monkeypatch):
     assert b is not a and not b.closed
 
 
-def test_service_for_codec_requires_accelerator(monkeypatch):
-    # cpu-jax probe: ok but not an accelerator -> bulk pipelines keep
-    # their direct (tested) dispatch paths
+def test_service_for_codec_routes_by_held_device(monkeypatch):
+    # cpu backend: bulk pipelines keep their direct (tested) dispatch
     monkeypatch.delenv("SEAWEEDFS_TPU_EC_SERVICE", raising=False)
-    device_probe.reset_cache()
     assert codec_service.service_for_codec("tpu") is None
+    assert codec_service.service_for_codec("cpu") is None
+    assert codec_service.service_for_codec("auto") is None
+    # an accelerator held by this process: the device-mode service
+    monkeypatch.setattr(
+        device, "held_device",
+        lambda: {"platform": "tpu", "kind": "TPU v5 lite", "count": 1})
+    svc = codec_service.service_for_codec("tpu")
+    assert svc is not None and svc.mode == "device"
     assert codec_service.service_for_codec("cpu") is None
 
 
@@ -430,3 +461,30 @@ def test_degraded_read_via_service(tmp_path, monkeypatch):
             assert needle.data == payloads[i]
     finally:
         ev.close()
+
+
+# -- compile cache placement -------------------------------------------------
+
+
+def test_compile_cache_dir_is_env_or_fixed_checkout_path(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set -> the code sets NO directory;
+    unset -> one fixed, git-ignored path inside the checkout."""
+    import jax
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    seen = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: seen.append((k, v)))
+    monkeypatch.setattr(device, "_cache_dir", None)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+    assert device.enable_compile_cache() == "/somewhere/else"
+    assert seen == []
+    monkeypatch.setattr(device, "_cache_dir", None)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    assert device.enable_compile_cache() == os.path.join(
+        repo, ".jax_compile_cache")
+    assert ("jax_compilation_cache_dir",
+            os.path.join(repo, ".jax_compile_cache")) in seen
+    assert device.enable_compile_cache() == device._cache_dir  # idempotent
+    with open(os.path.join(repo, ".gitignore")) as f:
+        assert ".jax_compile_cache/" in f.read().split()

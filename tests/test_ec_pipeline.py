@@ -108,6 +108,7 @@ def test_batched_slices_byte_identical(synthetic_base):
 
 def test_auto_codec_resolves():
     codec = get_codec("auto")
+    assert codec._impl == "cpu"  # decided in process: this backend is cpu
     data = np.arange(10 * 64, dtype=np.uint8).reshape(10, 64)
     ref = get_codec("cpu").parity_of(data)
     assert np.array_equal(np.asarray(codec.parity_of(data)), np.asarray(ref))

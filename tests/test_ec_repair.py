@@ -379,11 +379,24 @@ def test_degraded_reads_spawn_no_new_threads(encoded_base):
                   large_block_size=LARGE, small_block_size=SMALL)
     ev.remote_fetch = lambda sid, off, ln: originals[sid][off:off + ln]
 
+    from seaweedfs_tpu.storage.ec import volume as ec_volume
+
+    def census():
+        """-> (shared-pool workers, every other thread).  A stdlib pool
+        grows lazily up to max_workers, and how far four warm-up calls
+        get depends on core count — so the pool is held to its configured
+        bound and only threads OUTSIDE it are held to the baseline."""
+        pool = sum(t.name.startswith("ec-fetch")
+                   for t in threading.enumerate())
+        return pool, threading.active_count() - pool
+
     for i in range(4):  # warm the shared pool + caches
         ev._gather_and_decode(0, i * 7, 64)
-    baseline = threading.active_count()
+    _, baseline = census()
     for i in range(40):
         ev._gather_and_decode(0, i * 11, 64)  # distinct intervals: no LRU
-    assert threading.active_count() <= baseline, \
+    pool_threads, others = census()
+    assert pool_threads <= ec_volume._fetch_pool()._max_workers
+    assert others <= baseline, \
         "degraded reads must not spawn threads per call"
     ev.close()

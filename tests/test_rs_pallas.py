@@ -9,7 +9,7 @@ from seaweedfs_tpu.ops.rs_pallas import apply_matrix_pallas, parity_fn
 
 
 def test_pallas_parity_matches_cpu():
-    fn = parity_fn()  # interpret=None -> auto interpret on CPU
+    fn = parity_fn()  # interpret=None -> rs_pallas.INTERPRET (conftest)
     rng = np.random.default_rng(0)
     data = rng.integers(0, 256, (10, 4096), dtype=np.uint8)
     got = np.asarray(fn(jnp.asarray(data)))
@@ -31,16 +31,21 @@ def test_pallas_unaligned_width():
             assert np.array_equal(got[i], shards[10 + i]), (b, i)
 
 
-def test_pallas_u32_entry():
+def test_pallas_lane_tile_entry():
+    """The bare kernel entry over host-packed uint32 lane tiles, incl. a
+    width that pads to whole SUBLANES blocks on the host."""
+    from seaweedfs_tpu.ops.rs_pallas import LANES, SUBLANES, pack_lane_tiles
+
     fn = parity_fn()
     rng = np.random.default_rng(2)
-    data = rng.integers(0, 256, (10, 2048), dtype=np.uint8)
-    got = np.asarray(fn.as_u32(jnp.asarray(data.view(np.uint32))))
-    shards = list(data) + [np.zeros(2048, np.uint8) for _ in range(4)]
-    ReedSolomon().encode(shards)
-    got8 = got.view(np.uint8).reshape(4, -1) if got.dtype != np.uint8 else got
-    for i in range(4):
-        assert np.array_equal(np.ascontiguousarray(got8[i]), shards[10 + i])
+    for b in (2048, SUBLANES * LANES * 4 + 7):
+        data = rng.integers(0, 256, (10, b), dtype=np.uint8)
+        d3 = pack_lane_tiles(data)
+        assert d3.dtype == np.uint32 and d3.shape[2] == LANES
+        assert d3.shape[1] % min(SUBLANES, d3.shape[1]) == 0
+        got = np.asarray(fn.as_u32_3d(jnp.asarray(d3)))
+        got8 = got.view(np.uint8).reshape(4, -1)[:, :b]
+        assert np.array_equal(got8, ReedSolomon().parity_of(data)), b
 
 
 def test_pallas_decode_matrix():

@@ -1,0 +1,167 @@
+"""AOT compiles for a DESCRIBED TPU v5e (no chip attached, nothing runs):
+what the chip's compiler refuses — a kernel Mosaic cannot tile, a program
+whose temps do not fit HBM, a compile that takes a minute — fails here,
+at no chip time.  See /opt/skills/guides/on-chip-measurement section 2.
+
+All in ONE file, topology described inside a module-scoped fixture (only
+one process at a time may load libtpu; under pytest-xdist only the worker
+that is handed this file does), compile cache off around them (an entry
+written for an unattached chip cannot be read back and would warn).
+"""
+
+import time
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from seaweedfs_tpu.ops import gf256
+from seaweedfs_tpu.ops.rs_jax import _rows_of
+
+HBM_BYTES = 16 << 30  # one TPU v5e chip
+MIB = 1 << 20
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, *shapes):
+    t0 = time.perf_counter()
+    compiled = fn.lower(*shapes).compile()
+    return compiled, time.perf_counter() - t0
+
+
+def _decode_rows(lost):
+    """The survivor->lost decode plan _reconstruct builds for `lost`."""
+    present = [i for i in range(14) if i not in lost]
+    return gf256.decode_plan_for(
+        gf256.rs_matrix(10, 14), 10, present, tuple(lost))
+
+
+@pytest.mark.parametrize("what,shard_mib", [
+    ("parity", 1), ("parity", 64), ("decode1", 64), ("decode4", 64)])
+def test_pallas_kernels_compile_for_v5e(one_chip, what, shard_mib):
+    """The entry every Pallas caller now takes (host-packed uint32 lane
+    tiles: parity_of / encode_device / apply_rows_device / _reconstruct):
+    a Mosaic custom call, no HBM temp to speak of, compiled in seconds —
+    the old uint8 entry took 64x the input in temps and 80 s for the 1x10
+    decode matrix."""
+    from seaweedfs_tpu.ops.rs_pallas import LANES, _make_apply_pallas
+
+    rows = {"parity": gf256.rs_parity_matrix(10, 4),
+            "decode1": _decode_rows([3]),
+            "decode4": _decode_rows([0, 2, 5, 9])}[what]
+    fn = _make_apply_pallas(_rows_of(rows), False).as_u32_3d  # compiled
+    d3 = jax.ShapeDtypeStruct(
+        (10, shard_mib * MIB // (LANES * 4), LANES), jnp.uint32,
+        sharding=one_chip)
+    compiled, seconds = _compile(fn, d3)
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2 * mem.argument_size_in_bytes
+    assert mem.argument_size_in_bytes == 10 * shard_mib * MIB
+    assert mem.output_size_in_bytes == len(rows) * shard_mib * MIB
+    assert seconds < 30, f"{what} took {seconds:.0f}s to compile"
+
+
+@pytest.mark.parametrize("what", ["parity", "decode4"])
+def test_service_batch_program_fits_hbm_twice(topo, what):
+    """The codec service's device program (vmapped XOR network) at its
+    LARGEST bucket — one DEFAULT_SLICE job, (1, 10, 16 MiB) — must fit
+    the chip twice over: the scheduler keeps two batches in flight."""
+    from seaweedfs_tpu.ops.codec_service import CodecService
+    from seaweedfs_tpu.parallel.mesh import _sharded_apply
+    from seaweedfs_tpu.storage.ec.encoder import DEFAULT_SLICE
+
+    mesh = Mesh(np.asarray(topo.devices[:1]).reshape(1, 1), ("dp", "sp"))
+    rows = (gf256.rs_parity_matrix(10, 4) if what == "parity"
+            else _decode_rows([0, 2, 5, 9]))
+    w_pad = CodecService._pad_width(DEFAULT_SLICE, 1)
+    assert w_pad == DEFAULT_SLICE  # slices are already a bucket
+    block = jax.ShapeDtypeStruct(
+        (1, 10, w_pad), jnp.uint8,
+        sharding=NamedSharding(mesh, P("dp", None, "sp")))
+    compiled, seconds = _compile(_sharded_apply(mesh, _rows_of(rows)), block)
+    mem = compiled.memory_analysis()
+    live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert 2 * live < HBM_BYTES, f"{live / 2**30:.1f} GiB per batch"
+    assert seconds < 60
+
+
+def test_service_batches_are_capped_by_padded_bytes():
+    """Device mode stacks a batch into one (V, S, w_pad) block: the byte
+    cap counts that block, so a mixed batch cannot out-pad the program
+    checked above."""
+    from seaweedfs_tpu.ops.codec_service import CodecService
+
+    svc = CodecService(mode="device", max_batch=16, max_batch_mb=64)
+    rng = np.random.default_rng(0)
+    datas = [rng.integers(0, 256, (10, w), dtype=np.uint8)
+             for w in [1 << 10] + [5 << 20] + [1 << 10] * 14]
+    with svc._cond:
+        for d in datas:
+            svc._q.append(_job(svc, d))
+        batch, reason = svc._collect_locked()
+    padded = len(batch) * 10 * CodecService._pad_width(
+        max(j.width for j in batch), 1)
+    assert padded <= 64 * MIB and reason == "bytes"
+    svc._q.clear()
+    svc.close()
+
+
+def _job(svc, data):
+    from seaweedfs_tpu.ops.codec_service import _Job
+
+    return _Job("parity", svc._parity_key, svc.parity_matrix, data,
+                data.shape[1], None)
+
+
+def test_distributed_reconstruct_compiles_with_all_reduce(topo):
+    """The 2x2-mesh psum decode at a DEFAULT_SLICE-wide rebuild step: the
+    compiler must put an all-reduce over dp in, and it must fit."""
+    from seaweedfs_tpu.parallel.mesh import _reconstruct_program, make_mesh
+    from seaweedfs_tpu.storage.ec.encoder import DEFAULT_SLICE
+
+    mesh = make_mesh(topo.devices)
+    assert dict(mesh.shape) == {"dp": 2, "sp": 2}
+    a = jax.ShapeDtypeStruct(
+        (10, 32, 8), jnp.int8,
+        sharding=NamedSharding(mesh, P("dp", None, None)))
+    x = jax.ShapeDtypeStruct(
+        (10, DEFAULT_SLICE), jnp.uint8,
+        sharding=NamedSharding(mesh, P("dp", "sp")))
+    compiled, seconds = _compile(_reconstruct_program(mesh), a, x)
+    assert "all-reduce" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert live < HBM_BYTES, f"{live / 2**30:.1f} GiB per device"
+    assert seconds < 60
