@@ -61,6 +61,7 @@ from ..stats.metrics import (
     EC_SERVICE_QUEUE_DEPTH,
     EC_SERVICE_STAGE,
 )
+from ..telemetry import trace
 from .codec import DEVICE_CODEC_NAMES as _DEVICE_CODECS
 from .codec import resolve_codec_name
 from .rs_cpu import ReedSolomon
@@ -68,7 +69,16 @@ from .rs_cpu import ReedSolomon
 DATA_SHARDS = 10
 PARITY_SHARDS = 4
 
+_STAGE_QUEUE_WAIT = EC_SERVICE_STAGE.labels("queue_wait")
 _STAGE_BUILD = EC_SERVICE_STAGE.labels("build")
+_STAGE_ENQUEUE = EC_SERVICE_STAGE.labels("enqueue")
+_STAGE_DEVICE_WAIT = EC_SERVICE_STAGE.labels("device_wait")
+_STAGE_D2H = EC_SERVICE_STAGE.labels("d2h")
+_STAGE_DELIVER = EC_SERVICE_STAGE.labels("deliver")
+# In device mode `compute` and `readback` are the coarser stages of before
+# the split, observed with the extent they always had (`enqueue`, and
+# `device_wait` + `d2h`): the benchmark's svc_devwait_s_per_GB.* reads
+# them, and only a `benchmark` PR may repoint it.
 _STAGE_COMPUTE = EC_SERVICE_STAGE.labels("compute")
 _STAGE_READBACK = EC_SERVICE_STAGE.labels("readback")
 
@@ -161,6 +171,7 @@ class CodecService:
             coalesce_kb if coalesce_kb is not None else _env_int(
                 "SEAWEEDFS_TPU_EC_SERVICE_COALESCE_KB", 16)) << 10
         self._mesh = mesh
+        self._batch_seq = 0  # scheduler-thread-only: the spans' `batch`
         self._q: deque[_Job] = deque()
         self._cond = threading.Condition()
         self._open = True
@@ -356,7 +367,7 @@ class CodecService:
         return batch, reason
 
     def _run(self) -> None:
-        inflight: deque = deque()  # device mode: (jobs, device array)
+        inflight: deque = deque()  # device mode: (jobs, device array, tags)
         try:
             while True:
                 with self._cond:
@@ -376,16 +387,25 @@ class CodecService:
                         self._inflight_child.set(len(inflight))
                     continue
                 self._flush_children[reason].inc()
+                popped = time.perf_counter()
+                for job in batch:
+                    _STAGE_QUEUE_WAIT.observe(popped - job.t_submit)
+                # what every stage span of this batch carries, so one
+                # batch can be followed through a trace by hand
+                self._batch_seq += 1
+                tags = {"batch": self._batch_seq, "jobs": len(batch),
+                        "bytes": sum(j.width for j in batch)
+                        * batch[0].rows.shape[1]}
                 try:
                     if self.mode == "device":
-                        dev = self._dispatch_device(batch)
-                        inflight.append((batch, dev))
+                        dev = self._dispatch_device(batch, tags)
+                        inflight.append((batch, dev, tags))
                         self._inflight_child.set(len(inflight))
                         if len(inflight) >= 2:
                             self._complete_device(*inflight.popleft())
                             self._inflight_child.set(len(inflight))
                     else:
-                        self._compute_host(batch)
+                        self._compute_host(batch, tags)
                 except Exception as e:
                     # the collected batch is in neither queue nor
                     # inflight — fail it here or its waiters hang forever
@@ -397,7 +417,7 @@ class CodecService:
                 self._inflight_child.set(len(inflight))
         except Exception as e:  # scheduler death must not strand waiters
             self._thread_err = e
-            for jobs, _dev in inflight:
+            for jobs, _dev, _tags in inflight:
                 for job in jobs:
                     self._fail(job, e)
             with self._cond:
@@ -437,7 +457,7 @@ class CodecService:
         return [data[i] for i in range(s)] if isinstance(
             data, np.ndarray) else data
 
-    def _compute_host(self, batch: list[_Job]) -> None:
+    def _compute_host(self, batch: list[_Job], tags: dict) -> None:
         from ..native import lib as native
 
         rows = batch[0].rows
@@ -451,7 +471,7 @@ class CodecService:
                 # column-concatenate into the reused input slab -> ONE
                 # kernel call for the whole batch; per-job results are
                 # views of one output slab
-                with _STAGE_BUILD.time():
+                with trace.stage("ec.svc.build", _STAGE_BUILD, **tags):
                     total = sum(j.width for j in batch)
                     slab = self._slab_in
                     if (slab is None or slab.shape[0] != s
@@ -468,7 +488,7 @@ class CodecService:
                             for ri in range(s):
                                 slab[ri, at:at + w] = j.data[ri]
                         at += w
-                with _STAGE_COMPUTE.time():
+                with trace.stage("ec.svc.compute", _STAGE_COMPUTE, **tags):
                     out_slab = np.empty((r, total), dtype=np.uint8)
                     # row pointers: slab rows are strided by capacity, so
                     # pass each row's view; the kernel reads `total` bytes
@@ -481,7 +501,7 @@ class CodecService:
                     self._deliver(j, out_slab[:, at:at + j.width])
                     at += j.width
                 return
-            with _STAGE_COMPUTE.time():
+            with trace.stage("ec.svc.compute", _STAGE_COMPUTE, **tags):
                 for j in batch:
                     w = j.width
                     rows_in = self._rows_of(j.data, s)
@@ -527,13 +547,13 @@ class CodecService:
             w <<= 1
         return -(-w // sp) * sp
 
-    def _dispatch_device(self, batch: list[_Job]):
+    def _dispatch_device(self, batch: list[_Job], tags: dict):
         from ..parallel.mesh import batch_apply_sharded
 
         mesh = self._device_mesh()
         dp, sp = mesh.shape["dp"], mesh.shape["sp"]
         s = batch[0].rows.shape[1]
-        with _STAGE_BUILD.time():
+        with trace.stage("ec.svc.build", _STAGE_BUILD, **tags):
             w_pad = self._pad_width(max(j.width for j in batch), sp)
             v_pad = -(-len(batch) // dp) * dp
             block = np.zeros((v_pad, s, w_pad), dtype=np.uint8)
@@ -543,15 +563,25 @@ class CodecService:
                 else:
                     for ri in range(s):
                         block[vi, ri, :j.width] = j.data[ri]
-        with _STAGE_COMPUTE.time():  # trace/enqueue (async): compile cost
-            return batch_apply_sharded(mesh, batch[0].rows, block)
+        # staging of the numpy block, H2D dispatch (async), a compile on a miss
+        with trace.stage("ec.svc.enqueue", _STAGE_ENQUEUE, **tags) as st:
+            dev = batch_apply_sharded(mesh, batch[0].rows, block)
+        _STAGE_COMPUTE.observe(st.seconds)
+        return dev
 
-    def _complete_device(self, batch: list[_Job], dev) -> None:
+    def _complete_device(self, batch: list[_Job], dev, tags: dict) -> None:
         try:
-            with _STAGE_READBACK.time():  # blocks until compute + D2H done
-                out = np.asarray(dev)
-            for vi, j in enumerate(batch):
-                self._deliver(j, out[vi, :, :j.width])
+            # np.asarray alone would wait just the same: the split only
+            # says how much of it is the device and how much the copy out
+            with trace.stage("ec.svc.device_wait", _STAGE_DEVICE_WAIT,
+                             **tags) as wait:
+                dev.block_until_ready()
+            with trace.stage("ec.svc.d2h", _STAGE_D2H, **tags) as copy:
+                out = np.asarray(dev)  # D2H and its host-side transpose
+            _STAGE_READBACK.observe(wait.seconds + copy.seconds)
+            with trace.stage("ec.svc.deliver", _STAGE_DELIVER, **tags):
+                for vi, j in enumerate(batch):
+                    self._deliver(j, out[vi, :, :j.width])
         except Exception as e:
             for j in batch:
                 self._fail(j, e)

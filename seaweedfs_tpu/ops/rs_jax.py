@@ -160,12 +160,12 @@ class ReedSolomonTPU:
     def _apply_blocking(self, rows: np.ndarray, data: np.ndarray) -> np.ndarray:
         """apply_rows_device + readback, spanned separately so a slow
         rebuild is attributable to transfer-in + compute vs transfer-out."""
-        with trace.child_span("ec.device_compute", impl=self.impl,
-                              bytes=int(data.nbytes)):
+        with trace.stage("ec.device_compute", impl=self.impl,
+                         bytes=int(data.nbytes)):
             # dispatch is async: block here so transfer-in + compute land
             # in THIS span, not misattributed to the device_get transfer
             dev = self.apply_rows_device(rows, data).block_until_ready()
-        with trace.child_span("ec.device_get", impl=self.impl):
+        with trace.stage("ec.device_get", impl=self.impl):
             return np.asarray(dev)
 
     def parity_of(self, data: np.ndarray) -> np.ndarray:

@@ -114,9 +114,16 @@ def _sharded_apply(mesh: Mesh, rows: tuple[tuple[int, ...], ...]):
     batches through the same entry, so both inherit the dp x sp layout
     without a recompile per batch."""
     apply_one = make_apply_xor(rows)
+
+    def gf_apply(batch: jax.Array) -> jax.Array:  # (V, S, B) -> (V, R, B)
+        return jax.vmap(apply_one)(batch)
+
+    # the program's name on the device plane of a trace
+    # (`jit_gf_apply_r4_s10`): stable across a change of kernel, and it
+    # tells the matrix shapes in mixed traffic apart
+    gf_apply.__name__ = f"gf_apply_r{len(rows)}_s{len(rows[0])}"
     sharding = NamedSharding(mesh, P("dp", None, "sp"))
-    return jax.jit(jax.vmap(apply_one), in_shardings=sharding,
-                   out_shardings=sharding)
+    return jax.jit(gf_apply, in_shardings=sharding, out_shardings=sharding)
 
 
 def batch_apply_sharded(

@@ -192,7 +192,7 @@ def _traced_unary(server_type: str, method: str, fn: Callable) -> Callable:
     def handler(request, context):
         md = {k: v for k, v in (context.invocation_metadata() or ())}
         with _trace.remote_context(md.get(_trace.TRACEPARENT)):
-            with record_op(server_type, method):
+            with record_op(server_type, method, enclosing=True):
                 return fn(request, context)
 
     return handler

@@ -682,6 +682,14 @@ EC_PIPELINE_STAGE = REGISTRY.histogram(
     "per-slice wall time in each EC encode/rebuild pipeline stage",
     labels=("stage",),  # prefetch | decode | write
 )
+# work done, counted where it is done: bytes a slice brought in from the
+# .dat / the survivor shards, and bytes appended to shard files.  write /
+# prefetch is the pipeline's write amplification (1.4 for an encode)
+EC_PIPELINE_BYTES = REGISTRY.counter(
+    "seaweedfs_ec_pipeline_bytes_total",
+    "bytes through the EC encode/rebuild pipeline's prefetch and write stages",
+    labels=("stage",),  # prefetch | write
+)
 
 # -- EC codec service (ops/codec_service.py) --------------------------------
 # one bounded queue between every GF caller (encode, rebuild, degraded
@@ -726,7 +734,9 @@ EC_SERVICE_JOB_SECONDS = REGISTRY.histogram(
 EC_SERVICE_STAGE = REGISTRY.histogram(
     "seaweedfs_ec_service_stage_seconds",
     "per-batch wall time in each codec-service stage",
-    labels=("stage",),  # build | compute | readback
+    # queue_wait (per job) | build | enqueue | device_wait | d2h | deliver,
+    # and compute | readback (ops/codec_service.py says which is which)
+    labels=("stage",),
 )
 
 
@@ -1022,6 +1032,20 @@ HTTPD_INFLIGHT = REGISTRY.gauge(
     "seaweedfs_httpd_inflight_requests",
     "requests currently executing on an event-loop worker pool",
     labels=("server",),
+)
+# where a request on the event-loop front end waits, from the moment its
+# head is buffered (loop thread): until a pool worker picks it up, and
+# until its response is flushed.  resident - dispatch_wait - the handler's
+# seaweedfs_request_seconds = parse + flush
+HTTPD_DISPATCH_WAIT = REGISTRY.histogram(
+    "seaweedfs_httpd_dispatch_wait_seconds",
+    "request head buffered on the event loop -> a pool worker starts it",
+    labels=("surface", "method"),
+)
+HTTPD_RESIDENT = REGISTRY.histogram(
+    "seaweedfs_httpd_resident_seconds",
+    "request head buffered on the event loop -> response flushed",
+    labels=("surface", "method"),
 )
 EC_PREADV_BATCHES = REGISTRY.counter(
     "seaweedfs_ec_preadv_batches_total",

@@ -24,12 +24,14 @@ from ...ops import codec_service, gf256
 from ...ops.codec import get_codec
 from ...stats.metrics import (
     EC_PARTIAL_FALLBACK,
+    EC_PIPELINE_BYTES,
     EC_PIPELINE_STAGE,
     EC_REBUILD_BYTES,
     EC_REBUILD_RESULT,
     EC_REBUILD_SECONDS,
     EC_REBUILD_SHARDS,
 )
+from ...telemetry import trace
 from ...util import faultpoint
 from ..needle_map import NeedleMap
 from .constants import (
@@ -49,6 +51,8 @@ DEFAULT_SLICE = 16 * 1024 * 1024
 _STAGE_PREFETCH = EC_PIPELINE_STAGE.labels("prefetch")
 _STAGE_DECODE = EC_PIPELINE_STAGE.labels("decode")
 _STAGE_WRITE = EC_PIPELINE_STAGE.labels("write")
+_BYTES_PREFETCH = EC_PIPELINE_BYTES.labels("prefetch")
+_BYTES_WRITE = EC_PIPELINE_BYTES.labels("write")
 
 
 def write_sorted_file_from_idx(base_name: str, ext: str = ".ecx") -> None:
@@ -328,6 +332,7 @@ def _encode_stream_pipelined(
     import threading
 
     is_device_codec = hasattr(codec, "encode_device")
+    vid = os.path.basename(f.name).rsplit(".", 1)[0]  # the spans' `vid`
 
     q: queue.Queue = queue.Queue(maxsize=2)
     stop = threading.Event()
@@ -346,9 +351,11 @@ def _encode_stream_pipelined(
         try:
             for batch in _slice_tasks(dat_size, large, small, slice_size):
                 total = sum(seg[3] for seg in batch)
-                with _STAGE_PREFETCH.time():
+                with trace.stage("ec.pipeline.prefetch", _STAGE_PREFETCH,
+                                 vid=vid, offset=batch[0][0]):
                     data = np.empty((DATA_SHARDS, total), dtype=np.uint8)
                     fill_stripe_rows(f, batch, data)
+                _BYTES_PREFETCH.inc(data.nbytes)  # zero fill past EOF included
                 if not _put(data):
                     return
         except Exception as e:  # surfaced by the consumer
@@ -387,13 +394,15 @@ def _encode_stream_pipelined(
                 continue  # drain the queue so producers never block
             try:  # EVERYTHING must land in write_err, or drain() deadlocks
                 data, parity = pending
-                with _STAGE_WRITE.time():
+                with trace.stage("ec.pipeline.write", _STAGE_WRITE,
+                                 vid=vid, offset=done):
                     for i in range(DATA_SHARDS):
                         outs[i].write(data[i])  # buffer-protocol, no copy
                     # parity is a (P, W) array or a list of P rows (the
                     # codec-service future resolves to a row list)
                     for pi, prow in enumerate(parity):
                         outs[DATA_SHARDS + pi].write(prow)
+                _BYTES_WRITE.inc(data.shape[1] * (DATA_SHARDS + len(parity)))
                 done += data.shape[1] * DATA_SHARDS
                 if progress is not None:
                     progress(min(done, dat_size))
@@ -723,6 +732,7 @@ def rebuild_ec_files(base_name: str, codec_name: str = "cpu",
     ins: dict[int, object] = {}
     outs: dict[int, object] = {}
     t_start = time.perf_counter()
+    vid = os.path.basename(base_name)  # the spans' `vid`
 
     pool: queue.Queue = queue.Queue()
     q: queue.Queue = queue.Queue(maxsize=2)
@@ -785,7 +795,8 @@ def rebuild_ec_files(base_name: str, codec_name: str = "cpu",
                 buf = _get_buffer()
                 if buf is None:
                     return
-                with _STAGE_PREFETCH.time():
+                with trace.stage("ec.pipeline.prefetch", _STAGE_PREFETCH,
+                                 vid=vid, offset=off):
                     part = _fetch_partial(off, width)
                     if part is not None:
                         # only the LOCAL source rows are read here; the
@@ -804,6 +815,7 @@ def rebuild_ec_files(base_name: str, codec_name: str = "cpu",
                                 label_child[_src_label(sources[j])].inc(nb)
                         label_child["local"].inc(
                             DATA_SHARDS * width - sum(fetched))
+                _BYTES_PREFETCH.inc(view.nbytes)
                 if not _put((buf, view, off, width, part)):
                     return
         except Exception as e:  # surfaced by the consumer
@@ -823,9 +835,11 @@ def rebuild_ec_files(base_name: str, codec_name: str = "cpu",
                 continue  # drain so producers never block
             try:
                 buf, rebuilt, off, width = pending
-                with _STAGE_WRITE.time():
+                with trace.stage("ec.pipeline.write", _STAGE_WRITE,
+                                 vid=vid, offset=off):
                     for row, sid in zip(rebuilt, missing):
                         outs[sid].write(row)
+                _BYTES_WRITE.inc(len(missing) * width)
                 pool.put(buf)  # source slice fully consumed: recycle
                 if progress is not None:
                     progress(off + width)

@@ -17,7 +17,7 @@ timed, filer counted but never timed, volume did both by hand).
 
 from __future__ import annotations
 
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 
 from ..stats.metrics import REQUEST_COUNTER, REQUEST_HISTOGRAM
 from ..util import glog
@@ -39,13 +39,20 @@ TRACE_LIMIT_MAX = 1000
 
 
 @contextmanager
-def record_op(server_type: str, op: str, **attrs):
-    """Instrument one logical operation: counter + histogram + span."""
+def record_op(server_type: str, op: str, enclosing: bool = False, **attrs):
+    """Instrument one logical operation: counter + histogram + span.
+
+    The span is also put on the profiler's clock (`trace.annotate`), so
+    handler time shows in a device trace's idle gaps — unless the caller
+    says it is `enclosing`: a gRPC method holds a whole encode or
+    rebuild, whose stages carry their own spans."""
     REQUEST_COUNTER.labels(server_type, op).inc()
     hist = REQUEST_HISTOGRAM.labels(server_type, op)
+    name = f"{server_type}.{op}"
+    bridge = nullcontext() if enclosing else trace.annotate(name, **attrs)
     span = None
     try:
-        with trace.start_span(f"{server_type}.{op}", **attrs) as span:
+        with bridge, trace.start_span(name, **attrs) as span:
             yield span
     finally:
         if span is not None:

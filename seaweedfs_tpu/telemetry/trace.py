@@ -17,6 +17,8 @@ Usage:
     from seaweedfs_tpu.telemetry import trace
     with trace.start_span("volumeServer.post", path="/3,0123"):
         ...
+    with trace.stage("ec.svc.build", hist_child, batch=7):  # a stage boundary
+        ...
     hdr = trace.traceparent_header()        # inject into outgoing calls
     with trace.remote_context(incoming_hdr):  # adopt a caller's context
         ...
@@ -27,10 +29,11 @@ from __future__ import annotations
 import json
 import os
 import random
+import sys
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass, field
 
 from ..util import glog
@@ -227,6 +230,59 @@ def child_span(name: str, tracer: Tracer = TRACER, **attrs):
         return
     with start_span(name, tracer=tracer, **attrs) as span:
         yield span
+
+
+# -- the profiler's clock ----------------------------------------------------
+
+_NO_ANNOTATION = nullcontext()
+
+
+def annotate(name: str, **attrs):
+    """Context manager that puts `name` on the jax profiler's `/host:`
+    plane, on the same clock as the device plane, whenever a profiler
+    session is open (about 1 us when none is: the session is the switch).
+
+    Only in a process that ALREADY imported jax: master, filer and
+    gateway processes must never import it for a span.  Not for a span
+    that encloses other annotated spans for longer than one batch (a
+    gRPC method around a whole encode), nor for one in which a thread
+    only waits for another thread of the program: a trace reduction
+    names an idle gap by the event covering most of it, and such a span
+    would win every gap while saying nothing."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return _NO_ANNOTATION
+    try:
+        return jax.profiler.TraceAnnotation(name, **attrs)
+    except AttributeError:  # jax is mid-import on another thread
+        return _NO_ANNOTATION
+
+
+class _Stage:
+    __slots__ = ("span", "seconds")
+
+    def __init__(self):
+        self.span = None    # the ring's Span inside a request trace
+        self.seconds = 0.0  # elapsed, once the block has exited
+
+
+@contextmanager
+def stage(name: str, hist_child=None, **attrs):
+    """One stage boundary of the served path, seen three ways at once:
+    its elapsed `perf_counter` time observed into `hist_child` (a
+    histogram child, or None), an `annotate` span on the profiler's
+    clock, and `child_span`'s rule for the ring (recorded for
+    /debug/traces only inside an active request trace).  Yields a handle
+    whose `.seconds` holds the elapsed time after the block."""
+    st = _Stage()
+    t0 = time.perf_counter()
+    try:
+        with annotate(name, **attrs), child_span(name, **attrs) as st.span:
+            yield st
+    finally:
+        st.seconds = time.perf_counter() - t0
+        if hist_child is not None:
+            hist_child.observe(st.seconds)
 
 
 # -- W3C traceparent ---------------------------------------------------------

@@ -336,13 +336,14 @@ class _PrefixedRFile:
 
 
 class _Conn:
-    __slots__ = ("sock", "addr", "buf", "last")
+    __slots__ = ("sock", "addr", "buf", "last", "t_dispatch")
 
     def __init__(self, sock, addr):
         self.sock = sock
         self.addr = addr
         self.buf = bytearray()
         self.last = time.monotonic()
+        self.t_dispatch = 0.0  # perf_counter when the loop handed it over
 
 
 class EventLoopHTTPServer:
@@ -353,7 +354,12 @@ class EventLoopHTTPServer:
     and telemetry path is shared between front ends."""
 
     def __init__(self, server_address, handler_cls, surface: str = "volume"):
-        from ..stats.metrics import HTTPD_INFLIGHT, HTTPD_OPEN_SOCKETS
+        from ..stats.metrics import (
+            HTTPD_DISPATCH_WAIT,
+            HTTPD_INFLIGHT,
+            HTTPD_OPEN_SOCKETS,
+            HTTPD_RESIDENT,
+        )
 
         self.RequestHandlerClass = handler_cls
         self.surface = surface
@@ -390,6 +396,13 @@ class EventLoopHTTPServer:
         self._conns: set[_Conn] = set()
         self._open_gauge = HTTPD_OPEN_SOCKETS.labels(surface)
         self._inflight_gauge = HTTPD_INFLIGHT.labels(surface)
+        # (dispatch_wait, resident) children, resolved once per method the
+        # handler class serves; anything else is counted as "other"
+        self._wait_children = {
+            m: (HTTPD_DISPATCH_WAIT.labels(surface, m),
+                HTTPD_RESIDENT.labels(surface, m))
+            for m in ["other"] + [n[3:] for n in dir(handler_cls)
+                                  if n.startswith("do_")]}
 
     # -- loop thread ------------------------------------------------------
 
@@ -490,14 +503,19 @@ class EventLoopHTTPServer:
             self._sel.unregister(conn.sock)
         except (KeyError, ValueError):
             pass
+        self._submit(conn)
+
+    def _submit(self, conn: _Conn) -> None:
         conn.sock.settimeout(self._request_timeout)
         self._inflight_gauge.inc()
+        conn.t_dispatch = time.perf_counter()
         self._pool.submit(self._handle, conn)
 
     def _handle(self, conn: _Conn) -> None:
         """Worker: run exactly ONE request through the handler class,
         then park the connection back on the loop (keep-alive) or close
         it."""
+        t_start = time.perf_counter()
         keep = False
         rfile = None
         try:
@@ -516,6 +534,11 @@ class EventLoopHTTPServer:
                 handler.wfile.flush()
             except OSError:
                 handler.close_connection = True
+            wait, resident = self._wait_children.get(
+                getattr(handler, "command", None),
+                self._wait_children["other"])
+            wait.observe(t_start - conn.t_dispatch)
+            resident.observe(time.perf_counter() - conn.t_dispatch)
             keep = not handler.close_connection
         except Exception:  # noqa: BLE001 — a broken conn never kills a worker
             keep = False
@@ -540,9 +563,7 @@ class EventLoopHTTPServer:
             if b"\r\n\r\n" in conn.buf:
                 # a pipelined request is already complete: straight back
                 # to a worker, no select round-trip
-                conn.sock.settimeout(self._request_timeout)
-                self._inflight_gauge.inc()
-                self._pool.submit(self._handle, conn)
+                self._submit(conn)
                 continue
             try:
                 self._sel.register(conn.sock, selectors.EVENT_READ, conn)

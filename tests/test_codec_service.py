@@ -300,7 +300,7 @@ def test_clean_shutdown_delivers_inflight_jobs():
 def test_compute_failure_fails_jobs_not_hangs(monkeypatch):
     svc = CodecService(mode="host")
 
-    def boom(batch):
+    def boom(batch, tags):
         raise RuntimeError("injected compute failure")
 
     monkeypatch.setattr(svc, "_compute_host", boom)
