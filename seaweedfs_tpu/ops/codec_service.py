@@ -6,13 +6,16 @@ backend.  A scheduler thread drains it, coalesces jobs that share a
 matrix (same generator rows or same decode plan) into one batch, and
 dispatches the batch as a single compute call:
 
-* **device mode**: batches are stacked into ``(V, S, W)`` blocks, padded
-  to the mesh geometry, and run through the NamedSharding'd vmap GF
-  matmul from ``parallel.mesh`` — volumes shard over ``dp``, columns
-  over ``sp``.  Up to two batches stay in flight: while batch *k* computes,
-  batch *k+1* is assembled and dispatched, and *k*'s readback overlaps
-  *k+1*'s compute — replacing the encoder's one-async-slice rule with
-  true H2D/compute/D2H double buffering.
+* **device mode**: a batch runs as one ``(V, S, W)`` block through the
+  NamedSharding'd vmap GF matmul from ``parallel.mesh`` — volumes shard
+  over ``dp``, columns over ``sp``.  A batch that is one whole block
+  already (one job, a contiguous ``(S, W)`` array whose width is its own
+  bucket, a mesh that needs no padding volume) goes in as a view of the
+  job's array; every other batch is stacked into a fresh block padded
+  to the mesh geometry.  Up to two batches stay in flight: while batch
+  *k* computes, batch *k+1* is assembled and dispatched, and *k*'s
+  readback overlaps *k+1*'s compute — replacing the encoder's
+  one-async-slice rule with true H2D/compute/D2H double buffering.
 
 * **host mode**: the SAME scheduler runs on the C++ SIMD codec, so the
   batching and fairness properties hold on TPU-less hosts.  Small jobs
@@ -56,6 +59,7 @@ from ..stats.metrics import (
     EC_SERVICE_BATCH_JOBS,
     EC_SERVICE_FLUSH,
     EC_SERVICE_INFLIGHT,
+    EC_SERVICE_INPUT_BYTES,
     EC_SERVICE_JOB_SECONDS,
     EC_SERVICE_JOBS,
     EC_SERVICE_QUEUE_DEPTH,
@@ -81,6 +85,8 @@ _STAGE_DELIVER = EC_SERVICE_STAGE.labels("deliver")
 # them, and only a `benchmark` PR may repoint it.
 _STAGE_COMPUTE = EC_SERVICE_STAGE.labels("compute")
 _STAGE_READBACK = EC_SERVICE_STAGE.labels("readback")
+_INPUT_BYTES = {p: EC_SERVICE_INPUT_BYTES.labels(p)
+                for p in ("direct", "staged")}
 
 
 def _env_int(name: str, default: int) -> int:
@@ -136,6 +142,12 @@ class CodecService:
     (device iff ``codec_name`` names a device codec).  A device-mode
     service whose jax backend cannot initialise fails its jobs — it never
     degrades to the host codec.
+
+    A job's input belongs to the service until its future resolves: the
+    caller keeps it alive and does not write to it before then.  The
+    device may be handed the caller's own array, and its H2D transfer
+    runs on the runtime's threads after dispatch has returned, so a
+    buffer recycled early is read half-overwritten.
     """
 
     def __init__(self, mode: str = "auto", codec_name: str = "cpu",
@@ -199,7 +211,8 @@ class CodecService:
     # -- submission -------------------------------------------------------
 
     def submit_parity(self, data, out=None) -> CodecFuture:
-        """(data_shards, W) -> future of the parity rows."""
+        """(data_shards, W) -> future of the parity rows.  ``data`` is
+        the service's until the future resolves (see the class)."""
         return self._submit_many(
             "parity", self.parity_matrix, self._parity_key,
             (data,), (out,))[0]
@@ -214,7 +227,9 @@ class CodecService:
             "parity", self.parity_matrix, self._parity_key, datas, outs)
 
     def submit_apply(self, rows: np.ndarray, inputs, out=None) -> CodecFuture:
-        """Arbitrary (R, S) GF matrix x S input rows -> future of R rows."""
+        """Arbitrary (R, S) GF matrix x S input rows -> future of R rows.
+        ``inputs`` is the service's until the future resolves (see the
+        class)."""
         rows = np.ascontiguousarray(rows, dtype=np.uint8)
         if rows.ndim != 2:
             raise ValueError("rows must be a 2-D GF matrix")
@@ -233,17 +248,17 @@ class CodecService:
 
     @staticmethod
     def _validate(data, s: int):
-        """-> (data, width).  2-D uint8 arrays pass through untouched
-        (the fast path); anything else becomes a list of equal-length
-        1-D uint8 rows."""
+        """-> (data, width).  C-contiguous 2-D uint8 arrays pass through
+        untouched (the fast path); anything else becomes a list of
+        equal-length 1-D uint8 rows — a strided 2-D array (a column
+        slice of a wider buffer) as views of its rows, uncopied."""
         if isinstance(data, np.ndarray) and data.ndim == 2:
             if data.shape[0] != s:
                 raise ValueError(f"want {s} input rows, got {data.shape[0]}")
             if data.dtype != np.uint8:
                 raise ValueError("inputs must be uint8")
-            if not data.flags["C_CONTIGUOUS"]:
-                data = np.ascontiguousarray(data)
-            return data, data.shape[1]
+            if data.flags["C_CONTIGUOUS"]:
+                return data, data.shape[1]
         # ascontiguousarray, not asarray: the host fast path hands raw
         # row pointers to the native kernel, which reads stride-1 — a
         # strided view here would silently decode garbage
@@ -552,20 +567,32 @@ class CodecService:
 
         mesh = self._device_mesh()
         dp, sp = mesh.shape["dp"], mesh.shape["sp"]
-        s = batch[0].rows.shape[1]
-        with trace.stage("ec.svc.build", _STAGE_BUILD, **tags):
-            w_pad = self._pad_width(max(j.width for j in batch), sp)
-            v_pad = -(-len(batch) // dp) * dp
-            block = np.zeros((v_pad, s, w_pad), dtype=np.uint8)
-            for vi, j in enumerate(batch):
-                if isinstance(j.data, np.ndarray):
-                    block[vi, :, :j.width] = j.data
-                else:
-                    for ri in range(s):
-                        block[vi, ri, :j.width] = j.data[ri]
+        head = batch[0]
+        s = head.rows.shape[1]
+        w_pad = self._pad_width(max(j.width for j in batch), sp)
+        v_pad = -(-len(batch) // dp) * dp
+        # one job that fills the block IS the block (an ndarray job is
+        # C-contiguous (S, W) uint8, by _validate): same shape and dtype
+        # as the staged block, so the same compiled program, and none of
+        # the page faults of a fresh block per batch
+        path = ("direct" if len(batch) == v_pad == 1
+                and isinstance(head.data, np.ndarray)
+                and head.width == w_pad else "staged")
+        with trace.stage("ec.svc.build", _STAGE_BUILD, path=path, **tags):
+            if path == "direct":
+                block = head.data[None]
+            else:
+                block = np.zeros((v_pad, s, w_pad), dtype=np.uint8)
+                for vi, j in enumerate(batch):
+                    if isinstance(j.data, np.ndarray):
+                        block[vi, :, :j.width] = j.data
+                    else:
+                        for ri in range(s):
+                            block[vi, ri, :j.width] = j.data[ri]
+            _INPUT_BYTES[path].inc(tags["bytes"])
         # staging of the numpy block, H2D dispatch (async), a compile on a miss
         with trace.stage("ec.svc.enqueue", _STAGE_ENQUEUE, **tags) as st:
-            dev = batch_apply_sharded(mesh, batch[0].rows, block)
+            dev = batch_apply_sharded(mesh, head.rows, block)
         _STAGE_COMPUTE.observe(st.seconds)
         return dev
 

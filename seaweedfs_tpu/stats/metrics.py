@@ -731,6 +731,13 @@ EC_SERVICE_JOB_SECONDS = REGISTRY.histogram(
     "codec-service job wall time, submit to delivered result",
     labels=("kind",),
 )
+EC_SERVICE_INPUT_BYTES = REGISTRY.counter(
+    "seaweedfs_ec_service_input_bytes_total",
+    "device-mode input bytes (unpadded) by how the batch reached the jit",
+    # direct: the job's own array went in as the block | staged: copied
+    # into a fresh padded (V, S, W) block first
+    labels=("path",),
+)
 EC_SERVICE_STAGE = REGISTRY.histogram(
     "seaweedfs_ec_service_stage_seconds",
     "per-batch wall time in each codec-service stage",

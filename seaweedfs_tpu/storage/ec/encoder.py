@@ -917,7 +917,11 @@ def rebuild_ec_files(base_name: str, codec_name: str = "cpu",
                     raise write_err[0]
                 continue
             if service is not None:
-                dev = service.submit_apply(plan_mtx, list(view))
+                # a full slice is the pooled buffer itself, which the
+                # service can hand to the device as it is; `buf` is the
+                # service's until drain() has the result, and only the
+                # writer, after that, recycles it
+                dev = service.submit_apply(plan_mtx, view)
             else:
                 dev = codec.apply_rows_device(plan_mtx, view)
             pending_q.append((buf, dev, off, width, part))

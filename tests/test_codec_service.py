@@ -204,6 +204,78 @@ def test_device_mode_identity_parity_and_apply():
     svc.close()
 
 
+# what reaches the jit, per shape of batch: (mesh devices, dp, job widths,
+# how a job is handed in) -> (path, block shape)
+_BLOCK_CASES = {
+    "one_job_at_bucket_width": (1, 1, (1024,), "2d", "direct", (1, 10, 1024)),
+    "width_off_the_bucket": (1, 1, (1000,), "2d", "staged", (1, 10, 1024)),
+    "list_of_rows": (1, 1, (1024,), "rows", "staged", (1, 10, 1024)),
+    "strided_column_slice": (1, 1, (1024,), "strided", "staged", (1, 10, 1024)),
+    "two_coalesced_jobs": (1, 1, (1024, 1024), "2d", "staged", (2, 10, 1024)),
+    "dp2_padding_volume": (2, 2, (1024,), "2d", "staged", (2, 10, 1024)),
+}
+_HAND_IN = {
+    "2d": lambda d: d,
+    "rows": list,
+    # the rebuild's tail: the first columns of a wider pooled buffer
+    "strided": lambda d: np.concatenate([d, d], axis=1)[:, :d.shape[1]],
+}
+
+
+@pytest.mark.parametrize("case", sorted(_BLOCK_CASES))
+def test_device_block_is_the_job_or_a_staged_copy(case, monkeypatch):
+    """A device batch that is one whole block already reaches
+    batch_apply_sharded as a view of the job's array; every other batch
+    as a fresh padded block.  Same bytes out either way."""
+    import jax
+
+    from seaweedfs_tpu.parallel import mesh as mesh_mod
+    from seaweedfs_tpu.stats.metrics import EC_SERVICE_STAGE
+
+    n_dev, dp, widths, hand_in, want_path, want_shape = _BLOCK_CASES[case]
+    blocks = []
+    real = mesh_mod.batch_apply_sharded
+
+    def capture(mesh, matrix, block):
+        blocks.append(block)
+        return real(mesh, matrix, block)
+
+    monkeypatch.setattr(mesh_mod, "batch_apply_sharded", capture)
+    rs = ReedSolomon()
+    rng = np.random.default_rng(28)
+    datas = [_rand_block(rng, w) for w in widths]
+    build = EC_SERVICE_STAGE.labels("build")
+    counted = codec_service._INPUT_BYTES
+    before = {p: c.value for p, c in counted.items()}
+    build_before = (build.total, build.count)
+    svc = CodecService(
+        mode="device", codec_name="tpu_xor",
+        mesh=mesh_mod.make_mesh(jax.devices()[:n_dev], dp=dp))
+    futs = svc.submit_parity_many([_HAND_IN[hand_in](d) for d in datas])
+    for fut, data in zip(futs, datas):
+        assert np.array_equal(_as2d(fut.result(120)), rs.parity_of(data))
+    svc.close()
+
+    (block,) = blocks  # one batch, whatever it held
+    assert block.shape == want_shape and block.dtype == np.uint8
+    if want_path == "direct":
+        assert np.shares_memory(block, datas[0])
+        assert block.base is datas[0]  # a view: nothing was copied
+    else:
+        assert not any(np.shares_memory(block, d) for d in datas)
+        for vi, d in enumerate(datas):
+            assert np.array_equal(block[vi, :, :d.shape[1]], d)
+            assert not block[vi, :, d.shape[1]:].any()
+        assert not block[len(datas):].any()  # the mesh's padding volume
+    moved = {p: c.value - before[p] for p, c in counted.items()}
+    other = "staged" if want_path == "direct" else "direct"
+    assert moved == {want_path: 10 * sum(widths), other: 0}
+    # the stage is observed on every batch, the direct ones too: a sum of
+    # 0 would read as "no value" in the benchmark's per-GB reader
+    assert build.count - build_before[1] == 1
+    assert build.total - build_before[0] > 0
+
+
 def test_auto_mode_with_device_codec_is_device_mode():
     # a device codec name means the jax mesh program, whatever backend
     # this process holds — there is no host-mode fallback to name

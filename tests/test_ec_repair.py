@@ -92,6 +92,69 @@ def test_rebuild_byte_identity_device_codec(encoded_base, lost):
         assert got == originals[sid], f"shard {sid} differs on device codec"
 
 
+def test_rebuild_via_device_service_direct_slices_and_buffer_ownership(
+        encoded_base, monkeypatch):
+    """Through a device-mode service on a one-device mesh: a full slice
+    is the pooled buffer itself and reaches the device uncopied, the
+    tail is staged, the shards come out byte-identical — and no pooled
+    buffer is written to while a job that reads it is unresolved (the
+    submit_* contract, which the uncopied path depends on)."""
+    import jax
+
+    from seaweedfs_tpu.ops import codec_service
+    from seaweedfs_tpu.parallel.mesh import make_mesh
+    from seaweedfs_tpu.storage.ec import encoder
+
+    lost, slice_size = (0, 1, 2, 3), 1024
+    originals = _shard_bytes(encoded_base)
+    shard_size = len(originals[0])
+    n_full, tail = divmod(shard_size, slice_size)
+    assert n_full >= 4 and tail, "fixture must give full slices and a tail"
+    for sid in lost:
+        os.remove(encoded_base + ecc.to_ext(sid))
+
+    # one job a batch, as the byte cap makes it at the served 160 MiB
+    # slices (two queued toy slices would otherwise coalesce, and stage)
+    svc = codec_service.CodecService(
+        mode="device", codec_name="tpu_xor", max_batch=1,
+        mesh=make_mesh(jax.devices()[:1]))
+    owned = []  # (input rows, future) of every job the rebuild submitted
+    real_submit = svc.submit_apply
+
+    def submit(rows, inputs, out=None):
+        fut = real_submit(rows, inputs, out)
+        owned.append((inputs if isinstance(inputs, list) else [inputs], fut))
+        return fut
+
+    written_early = []
+    real_pread = encoder._pread_into
+
+    def pread(fd, dest, offset):
+        written_early.extend(
+            offset for rows, fut in list(owned) if not fut.done()
+            and any(np.may_share_memory(dest, r) for r in rows))
+        real_pread(fd, dest, offset)
+
+    monkeypatch.setattr(svc, "submit_apply", submit)
+    monkeypatch.setattr(encoder, "_pread_into", pread)
+    counted = codec_service._INPUT_BYTES
+    before = {p: c.value for p, c in counted.items()}
+    try:
+        rebuilt = rebuild_ec_files(encoded_base, codec_name="tpu_xor",
+                                   slice_size=slice_size, service=svc)
+    finally:
+        svc.close()
+    assert sorted(rebuilt) == sorted(lost)
+    for sid in lost:
+        got = open(encoded_base + ecc.to_ext(sid), "rb").read()
+        assert got == originals[sid], f"shard {sid} not byte-identical"
+    assert len(owned) == n_full + 1 and all(f.done() for _, f in owned)
+    assert written_early == []
+    moved = {p: c.value - before[p] for p, c in counted.items()}
+    assert moved == {"direct": n_full * ecc.DATA_SHARDS * slice_size,
+                     "staged": ecc.DATA_SHARDS * tail}
+
+
 def test_rebuild_progress_monotonic(encoded_base):
     for sid in (0, 11):
         os.remove(encoded_base + ecc.to_ext(sid))
