@@ -771,8 +771,8 @@ def child_mesh(seed: int, width: int, volumes: int) -> int:
         mesh_rebuild_ec_files,
     )
     from seaweedfs_tpu.parallel.mesh import (
-        batch_apply_sharded,
         distributed_reconstruct,
+        jobs_apply_sharded,
         make_mesh,
     )
     from seaweedfs_tpu.storage.ec.constants import TOTAL_SHARDS, to_ext
@@ -799,19 +799,22 @@ def child_mesh(seed: int, width: int, volumes: int) -> int:
               f"devices, 1/{share} each: {good}  [{arr.sharding}]")
         return good
 
-    # 1. the service's sharded batch program: `volumes` slices at once
+    # 1. the service's device batch program, on the mesh the service
+    # builds for itself: `volumes` slices at once, each an argument
     block = rng.integers(0, 256, (volumes, 10, width), dtype=np.uint8)
     want = np.stack([rs.parity_of(block[v]) for v in range(volumes)])
+    svc = CodecService(mode="device")
+    print(f"codec service mesh: {svc.mesh_shape()}")
     t = time.perf_counter()
-    out = batch_apply_sharded(mesh, rs.parity_matrix, block)
+    out = jobs_apply_sharded(svc._device_mesh(), rs.parity_matrix,
+                             [block[v] for v in range(volumes)])
     out.block_until_ready()
-    print(f"batch_apply_sharded {block.shape}: {time.perf_counter() - t:.2f}s"
+    print(f"jobs_apply_sharded {block.shape}: {time.perf_counter() - t:.2f}s"
           " incl. compile")
-    ok &= placed(out, "batch_apply_sharded output", n)
+    ok &= placed(out, "jobs_apply_sharded output", n)
     same = np.array_equal(np.asarray(out), want)
-    print(f"batch_apply_sharded vs rs_cpu: byte-identical={same}")
+    print(f"jobs_apply_sharded vs rs_cpu: byte-identical={same}")
     ok &= same
-    svc = CodecService(mode="device", mesh=mesh)
     futs = svc.submit_parity_many([block[v] for v in range(volumes)])
     got = np.stack([np.stack([np.asarray(r) for r in f.result(600)])
                     for f in futs])

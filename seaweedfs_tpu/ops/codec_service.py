@@ -6,16 +6,21 @@ backend.  A scheduler thread drains it, coalesces jobs that share a
 matrix (same generator rows or same decode plan) into one batch, and
 dispatches the batch as a single compute call:
 
-* **device mode**: a batch runs as one ``(V, S, W)`` block through the
-  NamedSharding'd vmap GF matmul from ``parallel.mesh`` — volumes shard
-  over ``dp``, columns over ``sp``.  A batch that is one whole block
-  already (one job, a contiguous ``(S, W)`` array whose width is its own
-  bucket, a mesh that needs no padding volume) goes in as a view of the
-  job's array; every other batch is stacked into a fresh block padded
-  to the mesh geometry.  Up to two batches stay in flight: while batch
-  *k* computes, batch *k+1* is assembled and dispatched, and *k*'s
-  readback overlaps *k+1*'s compute — replacing the encoder's
-  one-async-slice rule with true H2D/compute/D2H double buffering.
+* **device mode**: a batch is the head job plus queued jobs of OTHER
+  streams (slices of different volumes' encodes) that share its matrix
+  and its width bucket — a power of two of them, so that the compiled
+  shapes stay few, up to what the devices' memory holds with two batches
+  in flight (``_device_max_volumes``).  It runs as ONE device program
+  from ``parallel.mesh`` over every device the process holds: each job's
+  ``(S, W)`` array is an argument of its own, columns spread over all the
+  devices, and the ``(V, R, W)`` stack of results comes back as one
+  array.  A job that is a whole block already (a contiguous ``(S, W)``
+  array whose width is its own bucket) goes in as it is; any other job
+  is first copied into a reused staging buffer padded to the bucket.  Up
+  to two batches stay in flight: while batch *k* computes, batch *k+1* is
+  assembled and dispatched, and *k*'s readback overlaps *k+1*'s compute —
+  replacing the encoder's one-async-slice rule with true
+  H2D/compute/D2H double buffering.
 
 * **host mode**: the SAME scheduler runs on the C++ SIMD codec, so the
   batching and fairness properties hold on TPU-less hosts.  Small jobs
@@ -38,7 +43,8 @@ host mode calls the same kernel, device mode runs the same XOR-network
 formulation pinned byte-identical in tests/test_parallel.py.
 
 Env knobs (all ``SEAWEEDFS_TPU_EC_SERVICE_*``): ``QUEUE`` (bound, 64),
-``BATCH`` (max jobs/batch, 16), ``BATCH_MB`` (max input MB/batch, 64),
+``BATCH`` (max jobs/batch, 16), ``BATCH_MB`` (host mode: max input
+MB/batch, 64; a device batch is capped by the devices' memory instead),
 ``COALESCE_KB`` (host slab threshold per job, 16), ``DEGRADED`` ("1"
 routes degraded-read interval decodes through the service), and the
 top-level ``SEAWEEDFS_TPU_EC_SERVICE`` ("0" disables every default
@@ -47,6 +53,7 @@ wiring).
 
 from __future__ import annotations
 
+import contextlib
 import os
 import threading
 import time
@@ -57,6 +64,7 @@ import numpy as np
 from ..stats.metrics import (
     EC_SERVICE_BATCH_BYTES,
     EC_SERVICE_BATCH_JOBS,
+    EC_SERVICE_BLOCK_BYTES,
     EC_SERVICE_FLUSH,
     EC_SERVICE_INFLIGHT,
     EC_SERVICE_INPUT_BYTES,
@@ -87,6 +95,26 @@ _STAGE_COMPUTE = EC_SERVICE_STAGE.labels("compute")
 _STAGE_READBACK = EC_SERVICE_STAGE.labels("readback")
 _INPUT_BYTES = {p: EC_SERVICE_INPUT_BYTES.labels(p)
                 for p in ("direct", "staged")}
+_BLOCK_BYTES = EC_SERVICE_BLOCK_BYTES.labels()
+
+# What a device batch may occupy, from what the devices can hold, in bytes
+# on the device per byte of one job's padded (S, w_pad) input.  Resident per
+# job of a batch in flight: its input and its result as the device lays
+# them out (ten rows pad to sixteen sublanes, four result rows to the same
+# tile) — `peak_bytes_in_use` read 4.02 x the block's bytes with two
+# batches in flight at V = 1, 2 and 4 on a v5e, 2.01 a batch.  Once per
+# program, whatever V is: the XOR network's temporaries, 12.4 x one job's
+# bytes by the TPU compiler's memory analysis (the eight doubled multiples
+# of every row); `memory_stats` does not count them, the compiler does,
+# and refuses a program that does not fit (PERF.md §6, PR 30).  Two batches
+# are in flight and a batch may take _HBM_SHARE of each device's memory.
+_HBM_RESIDENT_PER_JOB_BYTE = 2.05
+_HBM_TEMP_PER_JOB_BYTE = 12.5
+_HBM_SHARE = 0.75
+_HBM_BYTES_UNREPORTED = 16 << 30  # a backend without memory_stats (CPU)
+# staging buffers kept for reuse per width bucket: three batches' worth
+# (one being built, two in flight) of the eight streams a server holds
+_STAGING_KEPT = 24
 
 
 def _env_int(name: str, default: int) -> int:
@@ -97,11 +125,14 @@ def _env_int(name: str, default: int) -> int:
 
 
 class _Job:
-    __slots__ = ("kind", "key", "rows", "data", "width", "out",
+    __slots__ = ("kind", "key", "rows", "data", "width", "out", "stream",
                  "event", "result", "error", "t_submit")
 
-    def __init__(self, kind, key, rows, data, width, out):
+    def __init__(self, kind, key, rows, data, width, out, stream=None):
         self.kind = kind
+        # jobs of one stream (the consecutive slices of one volume's
+        # pipeline) never share a device batch: see _collect_locked
+        self.stream = stream
         self.key = key
         self.rows = rows
         # (S, W) uint8 ndarray, or a list of S equal-length 1-D rows
@@ -176,14 +207,27 @@ class CodecService:
             "SEAWEEDFS_TPU_EC_SERVICE_BATCH", 16)
         self.max_queue = max_queue if max_queue is not None else _env_int(
             "SEAWEEDFS_TPU_EC_SERVICE_QUEUE", 64)
+        # host mode counts a batch's real input bytes against this; a
+        # device batch is capped by _device_max_volumes instead
         self.max_batch_bytes = (
             max_batch_mb if max_batch_mb is not None else _env_int(
                 "SEAWEEDFS_TPU_EC_SERVICE_BATCH_MB", 64)) << 20
+        self._device_bytes: "int | None" = None  # _device_max_volumes
         self.coalesce_bytes = (
             coalesce_kb if coalesce_kb is not None else _env_int(
                 "SEAWEEDFS_TPU_EC_SERVICE_COALESCE_KB", 16)) << 10
         self._mesh = mesh
         self._batch_seq = 0  # scheduler-thread-only: the spans' `batch`
+        # (S, w_pad) staging buffers of finished device batches by w_pad,
+        # for the next job that has to be staged (scheduler-thread-only):
+        # a fresh 80 MiB buffer is 20,480 page faults, a reused one a memcpy
+        self._staging: dict[int, list[np.ndarray]] = {}
+        # open streams (stream()) by name -> how often opened
+        self._streams: dict = {}
+        self._streams_lock = threading.Lock()
+        # (matrix key, w_pad) -> the largest V whose program _warm compiled
+        self._warmed: dict = {}
+        self._warm_lock = threading.Lock()
         self._q: deque[_Job] = deque()
         self._cond = threading.Condition()
         self._open = True
@@ -210,12 +254,36 @@ class CodecService:
 
     # -- submission -------------------------------------------------------
 
-    def submit_parity(self, data, out=None) -> CodecFuture:
+    @contextlib.contextmanager
+    def stream(self, name):
+        """Holds ``name`` open as a stream: a pipeline that submits one
+        volume's slices one after another under ``stream=name`` keeps it
+        open for as long as it runs.  While several are open their jobs
+        share device batches, and no batch holds more jobs than there are
+        streams, so a job submitted under an open stream first finds every
+        program such a batch can take compiled (``_warm``).  A stream that
+        is open alone changes nothing."""
+        with self._streams_lock:
+            self._streams[name] = self._streams.get(name, 0) + 1
+        try:
+            yield
+        finally:
+            with self._streams_lock:
+                self._streams[name] -= 1
+                if not self._streams[name]:
+                    del self._streams[name]
+
+    def submit_parity(self, data, out=None, stream=None) -> CodecFuture:
         """(data_shards, W) -> future of the parity rows.  ``data`` is
-        the service's until the future resolves (see the class)."""
+        the service's until the future resolves (see the class).  A
+        pipeline that submits one volume's slices one after another names
+        itself as their ``stream`` (and holds it open, see ``stream()``):
+        a device batch takes one job of a stream, so it fills with OTHER
+        volumes' slices and a lone pipeline's slices keep going to the
+        device one by one, as they are."""
         return self._submit_many(
             "parity", self.parity_matrix, self._parity_key,
-            (data,), (out,))[0]
+            (data,), (out,), stream)[0]
 
     def submit_parity_many(self, datas, outs=None) -> list[CodecFuture]:
         """Vectored submit: one lock/wakeup for a group of parity jobs —
@@ -226,15 +294,17 @@ class CodecService:
         return self._submit_many(
             "parity", self.parity_matrix, self._parity_key, datas, outs)
 
-    def submit_apply(self, rows: np.ndarray, inputs, out=None) -> CodecFuture:
+    def submit_apply(self, rows: np.ndarray, inputs, out=None,
+                     stream=None) -> CodecFuture:
         """Arbitrary (R, S) GF matrix x S input rows -> future of R rows.
         ``inputs`` is the service's until the future resolves (see the
-        class)."""
+        class); ``stream`` as in ``submit_parity``."""
         rows = np.ascontiguousarray(rows, dtype=np.uint8)
         if rows.ndim != 2:
             raise ValueError("rows must be a 2-D GF matrix")
         return self._submit_many(
-            "apply", rows, (rows.shape, rows.tobytes()), (inputs,), (out,))[0]
+            "apply", rows, (rows.shape, rows.tobytes()), (inputs,), (out,),
+            stream)[0]
 
     def submit_apply_many(self, rows: np.ndarray, inputs_list,
                           outs=None) -> list[CodecFuture]:
@@ -271,7 +341,8 @@ class CodecService:
                 raise ValueError("input rows must be equal-length 1-D")
         return data, width
 
-    def _submit_many(self, kind, rows, key, datas, outs) -> list[CodecFuture]:
+    def _submit_many(self, kind, rows, key, datas, outs,
+                     stream=None) -> list[CodecFuture]:
         r, s = rows.shape
         jobs: list[_Job] = []
         futs: list[CodecFuture] = []
@@ -284,7 +355,7 @@ class CodecService:
                 for o in out:
                     if len(o) != width:
                         raise ValueError("output rows must match input width")
-            job = _Job(kind, key, rows, data, width, out)
+            job = _Job(kind, key, rows, data, width, out, stream)
             futs.append(CodecFuture(job))
             if width == 0:  # nothing to compute: deliver inline
                 job.result = (out if out is not None else
@@ -292,6 +363,11 @@ class CodecService:
                 job.event.set()
             else:
                 jobs.append(job)
+        if jobs and stream is not None and self.mode == "device":
+            with self._streams_lock:
+                beside = len(self._streams) if stream in self._streams else 0
+            if beside > 1:  # before the jobs are queued: see _warm
+                self._warm(rows, key, {j.width for j in jobs}, beside)
         if jobs:
             with self._cond:
                 if not self._open:
@@ -336,6 +412,20 @@ class CodecService:
 
     # -- scheduler --------------------------------------------------------
 
+    def _device_max_volumes(self, job_bytes: int) -> int:
+        """How many jobs of `job_bytes` padded input each one device batch
+        may hold: two batches resident and one program's temporaries
+        within _HBM_SHARE of every device's memory (a job's columns are
+        spread over all of them).  At least the head job, always."""
+        if self._device_bytes is None:
+            mesh = self._device_mesh()
+            stats = mesh.devices.flat[0].memory_stats() or {}
+            self._device_bytes = int(_HBM_SHARE * mesh.size * stats.get(
+                "bytes_limit", _HBM_BYTES_UNREPORTED))
+        room = int((self._device_bytes / job_bytes - _HBM_TEMP_PER_JOB_BYTE)
+                   / (2 * _HBM_RESIDENT_PER_JOB_BYTE))
+        return max(1, min(room, self.max_batch))
+
     def _collect_locked(self) -> "tuple[list[_Job], str]":
         """Pop the head job plus every queued job sharing its matrix, up
         to the job/byte caps.  Head-of-queue start = oldest-first, so no
@@ -344,46 +434,60 @@ class CodecService:
         batch = [head]
         s = head.rows.shape[1]
         nbytes = head.width * s
-        # device mode stacks the batch into one (V, S, w_pad) block, so the
-        # byte cap must count what that block occupies in HBM — every job
-        # padded to the widest one's bucket — not the sum of real widths
-        # (16 jobs around one wide slice would otherwise pad 64MB of input
-        # into a >1GB block whose XOR-network temps do not fit the chip)
+        # a device batch is one program over V jobs' arrays: it takes jobs
+        # of the head's width bucket only (nothing pads beyond its own
+        # bucket), as many as the devices' memory holds, and one job of a
+        # stream — two slices of one volume in a batch only delay the
+        # first, and a lone pipeline's slices keep going one by one
         device = self.mode == "device"
-        w_max = head.width
+        if device:
+            n_dev = self._device_mesh().size
+            bucket = self._pad_width(head.width, n_dev)
+            room = self._device_max_volumes(s * bucket)
+            streams = {head.stream}
         reason = "ready"
-        if self.max_batch > 1 and self._q:
-            kept: deque[_Job] = deque()
-            while self._q:
-                job = self._q.popleft()
+        if self.max_batch > 1:
+            for job in self._q:
                 if job.key != head.key or job.kind != head.kind:
-                    kept.append(job)
                     continue
-                jb = job.width * s
+                if device and (
+                        self._pad_width(job.width, n_dev) != bucket
+                        or (job.stream is not None and job.stream in streams)):
+                    continue
                 if len(batch) >= self.max_batch:
-                    kept.append(job)
                     reason = "full"
                     break
-                w_new = max(w_max, job.width)
-                cost = ((len(batch) + 1) * s * self._pad_width(w_new, 1)
-                        if device else nbytes + jb)
-                if cost > self.max_batch_bytes:
-                    kept.append(job)
+                if (len(batch) >= room if device
+                        else nbytes + job.width * s > self.max_batch_bytes):
                     reason = "bytes"
                     break
                 batch.append(job)
-                nbytes += jb
-                w_max = w_new
-            kept.extend(self._q)
-            self._q = kept
+                nbytes += job.width * s
+                if device:
+                    streams.add(job.stream)
+            if device:
+                # a power of two of volumes: every (V, width) is a compiled
+                # program, and there must be few enough to warm them all
+                # (_warm); the rest keep their places in the queue
+                for job in batch[1 << (len(batch).bit_length() - 1):]:
+                    batch.remove(job)
+                    nbytes -= job.width * s
+            if len(batch) > 1:
+                taken = {id(j) for j in batch}
+                self._q = deque(j for j in self._q if id(j) not in taken)
         self._depth_child.set(len(self._q))
         self._batch_jobs_child.observe(len(batch))
         self._batch_bytes_child.observe(nbytes)
         return batch, reason
 
     def _run(self) -> None:
-        inflight: deque = deque()  # device mode: (jobs, device array, tags)
+        # device mode: (jobs, (device array, staging buffers used), tags)
+        inflight: deque = deque()
         try:
+            if self.mode == "device":
+                # the backend starts here if nothing started it before, not
+                # under the queue's lock
+                self._device_mesh()
             while True:
                 with self._cond:
                     while not self._q and self._open and not inflight:
@@ -550,8 +654,18 @@ class CodecService:
         if self._mesh is None:
             from ..parallel.mesh import make_mesh
 
-            self._mesh = make_mesh()
+            # every device on the column axis: a lone slice stays a whole
+            # block on any number of chips, and no batch needs a padding
+            # volume (measured against dp = 2 and one service per chip on
+            # four chips: PERF.md §6, PR 30)
+            self._mesh = make_mesh(dp=1)
         return self._mesh
+
+    def mesh_shape(self) -> str:
+        """"<dp>x<sp>" of the mesh device batches run over (`/status`,
+        the spans' `mesh`)."""
+        mesh = self._device_mesh()
+        return f"{mesh.shape['dp']}x{mesh.shape['sp']}"
 
     @staticmethod
     def _pad_width(width: int, sp: int) -> int:
@@ -562,41 +676,75 @@ class CodecService:
             w <<= 1
         return -(-w // sp) * sp
 
-    def _dispatch_device(self, batch: list[_Job], tags: dict):
-        from ..parallel.mesh import batch_apply_sharded
+    def _warm(self, rows: np.ndarray, key, widths, streams: int) -> None:
+        """Compile every device program that batches of up to ``streams``
+        jobs of these widths under this matrix can take — each power of
+        two of volumes the devices hold, at each width bucket.  The
+        submitter of a job of an open stream runs it BEFORE the job is
+        queued, with the number of streams open: of the jobs a batch
+        takes, one of a stream, the one submitted last saw at least as
+        many streams open as the batch has jobs, so no batch of open
+        streams' jobs forms before its program is compiled.  A shape
+        already compiled costs a dict lookup."""
+        from ..parallel.mesh import compile_jobs_apply
 
         mesh = self._device_mesh()
-        dp, sp = mesh.shape["dp"], mesh.shape["sp"]
+        s = rows.shape[1]
+        for w_pad in sorted({self._pad_width(w, mesh.size) for w in widths}):
+            room = min(self._device_max_volumes(s * w_pad), streams)
+            if self._warmed.get((key, w_pad), 0) * 2 > room:
+                continue
+            with self._warm_lock:
+                v = self._warmed.get((key, w_pad), 0) * 2 or 1
+                while v <= room:
+                    compile_jobs_apply(mesh, rows, v, (s, w_pad))
+                    self._warmed[key, w_pad] = v
+                    v *= 2
+
+    def _dispatch_device(self, batch: list[_Job], tags: dict):
+        from ..parallel.mesh import jobs_apply_sharded
+
+        mesh = self._device_mesh()
         head = batch[0]
         s = head.rows.shape[1]
-        w_pad = self._pad_width(max(j.width for j in batch), sp)
-        v_pad = -(-len(batch) // dp) * dp
-        # one job that fills the block IS the block (an ndarray job is
-        # C-contiguous (S, W) uint8, by _validate): same shape and dtype
-        # as the staged block, so the same compiled program, and none of
-        # the page faults of a fresh block per batch
-        path = ("direct" if len(batch) == v_pad == 1
-                and isinstance(head.data, np.ndarray)
-                and head.width == w_pad else "staged")
+        w_pad = self._pad_width(head.width, mesh.size)  # the batch's bucket
+        # a job that fills its bucket IS its block (an ndarray job is
+        # C-contiguous (S, W) uint8, by _validate) and goes in as it is;
+        # any other is copied into a staging buffer of the bucket's width
+        whole = [isinstance(j.data, np.ndarray) and j.width == w_pad
+                 for j in batch]
+        path = ("direct" if all(whole) else
+                "staged" if not any(whole) else "mixed")
+        tags.update(volumes=len(batch), v_pad=len(batch),
+                    mesh=self.mesh_shape())
+        blocks, staged = [], []
         with trace.stage("ec.svc.build", _STAGE_BUILD, path=path, **tags):
-            if path == "direct":
-                block = head.data[None]
-            else:
-                block = np.zeros((v_pad, s, w_pad), dtype=np.uint8)
-                for vi, j in enumerate(batch):
-                    if isinstance(j.data, np.ndarray):
-                        block[vi, :, :j.width] = j.data
-                    else:
-                        for ri in range(s):
-                            block[vi, ri, :j.width] = j.data[ri]
-            _INPUT_BYTES[path].inc(tags["bytes"])
-        # staging of the numpy block, H2D dispatch (async), a compile on a miss
+            for j, as_it_is in zip(batch, whole):
+                _INPUT_BYTES["direct" if as_it_is else "staged"].inc(
+                    j.width * s)
+                if as_it_is:
+                    blocks.append(j.data)
+                    continue
+                free = self._staging.get(w_pad)
+                block = free.pop() if free else np.empty(
+                    (s, w_pad), dtype=np.uint8)
+                if isinstance(j.data, np.ndarray):
+                    block[:, :j.width] = j.data
+                else:
+                    for ri in range(s):
+                        block[ri, :j.width] = j.data[ri]
+                block[:, j.width:] = 0
+                blocks.append(block)
+                staged.append(block)
+            _BLOCK_BYTES.inc(len(batch) * s * w_pad)
+        # H2D dispatch of every job's array (async), a compile on a miss
         with trace.stage("ec.svc.enqueue", _STAGE_ENQUEUE, **tags) as st:
-            dev = batch_apply_sharded(mesh, head.rows, block)
+            dev = jobs_apply_sharded(mesh, head.rows, blocks)
         _STAGE_COMPUTE.observe(st.seconds)
-        return dev
+        return dev, staged
 
-    def _complete_device(self, batch: list[_Job], dev, tags: dict) -> None:
+    def _complete_device(self, batch: list[_Job], sent, tags: dict) -> None:
+        dev, staged = sent
         try:
             # np.asarray alone would wait just the same: the split only
             # says how much of it is the device and how much the copy out
@@ -606,6 +754,11 @@ class CodecService:
             with trace.stage("ec.svc.d2h", _STAGE_D2H, **tags) as copy:
                 out = np.asarray(dev)  # D2H and its host-side transpose
             _STAGE_READBACK.observe(wait.seconds + copy.seconds)
+            # the result is here, so the device has read the staged jobs
+            for block in staged:
+                free = self._staging.setdefault(block.shape[1], [])
+                if len(free) < _STAGING_KEPT:
+                    free.append(block)
             with trace.stage("ec.svc.deliver", _STAGE_DELIVER, **tags):
                 for vi, j in enumerate(batch):
                     self._deliver(j, out[vi, :, :j.width])

@@ -108,35 +108,48 @@ def batch_encode_sharded(
 
 
 @functools.lru_cache(maxsize=None)
-def _sharded_apply(mesh: Mesh, rows: tuple[tuple[int, ...], ...]):
-    """One jitted sharded batch-apply per (mesh, matrix): the codec
-    service dispatches encode (parity rows) and decode (plan rows)
-    batches through the same entry, so both inherit the dp x sp layout
-    without a recompile per batch."""
+def _sharded_apply_jobs(mesh: Mesh, rows: tuple[tuple[int, ...], ...],
+                        n: int):
+    """One jitted program per (mesh, matrix, n): n jobs' own (S, B)
+    arrays in, the (n, R, B) stack of their results out, every array's
+    columns spread over ALL devices of the mesh.  The codec service's
+    device batch, for encode (parity rows) and decode (plan rows) alike:
+    each job's host array goes to the devices as it is (no (n, S, B)
+    block is ever built on the host), and the XOR network runs job after
+    job inside the one program, so its temporaries are one job's whatever
+    n is (a vmapped block program's grow with V: at V = 8 of the
+    encoder's slices the compiler refuses it on one v5e)."""
     apply_one = make_apply_xor(rows)
 
-    def gf_apply(batch: jax.Array) -> jax.Array:  # (V, S, B) -> (V, R, B)
-        return jax.vmap(apply_one)(batch)
+    def gf_apply(*blocks: jax.Array) -> jax.Array:  # n x (S, B) -> (n, R, B)
+        return jnp.stack([apply_one(block) for block in blocks])
 
     # the program's name on the device plane of a trace
     # (`jit_gf_apply_r4_s10`): stable across a change of kernel, and it
     # tells the matrix shapes in mixed traffic apart
     gf_apply.__name__ = f"gf_apply_r{len(rows)}_s{len(rows[0])}"
-    sharding = NamedSharding(mesh, P("dp", None, "sp"))
-    return jax.jit(gf_apply, in_shardings=sharding, out_shardings=sharding)
+    cols = mesh.axis_names
+    return jax.jit(
+        gf_apply,
+        in_shardings=(NamedSharding(mesh, P(None, cols)),) * n,
+        out_shardings=NamedSharding(mesh, P(None, None, cols)))
 
 
-def batch_apply_sharded(
-    mesh: Mesh,
-    matrix: np.ndarray,
-    batch: jax.Array | np.ndarray,
-) -> jax.Array:
-    """Apply one (R, S) GF matrix to (V, S, B) batched inputs over the
-    mesh: V shards over ``dp``, B over ``sp``.  The generalisation of
-    ``batch_encode_sharded`` to arbitrary matrices (decode plans,
-    survivor->wanted rebuild rows); dispatch is async, so the caller can
-    keep a second batch in flight while this one computes."""
-    return _sharded_apply(mesh, _rows_of(np.asarray(matrix)))(batch)
+def jobs_apply_sharded(mesh: Mesh, matrix: np.ndarray, blocks) -> jax.Array:
+    """Apply one (R, S) GF matrix to each of ``blocks`` — equal-shape
+    (S, B) uint8 host arrays, B a multiple of the device count — in one
+    device program; -> (len(blocks), R, B).  Dispatch is async."""
+    return _sharded_apply_jobs(
+        mesh, _rows_of(np.asarray(matrix)), len(blocks))(*blocks)
+
+
+def compile_jobs_apply(mesh: Mesh, matrix: np.ndarray, n: int,
+                       shape: tuple) -> None:
+    """Compile, and run nothing, the program ``jobs_apply_sharded`` takes
+    for n jobs of this (S, B) shape: the first real batch of that many
+    then finds it compiled."""
+    _sharded_apply_jobs(mesh, _rows_of(np.asarray(matrix)), n).lower(
+        *[jax.ShapeDtypeStruct(shape, jnp.uint8)] * n).compile()
 
 
 # ---------------------------------------------------------------------------

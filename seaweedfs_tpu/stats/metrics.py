@@ -691,6 +691,14 @@ EC_PIPELINE_BYTES = REGISTRY.counter(
     labels=("stage",),  # prefetch | write
 )
 
+# encodes this process runs at once: on a volume server, the ec.encode
+# rpcs (VolumeEcShardsGenerate) it holds.  Above 1, their slices share
+# device batches (ops/codec_service.py)
+EC_ENCODES_INFLIGHT = REGISTRY.gauge(
+    "seaweedfs_ec_encodes_inflight",
+    ".dat -> shard-file encodes in flight in this process",
+)
+
 # -- EC codec service (ops/codec_service.py) --------------------------------
 # one bounded queue between every GF caller (encode, rebuild, degraded
 # reads, bench) and the compute backend; the scheduler coalesces
@@ -734,9 +742,15 @@ EC_SERVICE_JOB_SECONDS = REGISTRY.histogram(
 EC_SERVICE_INPUT_BYTES = REGISTRY.counter(
     "seaweedfs_ec_service_input_bytes_total",
     "device-mode input bytes (unpadded) by how the batch reached the jit",
-    # direct: the job's own array went in as the block | staged: copied
-    # into a fresh padded (V, S, W) block first
+    # per job — direct: its own array went in as it is | staged: copied
+    # into a reused (S, w_pad) staging buffer first
     labels=("path",),
+)
+# beside batch_bytes_sum (unpadded input): block / input - 1 is what
+# bucketing widths adds to what the device is sent
+EC_SERVICE_BLOCK_BYTES = REGISTRY.counter(
+    "seaweedfs_ec_service_block_bytes_total",
+    "device-mode bytes sent to the device, every job at its width bucket",
 )
 EC_SERVICE_STAGE = REGISTRY.histogram(
     "seaweedfs_ec_service_stage_seconds",

@@ -15,7 +15,9 @@ because parity is columnwise.
 
 from __future__ import annotations
 
+import contextlib
 import os
+import threading
 import time
 
 import numpy as np
@@ -23,6 +25,7 @@ import numpy as np
 from ...ops import codec_service, gf256
 from ...ops.codec import get_codec
 from ...stats.metrics import (
+    EC_ENCODES_INFLIGHT,
     EC_PARTIAL_FALLBACK,
     EC_PIPELINE_BYTES,
     EC_PIPELINE_STAGE,
@@ -44,6 +47,8 @@ from .constants import (
 
 # Device batch: bytes per shard per codec call (64 x 256KB reference batches)
 DEFAULT_SLICE = 16 * 1024 * 1024
+# slice buffers one pipelined encode keeps (see _encode_stream_pipelined)
+_POOL_SLICES = 4
 
 # per-slice stage timings for the pipelined encode/rebuild: the pipeline
 # runs at max(stage), so bottleneck attribution = the widest histogram
@@ -53,6 +58,26 @@ _STAGE_DECODE = EC_PIPELINE_STAGE.labels("decode")
 _STAGE_WRITE = EC_PIPELINE_STAGE.labels("write")
 _BYTES_PREFETCH = EC_PIPELINE_BYTES.labels("prefetch")
 _BYTES_WRITE = EC_PIPELINE_BYTES.labels("write")
+
+
+_encodes_lock = threading.Lock()
+_encodes_in_flight = 0
+
+
+@contextlib.contextmanager
+def _encode_in_flight():
+    """Counts an encode among those this process runs at once (the gauge
+    `seaweedfs_ec_encodes_inflight`)."""
+    global _encodes_in_flight
+    with _encodes_lock:
+        _encodes_in_flight += 1
+        EC_ENCODES_INFLIGHT.set(_encodes_in_flight)
+    try:
+        yield
+    finally:
+        with _encodes_lock:
+            _encodes_in_flight -= 1
+            EC_ENCODES_INFLIGHT.set(_encodes_in_flight)
 
 
 def write_sorted_file_from_idx(base_name: str, ext: str = ".ecx") -> None:
@@ -103,6 +128,19 @@ def generate_ec_files(
         service = codec_service.service_for_codec(codec_name)
     dat_path = base_name + ".dat"
     dat_size = os.path.getsize(dat_path)
+    # this volume's slices are one stream of the service's, open while the
+    # encode runs: beside other encodes' streams they share device batches
+    stream = (service.stream(dat_path) if service is not None
+              else contextlib.nullcontext())
+    with _encode_in_flight(), stream:
+        _generate_ec_files(
+            base_name, dat_path, dat_size, codec, large_block_size,
+            small_block_size, slice_size, progress, sync, service)
+
+
+def _generate_ec_files(base_name, dat_path, dat_size, codec, large_block_size,
+                       small_block_size, slice_size, progress, sync,
+                       service) -> None:
     outs = [open(base_name + to_ext(i), "wb") for i in range(TOTAL_SHARDS)]
     try:
         with open(dat_path, "rb") as f:
@@ -347,13 +385,44 @@ def _encode_stream_pipelined(
                 continue
         return False
 
+    # pooled slice buffers, recycled by the writer once a slice's rows are
+    # in the shard files (the codec service owns a submitted slice until
+    # its parity is back, which is earlier): a fresh (10, 16 MiB) array per
+    # slice is 40,960 page faults and an mmap/munmap pair, and eight
+    # encodes at once take the process's one address-space lock for each.
+    # Four cover a slice in prefetch, two with the codec and one being
+    # written (the rebuild keeps three); allocated as the pipeline first
+    # needs them, so a small volume takes few.  Eight encodes at once keep
+    # 5 GiB between them: what they hold is what the host's write cache
+    # cannot.
+    pool: queue.Queue = queue.Queue()
+    made = 0
+
+    def _get_buffer() -> "np.ndarray | None":
+        """A free slice buffer; None once the consumer has bailed."""
+        nonlocal made
+        while not stop.is_set():
+            try:
+                return pool.get_nowait() if made < _POOL_SLICES else pool.get(
+                    timeout=0.1)
+            except queue.Empty:
+                if made < _POOL_SLICES:
+                    made += 1
+                    return np.empty((DATA_SHARDS, slice_size), dtype=np.uint8)
+        return None
+
     def reader() -> None:
         try:
             for batch in _slice_tasks(dat_size, large, small, slice_size):
                 total = sum(seg[3] for seg in batch)
+                buf = _get_buffer()  # a wait for the writer is no prefetch
+                if buf is None:
+                    return
+                # a full slice is the buffer itself: one contiguous block
+                # the service can hand to the device as it is
+                data = buf if total == slice_size else buf[:, :total]
                 with trace.stage("ec.pipeline.prefetch", _STAGE_PREFETCH,
                                  vid=vid, offset=batch[0][0]):
-                    data = np.empty((DATA_SHARDS, total), dtype=np.uint8)
                     fill_stripe_rows(f, batch, data)
                 _BYTES_PREFETCH.inc(data.nbytes)  # zero fill past EOF included
                 if not _put(data):
@@ -372,7 +441,8 @@ def _encode_stream_pipelined(
         if service is not None:
             # the codec service owns device transfer + double buffering;
             # slices become jobs it may coalesce with other volumes'
-            return service.submit_parity(data)
+            # (this volume's slices are one stream: a batch takes one)
+            return service.submit_parity(data, stream=f.name)
         if not is_device_codec:
             return codec.parity_of(data)
         return codec.encode_device(data)
@@ -404,6 +474,7 @@ def _encode_stream_pipelined(
                         outs[DATA_SHARDS + pi].write(prow)
                 _BYTES_WRITE.inc(data.shape[1] * (DATA_SHARDS + len(parity)))
                 done += data.shape[1] * DATA_SHARDS
+                pool.put(data if data.base is None else data.base)
                 if progress is not None:
                     progress(min(done, dat_size))
             except Exception as e:  # surfaced by the main thread
@@ -921,7 +992,7 @@ def rebuild_ec_files(base_name: str, codec_name: str = "cpu",
                 # service can hand to the device as it is; `buf` is the
                 # service's until drain() has the result, and only the
                 # writer, after that, recycles it
-                dev = service.submit_apply(plan_mtx, view)
+                dev = service.submit_apply(plan_mtx, view, stream=base_name)
             else:
                 dev = codec.apply_rows_device(plan_mtx, view)
             pending_q.append((buf, dev, off, width, part))

@@ -13,6 +13,7 @@ import urllib.error
 
 import grpc
 
+from ..ops import codec_service
 from ..ops.codec import DEVICE_CODEC_NAMES
 from ..ops.device import (
     compile_cache_stats,
@@ -228,9 +229,15 @@ class VolumeServer:
 
             out["device"] = self.ec_device
             out["compileCache"] = compile_cache_stats()
-            stats = jax.devices()[0].memory_stats() or {}
-            if "peak_bytes_in_use" in stats:
-                out["hbmPeakBytes"] = stats["peak_bytes_in_use"]
+            svc = codec_service.service_for_codec(self.store.codec_name)
+            if svc is not None:  # the mesh its device batches run over
+                out["mesh"] = svc.mesh_shape()
+            peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+                     for d in jax.devices()]
+            if None not in peaks:
+                # the fullest device's peak: what must fit one chip
+                out["hbmPeakBytes"] = max(peaks)
+                out["hbmPeakBytesPerDevice"] = peaks
         return out
 
     def update_gauges(self) -> None:

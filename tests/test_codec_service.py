@@ -205,14 +205,17 @@ def test_device_mode_identity_parity_and_apply():
 
 
 # what reaches the jit, per shape of batch: (mesh devices, dp, job widths,
-# how a job is handed in) -> (path, block shape)
+# how a job is handed in) -> (path of every job, width of every block)
 _BLOCK_CASES = {
-    "one_job_at_bucket_width": (1, 1, (1024,), "2d", "direct", (1, 10, 1024)),
-    "width_off_the_bucket": (1, 1, (1000,), "2d", "staged", (1, 10, 1024)),
-    "list_of_rows": (1, 1, (1024,), "rows", "staged", (1, 10, 1024)),
-    "strided_column_slice": (1, 1, (1024,), "strided", "staged", (1, 10, 1024)),
-    "two_coalesced_jobs": (1, 1, (1024, 1024), "2d", "staged", (2, 10, 1024)),
-    "dp2_padding_volume": (2, 2, (1024,), "2d", "staged", (2, 10, 1024)),
+    "one_job_at_bucket_width": (1, 1, (1024,), "2d", "direct", 1024),
+    "width_off_the_bucket": (1, 1, (1000,), "2d", "staged", 1024),
+    "list_of_rows": (1, 1, (1024,), "rows", "staged", 1024),
+    "strided_column_slice": (1, 1, (1024,), "strided", "staged", 1024),
+    "two_coalesced_jobs": (1, 1, (1024, 1024), "2d", "direct", 1024),
+    "two_jobs_off_the_bucket": (1, 1, (1000, 600), "2d", "staged", 1024),
+    # columns go over every device of any mesh: no padding volume
+    "dp2_mesh": (2, 2, (1024,), "2d", "direct", 1024),
+    "four_devices": (4, 1, (1024, 1024), "2d", "direct", 1024),
 }
 _HAND_IN = {
     "2d": lambda d: d,
@@ -224,23 +227,23 @@ _HAND_IN = {
 
 @pytest.mark.parametrize("case", sorted(_BLOCK_CASES))
 def test_device_block_is_the_job_or_a_staged_copy(case, monkeypatch):
-    """A device batch that is one whole block already reaches
-    batch_apply_sharded as a view of the job's array; every other batch
-    as a fresh padded block.  Same bytes out either way."""
+    """A job that is a whole block already reaches jobs_apply_sharded as
+    its own array; every other job as a padded copy in a staging buffer.
+    Same bytes out either way."""
     import jax
 
     from seaweedfs_tpu.parallel import mesh as mesh_mod
     from seaweedfs_tpu.stats.metrics import EC_SERVICE_STAGE
 
-    n_dev, dp, widths, hand_in, want_path, want_shape = _BLOCK_CASES[case]
-    blocks = []
-    real = mesh_mod.batch_apply_sharded
+    n_dev, dp, widths, hand_in, want_path, want_width = _BLOCK_CASES[case]
+    calls = []
+    real = mesh_mod.jobs_apply_sharded
 
-    def capture(mesh, matrix, block):
-        blocks.append(block)
-        return real(mesh, matrix, block)
+    def capture(mesh, matrix, blocks):
+        calls.append([(b, b.copy()) for b in blocks])
+        return real(mesh, matrix, blocks)
 
-    monkeypatch.setattr(mesh_mod, "batch_apply_sharded", capture)
+    monkeypatch.setattr(mesh_mod, "jobs_apply_sharded", capture)
     rs = ReedSolomon()
     rng = np.random.default_rng(28)
     datas = [_rand_block(rng, w) for w in widths]
@@ -256,17 +259,16 @@ def test_device_block_is_the_job_or_a_staged_copy(case, monkeypatch):
         assert np.array_equal(_as2d(fut.result(120)), rs.parity_of(data))
     svc.close()
 
-    (block,) = blocks  # one batch, whatever it held
-    assert block.shape == want_shape and block.dtype == np.uint8
-    if want_path == "direct":
-        assert np.shares_memory(block, datas[0])
-        assert block.base is datas[0]  # a view: nothing was copied
-    else:
-        assert not any(np.shares_memory(block, d) for d in datas)
-        for vi, d in enumerate(datas):
-            assert np.array_equal(block[vi, :, :d.shape[1]], d)
-            assert not block[vi, :, d.shape[1]:].any()
-        assert not block[len(datas):].any()  # the mesh's padding volume
+    (blocks,) = calls  # one batch, whatever it held
+    assert len(blocks) == len(datas)
+    for (block, sent), d in zip(blocks, datas):
+        assert sent.shape == (10, want_width) and sent.dtype == np.uint8
+        if want_path == "direct":
+            assert block is d  # the job's own array: nothing was copied
+        else:
+            assert not np.shares_memory(block, d)
+            assert np.array_equal(sent[:, :d.shape[1]], d)
+            assert not sent[:, d.shape[1]:].any()
     moved = {p: c.value - before[p] for p, c in counted.items()}
     other = "staged" if want_path == "direct" else "direct"
     assert moved == {want_path: 10 * sum(widths), other: 0}

@@ -1,0 +1,549 @@
+"""Device batches of more than one volume (ISSUE 30): jobs of several
+streams in one device program, byte for byte the plain reference's, on a
+one-device mesh and on the mesh a four-device process builds for itself;
+the cap derived from the devices' memory; one width bucket and one job of
+a stream to a batch; a power of two of volumes; every program a batch can
+take compiled before it forms; eight encodes at once through one service."""
+
+from __future__ import annotations
+
+import json
+import os
+import subprocess
+import sys
+import threading
+import time
+
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+from benchmark import reference_gf as ref  # noqa: E402
+
+from seaweedfs_tpu.ops import codec_service  # noqa: E402
+from seaweedfs_tpu.ops.codec_service import CodecService  # noqa: E402
+from seaweedfs_tpu.stats.metrics import (  # noqa: E402
+    EC_ENCODES_INFLIGHT,
+    EC_SERVICE_BATCH_JOBS,
+    EC_SERVICE_BLOCK_BYTES,
+)
+from seaweedfs_tpu.storage.ec import encoder  # noqa: E402
+
+# unequal widths: whole buckets, a few bytes over one, a few under
+WIDTHS = [1024, 700, 1025, 256, 2048, 999, 1024, 300]
+
+
+@pytest.fixture(autouse=True)
+def _clean_service_state():
+    yield
+    codec_service.shutdown_all(timeout=10)
+
+
+def _one_device_service(**kw):
+    import jax
+
+    from seaweedfs_tpu.parallel.mesh import make_mesh
+
+    return CodecService(mode="device", codec_name="tpu_xor",
+                        mesh=make_mesh(jax.devices()[:1]), **kw)
+
+
+def _hold_scheduler(svc):
+    """Keep submitted jobs queued until the returned release() is called, so
+    a test decides what the queue holds when a batch is collected."""
+    svc._cond.acquire()
+    return svc._cond.release
+
+
+def _jobs_per_batch():
+    child = EC_SERVICE_BATCH_JOBS.labels()
+    return child.total, child.count
+
+
+def _same_as_reference(result, data) -> bool:
+    got = np.stack([np.asarray(r) for r in result])
+    return np.array_equal(got, ref.parity_of(data))
+
+
+# -- V volumes in one block -------------------------------------------------------
+
+
+@pytest.mark.parametrize("v", [2, 3, 8])
+def test_batch_of_v_streams_equals_the_reference_job_by_job(v):
+    rng = np.random.default_rng(30 + v)
+    datas = [rng.integers(0, 256, (10, w), dtype=np.uint8)
+             for w in WIDTHS[:v]]
+    svc = _one_device_service()
+    jobs0, batches0 = _jobs_per_batch()
+    block0 = EC_SERVICE_BLOCK_BYTES.labels().value
+    release = _hold_scheduler(svc)
+    try:
+        futs = [svc.submit_parity(d, stream=f"vol{i}")
+                for i, d in enumerate(datas)]
+    finally:
+        release()
+    for fut, data in zip(futs, datas):
+        assert _same_as_reference(fut.result(120), data)
+    svc.close()
+    jobs1, batches1 = _jobs_per_batch()
+    assert jobs1 - jobs0 == v
+    # one width bucket and a power of two of volumes to a batch
+    assert batches1 - batches0 == _batches_of(WIDTHS[:v])
+    # every job is sent at its own bucket's width and no wider
+    sent = EC_SERVICE_BLOCK_BYTES.labels().value - block0
+    assert sent == 10 * sum(CodecService._pad_width(w, 1) for w in WIDTHS[:v])
+
+
+def _batches_of(widths) -> int:
+    """Batches the scheduler makes of jobs of these widths queued at once,
+    one stream each: per bucket, its count's powers of two."""
+    counts = {}
+    for w in widths:
+        bucket = CodecService._pad_width(w, 1)
+        counts[bucket] = counts.get(bucket, 0) + 1
+    return sum(bin(n).count("1") for n in counts.values())
+
+
+_FOUR_DEVICE_CHILD = """
+import json, sys
+import numpy as np
+sys.path.insert(0, %(root)r)
+import jax
+from benchmark import reference_gf as ref
+from seaweedfs_tpu.ops.codec_service import CodecService
+from seaweedfs_tpu.stats.metrics import EC_SERVICE_BATCH_JOBS, EC_SERVICE_BLOCK_BYTES
+widths = %(widths)r
+out = {"devices": len(jax.devices())}
+for v in (2, 3, 8):
+    rng = np.random.default_rng(30 + v)
+    datas = [rng.integers(0, 256, (10, w), dtype=np.uint8) for w in widths[:v]]
+    svc = CodecService(mode="device", codec_name="tpu_xor")  # its own mesh
+    child = EC_SERVICE_BATCH_JOBS.labels()
+    b0, sent0 = child.count, EC_SERVICE_BLOCK_BYTES.labels().value
+    with svc._cond:
+        futs = [svc.submit_parity(d, stream=i) for i, d in enumerate(datas)]
+    same = [bool(np.array_equal(np.stack([np.asarray(r) for r in f.result(120)]),
+                                ref.parity_of(d))) for f, d in zip(futs, datas)]
+    sent = EC_SERVICE_BLOCK_BYTES.labels().value - sent0
+    out[str(v)] = {"same": same, "batches": child.count - b0, "mesh": svc.mesh_shape(),
+                   "pad_pct": 100.0 * (sent / (10 * sum(widths[:v])) - 1)}
+    # a lone whole-bucket job still goes in as it is, on four devices too
+    lone = rng.integers(0, 256, (10, 1024), dtype=np.uint8)
+    before = {p: c.value for p, c in __import__(
+        "seaweedfs_tpu.ops.codec_service", fromlist=["x"])._INPUT_BYTES.items()}
+    assert np.array_equal(np.stack([np.asarray(r) for r in svc.submit_parity(lone).result(120)]),
+                          ref.parity_of(lone))
+    after = {p: c.value for p, c in __import__(
+        "seaweedfs_tpu.ops.codec_service", fromlist=["x"])._INPUT_BYTES.items()}
+    out[str(v)]["lone_direct"] = after["direct"] - before["direct"] == lone.size
+    svc.close()
+print(json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def four_device_run():
+    """One child process whose CPU backend has four devices: the service
+    builds its mesh from `jax.devices()`, as a server on four chips does."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    proc = subprocess.run(
+        [sys.executable, "-c",
+         _FOUR_DEVICE_CHILD % {"root": ROOT, "widths": WIDTHS}],
+        env=env, capture_output=True, text=True, timeout=600, cwd=ROOT)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("v", [2, 3, 8])
+def test_four_device_mesh_batches_equal_the_reference(four_device_run, v):
+    assert four_device_run["devices"] == 4
+    got = four_device_run[str(v)]
+    assert got["same"] == [True] * v
+    # columns over all four devices: no padding volume, whatever V is
+    assert got["mesh"] == "1x4"
+    assert got["batches"] == _batches_of(WIDTHS[:v])
+    assert got["lone_direct"] is True
+
+
+# -- what a batch may hold ----------------------------------------------------------
+
+
+def _queued(svc, datas, streams=None):
+    for i, d in enumerate(datas):
+        svc._q.append(codec_service._Job(
+            "parity", svc._parity_key, svc.parity_matrix, d, d.shape[1],
+            None, None if streams is None else streams[i]))
+
+
+def test_device_cap_is_derived_from_the_devices_memory():
+    svc = _one_device_service()
+    one_slice = 10 * encoder.DEFAULT_SLICE
+    # a CPU backend reports no memory: the unreported default (one v5e's
+    # 16 GiB) stands in.  Eight of the encoder's (10, 16 MiB) slices to a
+    # batch on one chip, where the cap of before (64 MB) held none; the
+    # job cap of sixteen on four; a head job of any size goes alone
+    assert 8 <= svc._device_max_volumes(one_slice) < 16
+    assert svc._device_bytes == int(
+        codec_service._HBM_SHARE * codec_service._HBM_BYTES_UNREPORTED)
+    assert svc._device_max_volumes(64 * one_slice) == 1
+    four = CodecService(mode="device", codec_name="tpu_xor")
+    four._device_bytes = 4 * svc._device_bytes
+    assert four._device_max_volumes(one_slice) == four.max_batch == 16
+    svc.close()
+
+
+def _hold(svc, job_bytes: int, volumes: int) -> None:
+    """Give the service devices that hold `volumes` jobs of `job_bytes`
+    to a batch by _device_max_volumes' own count, and not one more."""
+    svc._device_bytes = int(job_bytes * (
+        codec_service._HBM_TEMP_PER_JOB_BYTE
+        + 2 * codec_service._HBM_RESIDENT_PER_JOB_BYTE * (volumes + 0.5)))
+
+
+@pytest.mark.parametrize("case", ["never_over_the_cap", "head_over_the_cap"])
+def test_batch_never_exceeds_the_cap_but_the_head_always_goes(case):
+    svc = _one_device_service(max_batch=16)
+    _hold(svc, 10 * (16 << 10), 6)
+    rng = np.random.default_rng(5)
+    widths = ([16 << 10] * 12 if case == "never_over_the_cap"
+              else [256 << 10] * 3)
+    datas = [rng.integers(0, 256, (10, w), dtype=np.uint8) for w in widths]
+    with svc._cond:
+        _queued(svc, datas, streams=list(range(len(datas))))
+        batch, reason = svc._collect_locked()
+        left = len(svc._q)
+        svc._q.clear()
+    # what the cap counts: two batches' arrays and one program's temporaries
+    job_bytes = 10 * CodecService._pad_width(batch[0].width, 1)
+    held = job_bytes * (codec_service._HBM_TEMP_PER_JOB_BYTE + 2 * len(batch)
+                        * codec_service._HBM_RESIDENT_PER_JOB_BYTE)
+    if case == "never_over_the_cap":
+        # the devices hold 6 (160 KiB each); a power of two of them go
+        assert svc._device_max_volumes(job_bytes) == 6
+        assert len(batch) == 4 and held <= svc._device_bytes and left == 8
+    else:
+        # the head alone is 2.5 MiB: over the cap, and it still goes, alone
+        assert len(batch) == 1 and held > svc._device_bytes
+        assert reason == "bytes" and left == 2
+    svc.close()
+
+
+def test_one_job_of_a_stream_to_a_batch_and_order_is_kept():
+    svc = _one_device_service()
+    rng = np.random.default_rng(6)
+    datas = [rng.integers(0, 256, (10, 512), dtype=np.uint8)
+             for _ in range(6)]
+    streams = ["a", "a", "b", "a", "c", "b"]
+    with svc._cond:
+        _queued(svc, datas, streams)
+        first, _ = svc._collect_locked()
+        second, _ = svc._collect_locked()
+        third, _ = svc._collect_locked()
+        assert not svc._q
+    # a, b, c are three: two go (a power of two), c's job waits in front
+    assert [j.stream for j in first] == ["a", "b"]
+    assert [j.stream for j in second] == ["a", "c"]
+    assert [j.stream for j in third] == ["a", "b"]
+    assert [id(j.data) for j in first + second + third] == [
+        id(datas[i]) for i in (0, 2, 1, 4, 3, 5)]
+    svc.close()
+
+
+def test_a_lone_streams_slices_stay_whole_blocks():
+    """What keeps the one-rpc cells as they were: two queued slices of one
+    volume do not coalesce into a staged V = 2 block."""
+    svc = _one_device_service()
+    rng = np.random.default_rng(7)
+    datas = [rng.integers(0, 256, (10, 1024), dtype=np.uint8)
+             for _ in range(3)]
+    before = {p: c.value for p, c in codec_service._INPUT_BYTES.items()}
+    jobs0, batches0 = _jobs_per_batch()
+    release = _hold_scheduler(svc)
+    try:
+        futs = [svc.submit_parity(d, stream="one volume") for d in datas]
+    finally:
+        release()
+    for fut, data in zip(futs, datas):
+        assert _same_as_reference(fut.result(120), data)
+    svc.close()
+    moved = {p: c.value - before[p]
+             for p, c in codec_service._INPUT_BYTES.items()}
+    assert moved == {"direct": 3 * 10 * 1024, "staged": 0}
+    jobs1, batches1 = _jobs_per_batch()
+    assert (jobs1 - jobs0, batches1 - batches0) == (3, 3)
+
+
+def test_staging_buffers_are_reused_and_their_padding_is_zero(monkeypatch):
+    from seaweedfs_tpu.parallel import mesh as mesh_mod
+
+    calls = []
+    real = mesh_mod.jobs_apply_sharded
+
+    def capture(mesh, matrix, blocks):
+        calls.append([(b.ctypes.data, b.copy()) for b in blocks])
+        return real(mesh, matrix, blocks)
+
+    monkeypatch.setattr(mesh_mod, "jobs_apply_sharded", capture)
+    svc = _one_device_service()
+    rng = np.random.default_rng(8)
+    for _round in range(3):
+        datas = [rng.integers(1, 256, (10, w), dtype=np.uint8)
+                 for w in (1000, 600)]
+        release = _hold_scheduler(svc)
+        try:
+            futs = [svc.submit_parity(d, stream=i)
+                    for i, d in enumerate(datas)]
+        finally:
+            release()
+        for fut, data in zip(futs, datas):
+            assert _same_as_reference(fut.result(120), data)
+    svc.close()
+    assert [len(c) for c in calls] == [2, 2, 2]
+    # two staging buffers served all six jobs
+    assert len({addr for c in calls for addr, _ in c}) == 2
+    for c in calls:
+        for (_addr, sent), width in zip(c, (1000, 600)):
+            assert sent.shape == (10, 1024)
+            assert not sent[:, width:].any()  # zeroed again on every reuse
+
+
+def test_open_streams_warm_every_program_once(monkeypatch):
+    import contextlib
+
+    from seaweedfs_tpu.parallel import mesh as mesh_mod
+
+    programs = []
+    real = mesh_mod.compile_jobs_apply
+
+    def capture(mesh, matrix, n, shape):
+        programs.append((n, shape))
+        return real(mesh, matrix, n, shape)
+
+    monkeypatch.setattr(mesh_mod, "compile_jobs_apply", capture)
+    svc = _one_device_service(max_batch=16)
+    _hold(svc, 10 * 16384, 4)  # and sixteen, the job cap, at 1024
+    rng = np.random.default_rng(10)
+
+    def submit(svc, width, stream):
+        data = rng.integers(0, 256, (10, width), dtype=np.uint8)
+        assert _same_as_reference(
+            svc.submit_parity(data, stream=stream).result(120), data)
+
+    # a stream open alone, and one that nobody opened, warm nothing
+    with svc.stream("a"):
+        submit(svc, 1000, "a")
+        submit(svc, 1000, "nobody")
+    assert programs == []
+    with contextlib.ExitStack() as held:
+        for name in "ab":
+            held.enter_context(svc.stream(name))
+        # two open: V = 1, 2 at the job's own bucket, before it is queued
+        submit(svc, 1000, "a")
+        assert sorted(programs) == [(1, (10, 1024)), (2, (10, 1024))]
+        submit(svc, 9000, "b")
+        assert sorted(programs) == sorted(
+            [(v, (10, w)) for w in (1024, 16384) for v in (1, 2)])
+        for k in range(18):
+            held.enter_context(svc.stream(k))
+        # twenty open: as many volumes as the devices hold, and no more
+        submit(svc, 1024, "b")
+        submit(svc, 16384, 3)
+        want = sorted([(v, (10, 1024)) for v in (1, 2, 4, 8, 16)]
+                      + [(v, (10, 16384)) for v in (1, 2, 4)])
+        assert sorted(programs) == want
+        submit(svc, 1000, 4)
+        submit(svc, 9000, "a")
+        assert sorted(programs) == want  # nothing compiles twice
+        host = CodecService(mode="host")
+        with host.stream("x"), host.stream("y"):
+            submit(host, 1024, "x")
+        assert sorted(programs) == want  # host mode has no programs
+        # a batch of a warmed shape finds its program compiled; one of a
+        # shape nobody warmed (jobs of unopened streams) compiles when it
+        # forms (the listener sees that much)
+        from seaweedfs_tpu.ops import device
+
+        device.enable_compile_cache()
+        for width, streams, compiles in (
+                (1024, (0, 1, 2, 3), False), (2048, "wxyz", True)):
+            spent = device._stats["compile_seconds"]
+            datas = [rng.integers(0, 256, (10, width), dtype=np.uint8)
+                     for _ in range(4)]
+            release = _hold_scheduler(svc)
+            try:
+                futs = [svc.submit_parity(d, stream=name)
+                        for name, d in zip(streams, datas)]
+            finally:
+                release()
+            for fut, data in zip(futs, datas):
+                assert _same_as_reference(fut.result(120), data)
+            assert (device._stats["compile_seconds"] > spent) is compiles
+    assert svc._streams == {}
+    svc.close()
+
+
+# -- eight encodes at once ------------------------------------------------------------
+
+
+def _make_dat(path: str, size: int, seed: int) -> None:
+    with open(path, "wb") as f:
+        f.write(np.random.default_rng(seed).integers(
+            0, 256, size, dtype=np.uint8).tobytes())
+
+
+def _shard_bytes(base: str) -> list:
+    out = []
+    for i in range(14):
+        with open(f"{base}.ec{i:02d}", "rb") as f:
+            out.append(f.read())
+    return out
+
+
+def test_eight_concurrent_encodes_write_what_one_at_a_time_writes(
+        tmp_path, monkeypatch):
+    """Eight `generate_ec_files` at once through ONE device-mode service
+    (what eight VolumeEcShardsGenerate rpcs are to a volume server): the
+    files equal a lone encode's, batches carried more than one volume, the
+    gauge counted them, and their open streams warmed the batch programs."""
+    large, small, slice_size = 8192, 512, 4096
+    sizes = [70_000, 70_000, 41_000, 70_000, 12_345, 70_000, 55_555, 70_000]
+    for k, size in enumerate(sizes):
+        _make_dat(str(tmp_path / f"one_{k}.dat"), size, seed=k)
+        os.link(tmp_path / f"one_{k}.dat", tmp_path / f"many_{k}.dat")
+    from seaweedfs_tpu.parallel import mesh as mesh_mod
+
+    svc = _one_device_service()
+    programs = []
+    real_compile = mesh_mod.compile_jobs_apply
+    monkeypatch.setattr(mesh_mod, "compile_jobs_apply", lambda m, r, n, shape: (
+        programs.append((n, shape)), real_compile(m, r, n, shape))[1])
+    peak = [0.0]
+
+    def encode(base):
+        encoder.generate_ec_files(
+            base, large_block_size=large, small_block_size=small,
+            codec_name="tpu_xor", slice_size=slice_size, service=svc)
+
+    for k in range(len(sizes)):
+        encode(str(tmp_path / f"one_{k}"))
+    # a server that never holds two encodes compiles nothing ahead
+    assert programs == [] and EC_ENCODES_INFLIGHT.labels().value == 0
+
+    # a scheduler slower than its eight producers, as the chip's is at
+    # the served sizes: jobs of other volumes queue behind each batch
+    real_dispatch = svc._dispatch_device
+    monkeypatch.setattr(svc, "_dispatch_device", lambda batch, tags: (
+        time.sleep(0.05), real_dispatch(batch, tags))[1])
+    jobs0, batches0 = _jobs_per_batch()
+    gate = threading.Barrier(len(sizes))
+    real_stream = svc.stream
+
+    import contextlib
+
+    @contextlib.contextmanager
+    def open_together(name):
+        # every encode's stream is open before any goes on: what eight
+        # rpcs arriving together look like, made certain
+        with real_stream(name):
+            gate.wait(30)
+            peak[0] = max(peak[0], EC_ENCODES_INFLIGHT.labels().value)
+            yield
+
+    monkeypatch.setattr(svc, "stream", open_together)
+    errors = []
+
+    def guarded(base):
+        try:
+            encode(base)
+        except Exception as e:  # noqa: BLE001 — reported below
+            errors.append(e)
+
+    threads = [threading.Thread(target=guarded,
+                                args=(str(tmp_path / f"many_{k}"),))
+               for k in range(len(sizes))]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(300)
+    svc.close()
+    assert not errors, errors
+    assert peak[0] == len(sizes) and EC_ENCODES_INFLIGHT.labels().value == 0
+    assert svc._streams == {}
+    # every program a batch of eight streams can take, each compiled once
+    assert len(set(programs)) == len(programs)
+    assert {n for n, _shape in programs} == {1, 2, 4, 8}
+    for k in range(len(sizes)):
+        assert _shard_bytes(str(tmp_path / f"many_{k}")) == _shard_bytes(
+            str(tmp_path / f"one_{k}")), k
+    jobs1, batches1 = _jobs_per_batch()
+    assert jobs1 - jobs0 > batches1 - batches0  # some batch held V > 1
+
+
+def test_encode_recycles_six_slice_buffers_and_never_one_in_use(
+        tmp_path, monkeypatch):
+    """The pipelined encode keeps _POOL_SLICES slice buffers however many
+    slices the volume has, and refills one only after its parity is back
+    and its rows are written: shards equal the host codec's."""
+    large, small, slice_size = 8192, 512, 2048
+    _make_dat(str(tmp_path / "pooled.dat"), 600_000, seed=99)
+    os.link(tmp_path / "pooled.dat", tmp_path / "plain.dat")
+    svc = _one_device_service()
+    owned = []  # (the slice submitted, its future)
+    real_submit = svc.submit_parity
+    monkeypatch.setattr(svc, "submit_parity", lambda data, out=None, stream=None: (
+        owned.append((data, real_submit(data, out, stream))), owned[-1][1])[1])
+    filled_early, buffers = [], set()
+    real_fill = encoder.fill_stripe_rows
+
+    def fill(f, batch, dest):
+        buffers.add((dest if dest.base is None else dest.base).ctypes.data)
+        filled_early.extend(
+            1 for data, fut in list(owned)
+            if not fut.done() and np.shares_memory(dest, data))
+        real_fill(f, batch, dest)
+
+    monkeypatch.setattr(encoder, "fill_stripe_rows", fill)
+    encoder.generate_ec_files(
+        str(tmp_path / "pooled"), large_block_size=large,
+        small_block_size=small, codec_name="tpu_xor", slice_size=slice_size,
+        service=svc)
+    svc.close()
+    monkeypatch.setattr(encoder, "fill_stripe_rows", real_fill)
+    encoder.generate_ec_files(
+        str(tmp_path / "plain"), large_block_size=large,
+        small_block_size=small, codec_name="cpu", slice_size=slice_size)
+    assert len(owned) > 2 * encoder._POOL_SLICES  # many more slices than buffers
+    assert 1 <= len(buffers) <= encoder._POOL_SLICES
+    assert not filled_early
+    assert _shard_bytes(str(tmp_path / "pooled")) == _shard_bytes(
+        str(tmp_path / "plain"))
+
+
+def test_batch_spans_say_volumes_padding_and_mesh(monkeypatch):
+    from seaweedfs_tpu.telemetry import trace
+
+    seen = []
+    real_stage = trace.stage
+    monkeypatch.setattr(codec_service.trace, "stage", lambda name, hist=None, **attrs: (
+        seen.append((name, attrs)), real_stage(name, hist, **attrs))[1])
+    svc = _one_device_service()
+    rng = np.random.default_rng(9)
+    datas = [rng.integers(0, 256, (10, w), dtype=np.uint8)
+             for w in (1024, 1000)]
+    release = _hold_scheduler(svc)
+    try:
+        futs = [svc.submit_parity(d, stream=i) for i, d in enumerate(datas)]
+    finally:
+        release()
+    for fut in futs:
+        fut.result(120)
+    svc.close()
+    by_name = dict(seen)
+    for name in ("ec.svc.build", "ec.svc.enqueue"):
+        attrs = by_name[name]
+        assert (attrs["volumes"], attrs["v_pad"], attrs["mesh"]) == (2, 2, "1x1")
+        assert attrs["jobs"] == 2 and attrs["bytes"] == 10 * 2024
+    assert by_name["ec.svc.build"]["path"] == "mixed"  # one whole, one not

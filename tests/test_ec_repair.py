@@ -121,8 +121,8 @@ def test_rebuild_via_device_service_direct_slices_and_buffer_ownership(
     owned = []  # (input rows, future) of every job the rebuild submitted
     real_submit = svc.submit_apply
 
-    def submit(rows, inputs, out=None):
-        fut = real_submit(rows, inputs, out)
+    def submit(rows, inputs, out=None, stream=None):
+        fut = real_submit(rows, inputs, out, stream)
         owned.append((inputs if isinstance(inputs, list) else [inputs], fut))
         return fut
 

@@ -199,18 +199,20 @@ def test_split_adds_up_to_the_stages_the_benchmark_reads(device_service):
 
 
 def test_program_name_on_the_device_plane():
-    """The jitted GF program says what it is and its matrix shape."""
+    """The codec service's jitted GF program, over V jobs' arrays, says
+    what it is and its matrix shape, whatever V."""
     from seaweedfs_tpu.parallel.mesh import (
         _rows_of,
-        _sharded_apply,
+        _sharded_apply_jobs,
         make_mesh,
     )
 
     rows = _rows_of(np.arange(1, 41, dtype=np.uint8).reshape(4, 10))
-    fn = _sharded_apply(make_mesh(), rows)
-    text = fn.lower(np.zeros((make_mesh().shape["dp"], 10, 256),
-                             np.uint8)).as_text()
-    assert "jit_gf_apply_r4_s10" in text
+    mesh = make_mesh()
+    for n in (1, 2):
+        text = _sharded_apply_jobs(mesh, rows, n).lower(
+            *[np.zeros((10, 256), np.uint8)] * n).as_text()
+        assert "jit_gf_apply_r4_s10" in text
 
 
 # -- the reduction names a gap by the program's span ---------------------------
@@ -351,8 +353,9 @@ def test_front_end_counts_dispatch_wait_and_resident_per_method():
 
 def test_benchmark_names_the_fourteen_new_metrics():
     entries = {m["name"]: m for m in BENCH["per_layer"]}
-    assert [m["name"] for m in BENCH["per_layer"]][-14:] == NEW_METRICS
-    layers = {m["layer"] for m in BENCH["per_layer"][:-14]}
+    # PR 25's fourteen, then these fourteen; later PRs append after them
+    assert [m["name"] for m in BENCH["per_layer"]][14:28] == NEW_METRICS
+    layers = {m["layer"] for m in BENCH["per_layer"][:14]}
     for name in NEW_METRICS:
         m = entries[name]
         assert m["source"] == "program_span" and m["layer"] in layers

@@ -91,13 +91,22 @@ def test_pallas_kernels_compile_for_v5e(one_chip, what, shard_mib):
     assert seconds < 30, f"{what} took {seconds:.0f}s to compile"
 
 
+def _jobs_program(mesh, rows, n, width):
+    """(the service's program over n jobs, its n argument shapes)"""
+    from seaweedfs_tpu.parallel.mesh import _sharded_apply_jobs
+
+    job = jax.ShapeDtypeStruct(
+        (10, width), jnp.uint8,
+        sharding=NamedSharding(mesh, P(None, mesh.axis_names)))
+    return _sharded_apply_jobs(mesh, _rows_of(rows), n), [job] * n
+
+
 @pytest.mark.parametrize("what", ["parity", "decode4"])
 def test_service_batch_program_fits_hbm_twice(topo, what):
-    """The codec service's device program (vmapped XOR network) at its
-    LARGEST bucket — one DEFAULT_SLICE job, (1, 10, 16 MiB) — must fit
-    the chip twice over: the scheduler keeps two batches in flight."""
+    """The codec service's device program (the XOR network over each
+    job's array) for one DEFAULT_SLICE job, (10, 16 MiB), must fit the
+    chip twice over: the scheduler keeps two batches in flight."""
     from seaweedfs_tpu.ops.codec_service import CodecService
-    from seaweedfs_tpu.parallel.mesh import _sharded_apply
     from seaweedfs_tpu.storage.ec.encoder import DEFAULT_SLICE
 
     mesh = Mesh(np.asarray(topo.devices[:1]).reshape(1, 1), ("dp", "sp"))
@@ -105,10 +114,8 @@ def test_service_batch_program_fits_hbm_twice(topo, what):
             else _decode_rows([0, 2, 5, 9]))
     w_pad = CodecService._pad_width(DEFAULT_SLICE, 1)
     assert w_pad == DEFAULT_SLICE  # slices are already a bucket
-    block = jax.ShapeDtypeStruct(
-        (1, 10, w_pad), jnp.uint8,
-        sharding=NamedSharding(mesh, P("dp", None, "sp")))
-    compiled, seconds = _compile(_sharded_apply(mesh, _rows_of(rows)), block)
+    fn, jobs = _jobs_program(mesh, rows, 1, w_pad)
+    compiled, seconds = _compile(fn, *jobs)
     mem = compiled.memory_analysis()
     live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
@@ -116,13 +123,47 @@ def test_service_batch_program_fits_hbm_twice(topo, what):
     assert seconds < 60
 
 
-def test_service_batches_are_capped_by_padded_bytes():
-    """Device mode stacks a batch into one (V, S, w_pad) block: the byte
-    cap counts that block, so a mixed batch cannot out-pad the program
-    checked above."""
+@pytest.mark.parametrize("chips", [1, 4])
+def test_largest_batch_the_cap_allows_fits_hbm_twice(topo, chips):
+    """The largest batch CodecService._device_max_volumes lets through at
+    the encoder's slice width — eight volumes' slices on one chip, sixteen
+    (the job cap) over four — fits each chip by the compiler's own count:
+    two batches' arrays resident and one program's temporaries, inside the
+    share the cap is derived from; and the two ratios the cap is computed
+    from are the compiler's, rounded up."""
+    from seaweedfs_tpu.ops import codec_service as cs
+    from seaweedfs_tpu.storage.ec.encoder import DEFAULT_SLICE
+
+    mesh = Mesh(np.asarray(topo.devices[:chips]).reshape(1, chips),
+                ("dp", "sp"))
+    svc = cs.CodecService(mode="device", codec_name="tpu_xor", mesh=mesh)
+    svc._device_bytes = int(cs._HBM_SHARE * chips * HBM_BYTES)
+    job_bytes = 10 * DEFAULT_SLICE
+    v = svc._device_max_volumes(job_bytes)
+    v = 1 << (v.bit_length() - 1)
+    assert v == {1: 8, 4: 16}[chips]
+    fn, jobs = _jobs_program(mesh, gf256.rs_parity_matrix(10, 4), v,
+                             DEFAULT_SLICE)
+    compiled, seconds = _compile(fn, *jobs)
+    mem = compiled.memory_analysis()  # per device
+    resident = mem.argument_size_in_bytes + mem.output_size_in_bytes
+    assert 2 * resident + mem.temp_size_in_bytes <= cs._HBM_SHARE * HBM_BYTES
+    per_job_byte = job_bytes / chips
+    assert 1.8 < resident / v / per_job_byte <= cs._HBM_RESIDENT_PER_JOB_BYTE
+    assert 10 < mem.temp_size_in_bytes / per_job_byte <= (
+        cs._HBM_TEMP_PER_JOB_BYTE)
+    assert seconds < 90
+    svc.close()
+
+
+def test_service_batch_holds_one_width_bucket():
+    """A device batch is one program over jobs of ONE width bucket: a wide
+    job between narrow ones waits its turn, so nothing is padded beyond
+    its own bucket, and a head job over the cap still goes, alone."""
     from seaweedfs_tpu.ops.codec_service import CodecService
 
-    svc = CodecService(mode="device", max_batch=16, max_batch_mb=64)
+    svc = CodecService(mode="device", max_batch=16)
+    svc._device_bytes = 64 << 20  # what _device_max_volumes divides up
     rng = np.random.default_rng(0)
     datas = [rng.integers(0, 256, (10, w), dtype=np.uint8)
              for w in [1 << 10] + [5 << 20] + [1 << 10] * 14]
@@ -130,9 +171,11 @@ def test_service_batches_are_capped_by_padded_bytes():
         for d in datas:
             svc._q.append(_job(svc, d))
         batch, reason = svc._collect_locked()
-    padded = len(batch) * 10 * CodecService._pad_width(
-        max(j.width for j in batch), 1)
-    assert padded <= 64 * MIB and reason == "bytes"
+        assert [j.width for j in batch] == [1 << 10] * 8  # a power of two
+        assert svc._q[0].width == 5 << 20 and len(svc._q) == 8
+        wide, reason = svc._collect_locked()
+        # 80 MiB padded: more than the devices hold, and it still goes, alone
+        assert [j.width for j in wide] == [5 << 20]
     svc._q.clear()
     svc.close()
 
