@@ -392,8 +392,9 @@ def device_work_report(before: dict, after: dict, rpc: str, kind: str,
         return
     check(svc_ok > 0 and readback > 0 and svc_bytes > 0,
           f"{rpc}: the device-mode codec service did no work")
-    say(f"{rpc}: implementation = codec service, device mode (vmapped XOR "
-        f"network over the mesh; NOT the Pallas kernel) on {platform}")
+    say(f"{rpc}: implementation = codec service, device mode (XOR network "
+        f"on uint32 lane tiles over the mesh; NOT the Pallas kernel) on "
+        f"{platform}")
 
 
 def compare_files(a: str, b: str) -> bool:
@@ -799,8 +800,9 @@ def child_mesh(seed: int, width: int, volumes: int) -> int:
               f"devices, 1/{share} each: {good}  [{arr.sharding}]")
         return good
 
-    # 1. the service's device batch program, on the mesh the service
-    # builds for itself: `volumes` slices at once, each an argument
+    # 1. the service's device batch program (uint32 lane tiles both ways),
+    # on the mesh the service builds for itself: `volumes` slices at once,
+    # each an argument
     block = rng.integers(0, 256, (volumes, 10, width), dtype=np.uint8)
     want = np.stack([rs.parity_of(block[v]) for v in range(volumes)])
     svc = CodecService(mode="device")
@@ -811,7 +813,11 @@ def child_mesh(seed: int, width: int, volumes: int) -> int:
     out.block_until_ready()
     print(f"jobs_apply_sharded {block.shape}: {time.perf_counter() - t:.2f}s"
           " incl. compile")
-    ok &= placed(out, "jobs_apply_sharded output", n)
+    ok &= placed(out.dev, "jobs_apply_sharded output", n)
+    packed = str(out.dev.dtype) == "uint32" and out.dev.shape[-1] == 128
+    print(f"jobs_apply_sharded result on the device: {out.dev.dtype} "
+          f"{out.dev.shape}, lane tiles: {packed}")
+    ok &= packed
     same = np.array_equal(np.asarray(out), want)
     print(f"jobs_apply_sharded vs rs_cpu: byte-identical={same}")
     ok &= same

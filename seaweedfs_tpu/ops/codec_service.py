@@ -14,12 +14,14 @@ dispatches the batch as a single compute call:
   from ``parallel.mesh`` over every device the process holds: each job's
   ``(S, W)`` array is an argument of its own, columns spread over all the
   devices, and the ``(V, R, W)`` stack of results comes back as one
-  array.  A job that is a whole block already (a contiguous ``(S, W)``
-  array whose width is its own bucket) goes in as it is; any other job
-  is first copied into a reused staging buffer padded to the bucket.  Up
-  to two batches stay in flight: while batch *k* computes, batch *k+1* is
-  assembled and dispatched, and *k*'s readback overlaps *k+1*'s compute —
-  replacing the encoder's one-async-slice rule with true
+  array.  Both cross the bus as uint32 lane tiles, views of the host's
+  bytes in the layout the device keeps, so neither way is anything re-laid
+  on the host.  A job that is a whole block already (a contiguous
+  ``(S, W)`` array whose width is its own bucket) goes in as it is; any
+  other job is first copied into a reused staging buffer padded to the
+  bucket.  Up to two batches stay in flight: while batch *k* computes,
+  batch *k+1* is assembled and dispatched, and *k*'s readback overlaps
+  *k+1*'s compute — replacing the encoder's one-async-slice rule with true
   H2D/compute/D2H double buffering.
 
 * **host mode**: the SAME scheduler runs on the C++ SIMD codec, so the
@@ -99,15 +101,17 @@ _BLOCK_BYTES = EC_SERVICE_BLOCK_BYTES.labels()
 
 # What a device batch may occupy, from what the devices can hold, in bytes
 # on the device per byte of one job's padded (S, w_pad) input.  Resident per
-# job of a batch in flight: its input and its result as the device lays
-# them out (ten rows pad to sixteen sublanes, four result rows to the same
-# tile) — `peak_bytes_in_use` read 4.02 x the block's bytes with two
-# batches in flight at V = 1, 2 and 4 on a v5e, 2.01 a batch.  Once per
-# program, whatever V is: the XOR network's temporaries, 12.4 x one job's
-# bytes by the TPU compiler's memory analysis (the eight doubled multiples
-# of every row); `memory_stats` does not count them, the compiler does,
-# and refuses a program that does not fit (PERF.md §6, PR 30).  Two batches
-# are in flight and a batch may take _HBM_SHARE of each device's memory.
+# job of a batch in flight: its input and its result.  Once per program,
+# whatever V is: the XOR network's temporaries (the doubled multiples of
+# every row); `memory_stats` does not count them, the compiler does, and
+# refuses a program that does not fit.  Both constants are the uint8
+# program's of before (2.01 and 12.4: PERF.md §6, PR 30), kept as upper
+# bounds so that the cap stays the one measured then: as uint32 lane tiles
+# nothing pads (ten rows in, four out: `peak_bytes_in_use` read 2.80 x the
+# block's bytes with two batches in flight at V = 1, 2, 4 and 8 on a v5e,
+# 1.40 a batch) and the temporaries are 7.1 x one job's bytes (PERF.md §6,
+# PR 31).  Two batches are in flight and a batch may take _HBM_SHARE of
+# each device's memory.
 _HBM_RESIDENT_PER_JOB_BYTE = 2.05
 _HBM_TEMP_PER_JOB_BYTE = 12.5
 _HBM_SHARE = 0.75
@@ -115,6 +119,8 @@ _HBM_BYTES_UNREPORTED = 16 << 30  # a backend without memory_stats (CPU)
 # staging buffers kept for reuse per width bucket: three batches' worth
 # (one being built, two in flight) of the eight streams a server holds
 _STAGING_KEPT = 24
+# what the spans of a batch's two bus crossings say of its form
+_LAYOUT = "u32x128"
 
 
 def _env_int(name: str, default: int) -> int:
@@ -669,12 +675,16 @@ class CodecService:
 
     @staticmethod
     def _pad_width(width: int, sp: int) -> int:
-        """Bucket widths to powers of two (multiples of sp) so the jitted
-        sharded program compiles once per bucket, not once per slice."""
-        w = max(sp, 256)
+        """Bucket widths to a power of two of whole lane tiles on each of
+        the sp devices, so the jitted sharded program compiles once per
+        bucket, not once per slice, and every bucket is one the program
+        takes (``parallel.mesh.jobs_apply_sharded``)."""
+        from ..parallel.mesh import TILE_BYTES
+
+        w = TILE_BYTES * sp
         while w < width:
             w <<= 1
-        return -(-w // sp) * sp
+        return w
 
     def _warm(self, rows: np.ndarray, key, widths, streams: int) -> None:
         """Compile every device program that batches of up to ``streams``
@@ -738,7 +748,8 @@ class CodecService:
                 staged.append(block)
             _BLOCK_BYTES.inc(len(batch) * s * w_pad)
         # H2D dispatch of every job's array (async), a compile on a miss
-        with trace.stage("ec.svc.enqueue", _STAGE_ENQUEUE, **tags) as st:
+        with trace.stage("ec.svc.enqueue", _STAGE_ENQUEUE, layout=_LAYOUT,
+                         **tags) as st:
             dev = jobs_apply_sharded(mesh, head.rows, blocks)
         _STAGE_COMPUTE.observe(st.seconds)
         return dev, staged
@@ -751,8 +762,9 @@ class CodecService:
             with trace.stage("ec.svc.device_wait", _STAGE_DEVICE_WAIT,
                              **tags) as wait:
                 dev.block_until_ready()
-            with trace.stage("ec.svc.d2h", _STAGE_D2H, **tags) as copy:
-                out = np.asarray(dev)  # D2H and its host-side transpose
+            with trace.stage("ec.svc.d2h", _STAGE_D2H, layout=_LAYOUT,
+                             **tags) as copy:
+                out = np.asarray(dev)  # D2H: a copy, (V, R, w_pad) bytes
             _STAGE_READBACK.observe(wait.seconds + copy.seconds)
             # the result is here, so the device has read the staged jobs
             for block in staged:

@@ -12,6 +12,9 @@ the hot loop at weed/storage/erasure_coding/ec_encoder.go:179
    folds the selection into a static XOR network and fuses the whole encode
    into one elementwise kernel: 10 streams in, 4 streams out, no
    intermediates in HBM.
+   The network is the same on bytes and on words of them: the codec
+   service's program (parallel.mesh) runs it on uint32 lane tiles, four
+   bytes to a lane, the layout the host and the device share.
 
 2. ``mxu`` (systolic array): over GF(2) the codec is linear in *bits*, so
    unpack bytes to bit-planes, multiply by the 8Rx8C 0/1 matrix of
@@ -37,22 +40,35 @@ from ..telemetry import trace
 _REDUCE = 0x1D  # low byte of the field polynomial 0x11D
 
 
+def _byte_lanes(dtype, byte: int):
+    """``byte`` in every byte lane of one ``dtype`` word."""
+    dtype = np.dtype(dtype)
+    return dtype.type(int.from_bytes(bytes([byte]) * dtype.itemsize, "little"))
+
+
 def _multiples(data: jax.Array) -> list[jax.Array]:
     """[data * 2^k for k in 0..7] — the doubling chain in GF(2^8).
 
-    data: uint8 (..., B).  Each step: x*2 = (x << 1) ^ (0x1D if x & 0x80).
+    data: uint8 bytes, or words of them in host order (uint32 lane tiles:
+    rs_pallas.pack_lane_tiles), leading axis the shards.  Each step, per
+    byte lane: x*2 = ((x & 0x7F) << 1) ^ (0x1D if x & 0x80).  Nothing
+    crosses a byte lane — the mask drops the bit the shift would carry
+    over, and 0x1D < 0x100 — so a word is its bytes side by side in any
+    byte order.
     """
+    lo7, one = _byte_lanes(data.dtype, 0x7F), _byte_lanes(data.dtype, 0x01)
+    reduce = data.dtype.type(_REDUCE)  # times 0 or 1 in each byte lane
     ms = [data]
     x = data
     for _ in range(7):
-        hi = x >> 7  # 0 or 1
-        x = ((x << 1) ^ (hi * jnp.uint8(_REDUCE))).astype(jnp.uint8)
+        x = ((x & lo7) << 1) ^ (((x >> 7) & one) * reduce)
         ms.append(x)
     return ms
 
 
 def _xor_network(rows: tuple[tuple[int, ...], ...], data: jax.Array) -> jax.Array:
-    """Apply a constant GF matrix to (S, B) data via the XOR network."""
+    """Apply a constant GF matrix to (S, ...) data, bytes or words of
+    them (``_multiples``), via the XOR network -> (R, ...)."""
     ms = _multiples(data)
     outs = []
     for row in rows:
@@ -68,7 +84,8 @@ def _xor_network(rows: tuple[tuple[int, ...], ...], data: jax.Array) -> jax.Arra
 
 @functools.lru_cache(maxsize=None)
 def make_apply_xor(rows: tuple[tuple[int, ...], ...]):
-    """Jitted (S, B) uint8 -> (R, B) uint8 GF matmul with baked constants."""
+    """Jitted (S, ...) -> (R, ...) GF matmul with baked constants, on
+    uint8 bytes or on uint32 words of them (``_multiples``)."""
 
     @jax.jit
     def apply(data: jax.Array) -> jax.Array:

@@ -87,10 +87,12 @@ def make_apply_pallas(
 
 
 class PackedRows:
-    """Async result of a packed GF apply: ``(R, rows, 128)`` uint32 lane
-    tiles still on (or on their way from) the device.  ``np.asarray`` of
-    it blocks, reads back and returns the ``(R, B)`` uint8 bytes — the
-    u32->u8 view and the trim of the host-side pad are free ndarray views.
+    """Async result of a packed GF apply: ``(..., R, rows, 128)`` uint32
+    lane tiles still on (or on their way from) the device.  ``np.asarray``
+    of it blocks, reads back and returns the ``(..., R, B)`` uint8 bytes —
+    the device holds such tiles in the host's own order, so the readback
+    is a copy, and the u32->u8 view and the trim of the host-side pad are
+    free ndarray views.
     """
 
     __slots__ = ("dev", "width")
@@ -105,13 +107,14 @@ class PackedRows:
 
     def __array__(self, dtype=None, copy=None):
         out = np.asarray(self.dev).view(np.uint8).reshape(
-            self.dev.shape[0], -1)[:, :self.width]
+            *self.dev.shape[:-2], -1)[..., :self.width]
         return out if dtype is None else out.astype(dtype, copy=False)
 
 
-def pack_lane_tiles(data: np.ndarray) -> np.ndarray:
+def pack_lane_tiles(data: np.ndarray, block_rows: int = SUBLANES) -> np.ndarray:
     """(S, B) uint8 host bytes -> (S, R, 128) uint32 lane tiles, zero-
-    padded ON THE HOST so R splits into whole kernel blocks.
+    padded ON THE HOST so R splits into whole blocks of ``block_rows``
+    (the kernel's grid step; parallel.mesh: eight sublanes on each device).
 
     The host already holds the bytes, so the u8->u32 repack is a free
     ndarray view here; done on the device it cost a 64x temp and an 80 s
@@ -119,8 +122,8 @@ def pack_lane_tiles(data: np.ndarray) -> np.ndarray:
     s, b = data.shape
     tile = LANES * BYTES_PER_LANE  # 512 bytes per lane-tile row
     rows_total = -(-b // tile)
-    if rows_total > SUBLANES:
-        rows_total = -(-rows_total // SUBLANES) * SUBLANES
+    if rows_total > block_rows:
+        rows_total = -(-rows_total // block_rows) * block_rows
     padded = rows_total * tile
     if padded != b:
         buf = np.zeros((s, padded), dtype=np.uint8)

@@ -30,7 +30,20 @@ from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops import gf256
-from ..ops.rs_jax import _multiples, _rows_of, make_apply_xor
+from ..ops.rs_jax import _rows_of, make_apply_xor
+from ..ops.rs_pallas import (
+    BYTES_PER_LANE,
+    LANES,
+    PackedRows,
+    pack_lane_tiles,
+)
+
+# A device's least share of a job's row: eight sublanes of 128 uint32 lanes,
+# the tile the TPU stores 32-bit arrays in — laid out on the device as on
+# the host.  The codec service's width buckets are multiples of it.
+_ROW_BYTES = LANES * BYTES_PER_LANE
+_TILE_ROWS = 8
+TILE_BYTES = _TILE_ROWS * _ROW_BYTES
 
 
 def make_mesh(
@@ -110,19 +123,23 @@ def batch_encode_sharded(
 @functools.lru_cache(maxsize=None)
 def _sharded_apply_jobs(mesh: Mesh, rows: tuple[tuple[int, ...], ...],
                         n: int):
-    """One jitted program per (mesh, matrix, n): n jobs' own (S, B)
-    arrays in, the (n, R, B) stack of their results out, every array's
-    columns spread over ALL devices of the mesh.  The codec service's
-    device batch, for encode (parity rows) and decode (plan rows) alike:
-    each job's host array goes to the devices as it is (no (n, S, B)
-    block is ever built on the host), and the XOR network runs job after
-    job inside the one program, so its temporaries are one job's whatever
-    n is (a vmapped block program's grow with V: at V = 8 of the
-    encoder's slices the compiler refuses it on one v5e)."""
+    """One jitted program per (mesh, matrix, n): n jobs' own (S, T, 128)
+    uint32 lane tiles in (ops.rs_pallas.pack_lane_tiles: a job's bytes,
+    four to a word in host order), the (n, R, T, 128) stack of their
+    results out, every array's tile rows — so its columns — spread over
+    ALL devices of the mesh.  The codec service's device batch, for encode
+    (parity rows) and decode (plan rows) alike: each job's host array goes
+    to the devices as it is (no (n, S, B) block is ever built on the
+    host), both ways in the layout the device keeps (an 8-bit array would
+    be re-laid byte by byte on the host, in and out), and the XOR network
+    runs job after job on the packed words inside the one program, so its
+    temporaries are one job's whatever n is (a vmapped block program's
+    grow with V: at V = 8 of the encoder's slices the compiler refuses it
+    on one v5e)."""
     apply_one = make_apply_xor(rows)
 
-    def gf_apply(*blocks: jax.Array) -> jax.Array:  # n x (S, B) -> (n, R, B)
-        return jnp.stack([apply_one(block) for block in blocks])
+    def gf_apply(*tiles: jax.Array) -> jax.Array:
+        return jnp.stack([apply_one(t) for t in tiles])
 
     # the program's name on the device plane of a trace
     # (`jit_gf_apply_r4_s10`): stable across a change of kernel, and it
@@ -131,16 +148,30 @@ def _sharded_apply_jobs(mesh: Mesh, rows: tuple[tuple[int, ...], ...],
     cols = mesh.axis_names
     return jax.jit(
         gf_apply,
-        in_shardings=(NamedSharding(mesh, P(None, cols)),) * n,
-        out_shardings=NamedSharding(mesh, P(None, None, cols)))
+        in_shardings=(NamedSharding(mesh, P(None, cols, None)),) * n,
+        out_shardings=NamedSharding(mesh, P(None, None, cols, None)))
 
 
-def jobs_apply_sharded(mesh: Mesh, matrix: np.ndarray, blocks) -> jax.Array:
+def _lane_tile_shape(mesh: Mesh, shape: tuple) -> tuple:
+    """The (S, T, 128) of an (S, B) uint8 job on this mesh."""
+    s, b = shape
+    if b % (TILE_BYTES * mesh.size):
+        raise ValueError(
+            f"job width {b} is no multiple of {TILE_BYTES} bytes x "
+            f"{mesh.size} devices")
+    return s, b // _ROW_BYTES, LANES
+
+
+def jobs_apply_sharded(mesh: Mesh, matrix: np.ndarray, blocks) -> PackedRows:
     """Apply one (R, S) GF matrix to each of ``blocks`` — equal-shape
-    (S, B) uint8 host arrays, B a multiple of the device count — in one
-    device program; -> (len(blocks), R, B).  Dispatch is async."""
-    return _sharded_apply_jobs(
-        mesh, _rows_of(np.asarray(matrix)), len(blocks))(*blocks)
+    C-contiguous (S, B) uint8 host arrays, B a multiple of 4096 x the
+    device count — in one device program over views of them; ->
+    ``PackedRows`` of (len(blocks), R, B).  Dispatch is async."""
+    _lane_tile_shape(mesh, blocks[0].shape)
+    tiles = [pack_lane_tiles(b, _TILE_ROWS * mesh.size) for b in blocks]
+    return PackedRows(
+        _sharded_apply_jobs(mesh, _rows_of(np.asarray(matrix)), len(tiles))(
+            *tiles), blocks[0].shape[1])
 
 
 def compile_jobs_apply(mesh: Mesh, matrix: np.ndarray, n: int,
@@ -148,8 +179,9 @@ def compile_jobs_apply(mesh: Mesh, matrix: np.ndarray, n: int,
     """Compile, and run nothing, the program ``jobs_apply_sharded`` takes
     for n jobs of this (S, B) shape: the first real batch of that many
     then finds it compiled."""
+    tiles = jax.ShapeDtypeStruct(_lane_tile_shape(mesh, shape), jnp.uint32)
     _sharded_apply_jobs(mesh, _rows_of(np.asarray(matrix)), n).lower(
-        *[jax.ShapeDtypeStruct(shape, jnp.uint8)] * n).compile()
+        *[tiles] * n).compile()
 
 
 # ---------------------------------------------------------------------------

@@ -2,6 +2,10 @@
 
 from __future__ import annotations
 
+import json
+import os
+import subprocess
+import sys
 import threading
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
@@ -175,3 +179,17 @@ def start_master_cluster(base_dir: str, **kw):
             return leaders[0], masters
         time.sleep(0.05)
     raise AssertionError("master quorum elected no leader")
+
+
+def run_four_device_child(script: str) -> dict:
+    """Run ``script`` in a child whose CPU backend has four devices — a
+    codec service there builds its mesh from `jax.devices()`, as a server
+    on four chips does — and return the JSON object of its last line."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    proc = subprocess.run(
+        [sys.executable, "-c", script], env=env, capture_output=True,
+        text=True, timeout=600, cwd=root)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    return json.loads(proc.stdout.strip().splitlines()[-1])

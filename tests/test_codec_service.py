@@ -188,7 +188,7 @@ def test_device_mode_identity_parity_and_apply():
     svc = CodecService(mode="device", codec_name="tpu_xor")
     rng = np.random.default_rng(6)
     futs, expect = [], []
-    for w in (64, 200, 256, 1000):  # spans two width buckets
+    for w in (64, 200, 32768, 40000):  # spans two width buckets
         block = _rand_block(rng, w)
         futs.append(svc.submit_parity(block))
         expect.append(rs.parity_of(block))
@@ -207,15 +207,15 @@ def test_device_mode_identity_parity_and_apply():
 # what reaches the jit, per shape of batch: (mesh devices, dp, job widths,
 # how a job is handed in) -> (path of every job, width of every block)
 _BLOCK_CASES = {
-    "one_job_at_bucket_width": (1, 1, (1024,), "2d", "direct", 1024),
-    "width_off_the_bucket": (1, 1, (1000,), "2d", "staged", 1024),
-    "list_of_rows": (1, 1, (1024,), "rows", "staged", 1024),
-    "strided_column_slice": (1, 1, (1024,), "strided", "staged", 1024),
-    "two_coalesced_jobs": (1, 1, (1024, 1024), "2d", "direct", 1024),
-    "two_jobs_off_the_bucket": (1, 1, (1000, 600), "2d", "staged", 1024),
+    "one_job_at_bucket_width": (1, 1, (16384,), "2d", "direct", 16384),
+    "width_off_the_bucket": (1, 1, (16000,), "2d", "staged", 16384),
+    "list_of_rows": (1, 1, (16384,), "rows", "staged", 16384),
+    "strided_column_slice": (1, 1, (16384,), "strided", "staged", 16384),
+    "two_coalesced_jobs": (1, 1, (16384, 16384), "2d", "direct", 16384),
+    "two_jobs_off_the_bucket": (1, 1, (16000, 9000), "2d", "staged", 16384),
     # columns go over every device of any mesh: no padding volume
-    "dp2_mesh": (2, 2, (1024,), "2d", "direct", 1024),
-    "four_devices": (4, 1, (1024, 1024), "2d", "direct", 1024),
+    "dp2_mesh": (2, 2, (16384,), "2d", "direct", 16384),
+    "four_devices": (4, 1, (16384, 16384), "2d", "direct", 16384),
 }
 _HAND_IN = {
     "2d": lambda d: d,

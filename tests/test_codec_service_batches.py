@@ -7,9 +7,7 @@ take compiled before it forms; eight encodes at once through one service."""
 
 from __future__ import annotations
 
-import json
 import os
-import subprocess
 import sys
 import threading
 import time
@@ -21,6 +19,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 from benchmark import reference_gf as ref  # noqa: E402
+from helpers import run_four_device_child  # noqa: E402
 
 from seaweedfs_tpu.ops import codec_service  # noqa: E402
 from seaweedfs_tpu.ops.codec_service import CodecService  # noqa: E402
@@ -32,7 +31,7 @@ from seaweedfs_tpu.stats.metrics import (  # noqa: E402
 from seaweedfs_tpu.storage.ec import encoder  # noqa: E402
 
 # unequal widths: whole buckets, a few bytes over one, a few under
-WIDTHS = [1024, 700, 1025, 256, 2048, 999, 1024, 300]
+WIDTHS = [16384, 11200, 16400, 4096, 32768, 15984, 16384, 4800]
 
 
 @pytest.fixture(autouse=True)
@@ -96,12 +95,12 @@ def test_batch_of_v_streams_equals_the_reference_job_by_job(v):
     assert sent == 10 * sum(CodecService._pad_width(w, 1) for w in WIDTHS[:v])
 
 
-def _batches_of(widths) -> int:
+def _batches_of(widths, devices: int = 1) -> int:
     """Batches the scheduler makes of jobs of these widths queued at once,
     one stream each: per bucket, its count's powers of two."""
     counts = {}
     for w in widths:
-        bucket = CodecService._pad_width(w, 1)
+        bucket = CodecService._pad_width(w, devices)
         counts[bucket] = counts.get(bucket, 0) + 1
     return sum(bin(n).count("1") for n in counts.values())
 
@@ -130,7 +129,7 @@ for v in (2, 3, 8):
     out[str(v)] = {"same": same, "batches": child.count - b0, "mesh": svc.mesh_shape(),
                    "pad_pct": 100.0 * (sent / (10 * sum(widths[:v])) - 1)}
     # a lone whole-bucket job still goes in as it is, on four devices too
-    lone = rng.integers(0, 256, (10, 1024), dtype=np.uint8)
+    lone = rng.integers(0, 256, (10, 16384), dtype=np.uint8)
     before = {p: c.value for p, c in __import__(
         "seaweedfs_tpu.ops.codec_service", fromlist=["x"])._INPUT_BYTES.items()}
     assert np.array_equal(np.stack([np.asarray(r) for r in svc.submit_parity(lone).result(120)]),
@@ -147,14 +146,8 @@ print(json.dumps(out))
 def four_device_run():
     """One child process whose CPU backend has four devices: the service
     builds its mesh from `jax.devices()`, as a server on four chips does."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               XLA_FLAGS="--xla_force_host_platform_device_count=4")
-    proc = subprocess.run(
-        [sys.executable, "-c",
-         _FOUR_DEVICE_CHILD % {"root": ROOT, "widths": WIDTHS}],
-        env=env, capture_output=True, text=True, timeout=600, cwd=ROOT)
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    return json.loads(proc.stdout.strip().splitlines()[-1])
+    return run_four_device_child(
+        _FOUR_DEVICE_CHILD % {"root": ROOT, "widths": WIDTHS})
 
 
 @pytest.mark.parametrize("v", [2, 3, 8])
@@ -164,7 +157,7 @@ def test_four_device_mesh_batches_equal_the_reference(four_device_run, v):
     assert got["same"] == [True] * v
     # columns over all four devices: no padding volume, whatever V is
     assert got["mesh"] == "1x4"
-    assert got["batches"] == _batches_of(WIDTHS[:v])
+    assert got["batches"] == _batches_of(WIDTHS[:v], 4)
     assert got["lone_direct"] is True
 
 
@@ -257,7 +250,7 @@ def test_a_lone_streams_slices_stay_whole_blocks():
     volume do not coalesce into a staged V = 2 block."""
     svc = _one_device_service()
     rng = np.random.default_rng(7)
-    datas = [rng.integers(0, 256, (10, 1024), dtype=np.uint8)
+    datas = [rng.integers(0, 256, (10, 4096), dtype=np.uint8)
              for _ in range(3)]
     before = {p: c.value for p, c in codec_service._INPUT_BYTES.items()}
     jobs0, batches0 = _jobs_per_batch()
@@ -271,7 +264,7 @@ def test_a_lone_streams_slices_stay_whole_blocks():
     svc.close()
     moved = {p: c.value - before[p]
              for p, c in codec_service._INPUT_BYTES.items()}
-    assert moved == {"direct": 3 * 10 * 1024, "staged": 0}
+    assert moved == {"direct": 3 * 10 * 4096, "staged": 0}
     jobs1, batches1 = _jobs_per_batch()
     assert (jobs1 - jobs0, batches1 - batches0) == (3, 3)
 
@@ -291,7 +284,7 @@ def test_staging_buffers_are_reused_and_their_padding_is_zero(monkeypatch):
     rng = np.random.default_rng(8)
     for _round in range(3):
         datas = [rng.integers(1, 256, (10, w), dtype=np.uint8)
-                 for w in (1000, 600)]
+                 for w in (4000, 2500)]
         release = _hold_scheduler(svc)
         try:
             futs = [svc.submit_parity(d, stream=i)
@@ -305,9 +298,59 @@ def test_staging_buffers_are_reused_and_their_padding_is_zero(monkeypatch):
     # two staging buffers served all six jobs
     assert len({addr for c in calls for addr, _ in c}) == 2
     for c in calls:
-        for (_addr, sent), width in zip(c, (1000, 600)):
-            assert sent.shape == (10, 1024)
+        for (_addr, sent), width in zip(c, (4000, 2500)):
+            assert sent.shape == (10, 4096)
             assert not sent[:, width:].any()  # zeroed again on every reuse
+
+
+def test_program_takes_views_and_jobs_get_views_of_one_readback(monkeypatch):
+    """Both bus crossings are copies of nothing on the host: the program
+    is handed uint32 lane-tile views of the jobs' own bytes (of the staging
+    buffer for a job off its bucket), and every job's result rows are
+    C-contiguous views into the ONE array the readback made."""
+    from seaweedfs_tpu.ops import rs_pallas
+    from seaweedfs_tpu.parallel import mesh as mesh_mod
+
+    handed, readbacks = [], []
+    real_program = mesh_mod._sharded_apply_jobs
+
+    def program(mesh, rows, n):
+        fn = real_program(mesh, rows, n)
+        return lambda *tiles: (handed.append(tiles), fn(*tiles))[1]
+
+    monkeypatch.setattr(mesh_mod, "_sharded_apply_jobs", program)
+    real_array = rs_pallas.PackedRows.__array__
+    monkeypatch.setattr(
+        rs_pallas.PackedRows, "__array__", lambda self, *a, **kw: (
+            readbacks.append(real_array(self, *a, **kw)), readbacks[-1])[1])
+    svc = _one_device_service()
+    rng = np.random.default_rng(31)
+    datas = [rng.integers(0, 256, (10, w), dtype=np.uint8)
+             for w in (65536, 65536, 65000, 65536)]
+    release = _hold_scheduler(svc)
+    try:
+        futs = [svc.submit_parity(d, stream=i) for i, d in enumerate(datas)]
+    finally:
+        release()
+    results = [fut.result(120) for fut in futs]
+    svc.close()
+    (tiles,), (readback,) = handed, readbacks  # one batch, one readback
+    for tile, data in zip(tiles, datas):
+        assert tile.dtype == np.uint32 and tile.shape == (10, 128, 128)
+        whole = data.shape[1] == 65536
+        assert np.shares_memory(tile, data) is whole
+        if whole:  # the caller's own buffer, from its first byte
+            assert tile.ctypes.data == data.ctypes.data
+    # the readback: the device's (V, R, T, 128) words viewed as bytes
+    assert readback.shape == (4, 4, 65536) and readback.dtype == np.uint8
+    assert not readback.flags["OWNDATA"]
+    for vi, (result, data) in enumerate(zip(results, datas)):
+        assert _same_as_reference(result, data)
+        assert result.shape == (4, data.shape[1])
+        assert not result.flags["OWNDATA"]
+        assert all(row.flags["C_CONTIGUOUS"] for row in result)
+        # where np.asarray put it, untouched since
+        assert result.ctypes.data == readback[vi].ctypes.data
 
 
 def test_open_streams_warm_every_program_once(monkeypatch):
@@ -324,7 +367,7 @@ def test_open_streams_warm_every_program_once(monkeypatch):
 
     monkeypatch.setattr(mesh_mod, "compile_jobs_apply", capture)
     svc = _one_device_service(max_batch=16)
-    _hold(svc, 10 * 16384, 4)  # and sixteen, the job cap, at 1024
+    _hold(svc, 10 * 16384, 4)  # and sixteen, the job cap, at 4096
     rng = np.random.default_rng(10)
 
     def submit(svc, width, stream):
@@ -334,32 +377,32 @@ def test_open_streams_warm_every_program_once(monkeypatch):
 
     # a stream open alone, and one that nobody opened, warm nothing
     with svc.stream("a"):
-        submit(svc, 1000, "a")
-        submit(svc, 1000, "nobody")
+        submit(svc, 4000, "a")
+        submit(svc, 4000, "nobody")
     assert programs == []
     with contextlib.ExitStack() as held:
         for name in "ab":
             held.enter_context(svc.stream(name))
         # two open: V = 1, 2 at the job's own bucket, before it is queued
-        submit(svc, 1000, "a")
-        assert sorted(programs) == [(1, (10, 1024)), (2, (10, 1024))]
+        submit(svc, 4000, "a")
+        assert sorted(programs) == [(1, (10, 4096)), (2, (10, 4096))]
         submit(svc, 9000, "b")
         assert sorted(programs) == sorted(
-            [(v, (10, w)) for w in (1024, 16384) for v in (1, 2)])
+            [(v, (10, w)) for w in (4096, 16384) for v in (1, 2)])
         for k in range(18):
             held.enter_context(svc.stream(k))
         # twenty open: as many volumes as the devices hold, and no more
-        submit(svc, 1024, "b")
+        submit(svc, 4096, "b")
         submit(svc, 16384, 3)
-        want = sorted([(v, (10, 1024)) for v in (1, 2, 4, 8, 16)]
+        want = sorted([(v, (10, 4096)) for v in (1, 2, 4, 8, 16)]
                       + [(v, (10, 16384)) for v in (1, 2, 4)])
         assert sorted(programs) == want
-        submit(svc, 1000, 4)
+        submit(svc, 4000, 4)
         submit(svc, 9000, "a")
         assert sorted(programs) == want  # nothing compiles twice
         host = CodecService(mode="host")
         with host.stream("x"), host.stream("y"):
-            submit(host, 1024, "x")
+            submit(host, 4096, "x")
         assert sorted(programs) == want  # host mode has no programs
         # a batch of a warmed shape finds its program compiled; one of a
         # shape nobody warmed (jobs of unopened streams) compiles when it
@@ -368,7 +411,7 @@ def test_open_streams_warm_every_program_once(monkeypatch):
 
         device.enable_compile_cache()
         for width, streams, compiles in (
-                (1024, (0, 1, 2, 3), False), (2048, "wxyz", True)):
+                (4096, (0, 1, 2, 3), False), (8192, "wxyz", True)):
             spent = device._stats["compile_seconds"]
             datas = [rng.integers(0, 256, (10, width), dtype=np.uint8)
                      for _ in range(4)]
@@ -532,7 +575,7 @@ def test_batch_spans_say_volumes_padding_and_mesh(monkeypatch):
     svc = _one_device_service()
     rng = np.random.default_rng(9)
     datas = [rng.integers(0, 256, (10, w), dtype=np.uint8)
-             for w in (1024, 1000)]
+             for w in (4096, 4000)]
     release = _hold_scheduler(svc)
     try:
         futs = [svc.submit_parity(d, stream=i) for i, d in enumerate(datas)]
@@ -545,5 +588,8 @@ def test_batch_spans_say_volumes_padding_and_mesh(monkeypatch):
     for name in ("ec.svc.build", "ec.svc.enqueue"):
         attrs = by_name[name]
         assert (attrs["volumes"], attrs["v_pad"], attrs["mesh"]) == (2, 2, "1x1")
-        assert attrs["jobs"] == 2 and attrs["bytes"] == 10 * 2024
+        assert attrs["jobs"] == 2 and attrs["bytes"] == 10 * 8096
     assert by_name["ec.svc.build"]["path"] == "mixed"  # one whole, one not
+    # the form a batch crossed the bus in, both ways
+    for name in ("ec.svc.enqueue", "ec.svc.d2h"):
+        assert by_name[name]["layout"] == "u32x128"

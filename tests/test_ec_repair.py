@@ -35,15 +35,27 @@ LARGE = 10000  # scaled-down block sizes, as in test_ec_pipeline.py
 SMALL = 100
 
 
-@pytest.fixture()
-def encoded_base(tmp_path):
-    vol = make_volume(str(tmp_path), n_needles=60, seed=21, max_size=3000)
+def _encoded(tmp_path, n_needles):
+    vol = make_volume(str(tmp_path), n_needles=n_needles, seed=21,
+                      max_size=3000)
     base = vol.file_name()
     vol.close()
     generate_ec_files(base, large_block_size=LARGE, small_block_size=SMALL,
                       codec_name="cpu", slice_size=1 << 20)
     write_sorted_file_from_idx(base)
     return base
+
+
+@pytest.fixture()
+def encoded_base(tmp_path):
+    return _encoded(tmp_path, 60)
+
+
+@pytest.fixture()
+def encoded_base_wide(tmp_path):
+    """Shards of several 4 KiB slices (a device's least lane tile) and a
+    tail."""
+    return _encoded(tmp_path, 200)
 
 
 def _shard_bytes(base):
@@ -93,7 +105,7 @@ def test_rebuild_byte_identity_device_codec(encoded_base, lost):
 
 
 def test_rebuild_via_device_service_direct_slices_and_buffer_ownership(
-        encoded_base, monkeypatch):
+        encoded_base_wide, monkeypatch):
     """Through a device-mode service on a one-device mesh: a full slice
     is the pooled buffer itself and reaches the device uncopied, the
     tail is staged, the shards come out byte-identical — and no pooled
@@ -105,13 +117,13 @@ def test_rebuild_via_device_service_direct_slices_and_buffer_ownership(
     from seaweedfs_tpu.parallel.mesh import make_mesh
     from seaweedfs_tpu.storage.ec import encoder
 
-    lost, slice_size = (0, 1, 2, 3), 1024
-    originals = _shard_bytes(encoded_base)
+    lost, slice_size = (0, 1, 2, 3), 4096
+    originals = _shard_bytes(encoded_base_wide)
     shard_size = len(originals[0])
     n_full, tail = divmod(shard_size, slice_size)
     assert n_full >= 4 and tail, "fixture must give full slices and a tail"
     for sid in lost:
-        os.remove(encoded_base + ecc.to_ext(sid))
+        os.remove(encoded_base_wide + ecc.to_ext(sid))
 
     # one job a batch, as the byte cap makes it at the served 160 MiB
     # slices (two queued toy slices would otherwise coalesce, and stage)
@@ -140,13 +152,13 @@ def test_rebuild_via_device_service_direct_slices_and_buffer_ownership(
     counted = codec_service._INPUT_BYTES
     before = {p: c.value for p, c in counted.items()}
     try:
-        rebuilt = rebuild_ec_files(encoded_base, codec_name="tpu_xor",
+        rebuilt = rebuild_ec_files(encoded_base_wide, codec_name="tpu_xor",
                                    slice_size=slice_size, service=svc)
     finally:
         svc.close()
     assert sorted(rebuilt) == sorted(lost)
     for sid in lost:
-        got = open(encoded_base + ecc.to_ext(sid), "rb").read()
+        got = open(encoded_base_wide + ecc.to_ext(sid), "rb").read()
         assert got == originals[sid], f"shard {sid} not byte-identical"
     assert len(owned) == n_full + 1 and all(f.done() for _, f in owned)
     assert written_early == []

@@ -211,7 +211,7 @@ def test_program_name_on_the_device_plane():
     mesh = make_mesh()
     for n in (1, 2):
         text = _sharded_apply_jobs(mesh, rows, n).lower(
-            *[np.zeros((10, 256), np.uint8)] * n).as_text()
+            *[np.zeros((10, 8 * mesh.size, 128), np.uint32)] * n).as_text()
         assert "jit_gf_apply_r4_s10" in text
 
 
