@@ -699,6 +699,19 @@ EC_ENCODES_INFLIGHT = REGISTRY.gauge(
     ".dat -> shard-file encodes in flight in this process",
 )
 
+# the pipelines' (10, slice) buffers come from one process-wide pool that
+# outlives an rpc (storage/ec/encoder.py): `fresh` is a buffer the pool
+# did not have, i.e. memory whose every page is still to be faulted in
+EC_SLICE_BUFFERS = REGISTRY.counter(
+    "seaweedfs_ec_slice_buffers_total",
+    "slice buffers the EC pipelines took, by where they came from",
+    labels=("pipeline", "source"),  # encode | rebuild; pooled | fresh
+)
+EC_SLICE_POOL_BYTES = REGISTRY.gauge(
+    "seaweedfs_ec_slice_pool_bytes",
+    "bytes of free EC slice buffers the process retains for the next slice",
+)
+
 # -- EC codec service (ops/codec_service.py) --------------------------------
 # one bounded queue between every GF caller (encode, rebuild, degraded
 # reads, bench) and the compute backend; the scheduler coalesces

@@ -19,6 +19,7 @@ import contextlib
 import os
 import threading
 import time
+from collections import deque
 
 import numpy as np
 
@@ -33,6 +34,8 @@ from ...stats.metrics import (
     EC_REBUILD_RESULT,
     EC_REBUILD_SECONDS,
     EC_REBUILD_SHARDS,
+    EC_SLICE_BUFFERS,
+    EC_SLICE_POOL_BYTES,
 )
 from ...telemetry import trace
 from ...util import faultpoint
@@ -47,8 +50,16 @@ from .constants import (
 
 # Device batch: bytes per shard per codec call (64 x 256KB reference batches)
 DEFAULT_SLICE = 16 * 1024 * 1024
-# slice buffers one pipelined encode keeps (see _encode_stream_pipelined)
+# slice buffers one pipelined encode holds at once: one in prefetch, two
+# with the codec, one being written.  The bound is the writer's
+# back-pressure on the reader and nothing more — the buffers are the
+# process's (_SlicePool) and outlive the encode
 _POOL_SLICES = 4
+# ... and a rebuild: one in prefetch, one in compute, one in the writer
+_REBUILD_SLICES = 3
+# seconds a free slice buffer may lie untaken before it is released: this
+# long after the process's last EC pipeline ended, the pool holds nothing
+_POOL_IDLE_S = 30.0
 
 # per-slice stage timings for the pipelined encode/rebuild: the pipeline
 # runs at max(stage), so bottleneck attribution = the widest histogram
@@ -58,6 +69,113 @@ _STAGE_DECODE = EC_PIPELINE_STAGE.labels("decode")
 _STAGE_WRITE = EC_PIPELINE_STAGE.labels("write")
 _BYTES_PREFETCH = EC_PIPELINE_BYTES.labels("prefetch")
 _BYTES_WRITE = EC_PIPELINE_BYTES.labels("write")
+_POOL_BYTES = EC_SLICE_POOL_BYTES.labels()
+
+
+class _SlicePool:
+    """The process's free (DATA_SHARDS, slice_size) uint8 buffers, by
+    slice_size: the one place an EC pipeline's slice buffers come from.
+
+    A fresh (10, 16 MiB) array is 40,960 pages to fault in and an
+    mmap/munmap pair, and every pipeline of the process takes its one
+    address-space lock for each.  Buffers that died with their pipeline
+    (numpy hands 160 MiB back with munmap) would have every rpc read its
+    first slices into never-touched memory; these outlive the rpc, so a
+    server that encodes or rebuilds volume after volume reads into pages
+    that are already mapped.  `take` hands out the most recently returned
+    buffer, so what a lighter load does not need lies still and ages.
+
+    Ownership: a submitted slice is the codec service's until its future
+    resolves, so a buffer comes back ONLY from a writer's success path,
+    after the parity is back and the slice's rows are in the shard files.
+    A pipeline that fails or is stopped drops what it holds — the pool
+    never hands out memory a device transfer may still be reading.
+
+    A reused buffer holds another volume's bytes: a pipeline writes every
+    byte it uses (fill_stripe_rows zero-fills past EOF, a tail slice is
+    buf[:, :total], the rebuild fills buf[:, :width]).
+
+    Bounded: a pipeline holds at most its own bound at once
+    (_PipelineBuffers), so the pool has no more than its pipelines held at
+    their peak (eight encodes: 32 buffers, 5 GiB, as before); a buffer
+    nobody took for _POOL_IDLE_S is released, so an idle server keeps
+    nothing."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        # slice_size -> (returned at, buffer), the longest free first
+        self._free: dict[int, deque] = {}
+        self._timer: "threading.Timer | None" = None
+
+    def take(self, slice_size: int) -> "tuple[np.ndarray, str]":
+        """-> (buffer, "pooled" | "fresh")."""
+        with self._lock:
+            free = self._free.get(slice_size)
+            if free:
+                buf = free.pop()[1]
+                _POOL_BYTES.dec(buf.nbytes)
+                return buf, "pooled"
+        return np.empty((DATA_SHARDS, slice_size), dtype=np.uint8), "fresh"
+
+    def give_back(self, buf: np.ndarray) -> None:
+        now = time.monotonic()
+        with self._lock:
+            self._free.setdefault(buf.shape[1], deque()).append((now, buf))
+            _POOL_BYTES.inc(buf.nbytes)
+            self._release_idle(now)
+
+    def _release_idle(self, now: float) -> None:
+        """Lock held.  Drops what has lain free for _POOL_IDLE_S and keeps
+        one timer pending while anything is free."""
+        oldest = None
+        for free in self._free.values():
+            while free and now - free[0][0] >= _POOL_IDLE_S:
+                _POOL_BYTES.dec(free.popleft()[1].nbytes)
+            if free and (oldest is None or free[0][0] < oldest):
+                oldest = free[0][0]
+        if oldest is not None and self._timer is None:
+            self._timer = threading.Timer(
+                max(oldest + _POOL_IDLE_S - now, 0.0), self.release_idle)
+            self._timer.daemon = True
+            self._timer.start()
+
+    def release_idle(self) -> None:
+        """What the timer runs."""
+        with self._lock:
+            self._timer = None
+            self._release_idle(time.monotonic())
+
+
+_SLICE_POOL = _SlicePool()
+
+
+class _PipelineBuffers:
+    """One pipeline's hold on the pool: at most `bound` buffers at once.  A
+    reader that asks for one more waits for its writer to give one back,
+    which is the pipeline's back-pressure."""
+
+    def __init__(self, pipeline: str, slice_size: int, bound: int,
+                 stop: threading.Event):
+        self._slice_size, self._stop = slice_size, stop
+        self._slots = threading.Semaphore(bound)
+        self._taken = {source: EC_SLICE_BUFFERS.labels(pipeline, source)
+                       for source in ("pooled", "fresh")}
+
+    def take(self) -> "tuple[np.ndarray | None, str]":
+        """-> (buffer, "pooled" | "fresh"); no buffer once the consumer
+        has bailed (a failed writer gives nothing back, so a bare wait
+        would strand the reader and wedge the pipeline's join)."""
+        while not self._stop.is_set():
+            if self._slots.acquire(timeout=0.1):
+                buf, source = _SLICE_POOL.take(self._slice_size)
+                self._taken[source].inc()
+                return buf, source
+        return None, ""
+
+    def give_back(self, buf: np.ndarray) -> None:
+        """For the writer's success path alone (see _SlicePool)."""
+        _SLICE_POOL.give_back(buf)
+        self._slots.release()
 
 
 _encodes_lock = threading.Lock()
@@ -367,7 +485,6 @@ def _encode_stream_pipelined(
     view, so the device program is exactly the kernel).
     """
     import queue
-    import threading
 
     is_device_codec = hasattr(codec, "encode_device")
     vid = os.path.basename(f.name).rsplit(".", 1)[0]  # the spans' `vid`
@@ -385,44 +502,23 @@ def _encode_stream_pipelined(
                 continue
         return False
 
-    # pooled slice buffers, recycled by the writer once a slice's rows are
-    # in the shard files (the codec service owns a submitted slice until
-    # its parity is back, which is earlier): a fresh (10, 16 MiB) array per
-    # slice is 40,960 page faults and an mmap/munmap pair, and eight
-    # encodes at once take the process's one address-space lock for each.
-    # Four cover a slice in prefetch, two with the codec and one being
-    # written (the rebuild keeps three); allocated as the pipeline first
-    # needs them, so a small volume takes few.  Eight encodes at once keep
-    # 5 GiB between them: what they hold is what the host's write cache
-    # cannot.
-    pool: queue.Queue = queue.Queue()
-    made = 0
-
-    def _get_buffer() -> "np.ndarray | None":
-        """A free slice buffer; None once the consumer has bailed."""
-        nonlocal made
-        while not stop.is_set():
-            try:
-                return pool.get_nowait() if made < _POOL_SLICES else pool.get(
-                    timeout=0.1)
-            except queue.Empty:
-                if made < _POOL_SLICES:
-                    made += 1
-                    return np.empty((DATA_SHARDS, slice_size), dtype=np.uint8)
-        return None
+    # slice buffers from the process's pool, given back by the writer once
+    # a slice's rows are in the shard files (_SlicePool has the rules)
+    buffers = _PipelineBuffers("encode", slice_size, _POOL_SLICES, stop)
 
     def reader() -> None:
         try:
             for batch in _slice_tasks(dat_size, large, small, slice_size):
                 total = sum(seg[3] for seg in batch)
-                buf = _get_buffer()  # a wait for the writer is no prefetch
+                # a wait for the writer is no prefetch
+                buf, source = buffers.take()
                 if buf is None:
                     return
                 # a full slice is the buffer itself: one contiguous block
                 # the service can hand to the device as it is
                 data = buf if total == slice_size else buf[:, :total]
                 with trace.stage("ec.pipeline.prefetch", _STAGE_PREFETCH,
-                                 vid=vid, offset=batch[0][0]):
+                                 vid=vid, offset=batch[0][0], buffer=source):
                     fill_stripe_rows(f, batch, data)
                 _BYTES_PREFETCH.inc(data.nbytes)  # zero fill past EOF included
                 if not _put(data):
@@ -474,7 +570,7 @@ def _encode_stream_pipelined(
                         outs[DATA_SHARDS + pi].write(prow)
                 _BYTES_WRITE.inc(data.shape[1] * (DATA_SHARDS + len(parity)))
                 done += data.shape[1] * DATA_SHARDS
-                pool.put(data if data.base is None else data.base)
+                buffers.give_back(data if data.base is None else data.base)
                 if progress is not None:
                     progress(min(done, dat_size))
             except Exception as e:  # surfaced by the main thread
@@ -496,8 +592,6 @@ def _encode_stream_pipelined(
         wq.put((data, parity))
         if write_err:
             raise write_err[0]
-
-    from collections import deque
 
     # service dispatch is a queue submit, so TWO slices ride in flight
     # (the service double-buffers H2D against compute against D2H);
@@ -707,7 +801,6 @@ def rebuild_ec_files(base_name: str, codec_name: str = "cpu",
     reconstructed slice hits the output files.
     """
     import queue
-    import threading
     from concurrent.futures import ThreadPoolExecutor
 
     from ...util.executors import MeteredThreadPoolExecutor
@@ -805,9 +898,10 @@ def rebuild_ec_files(base_name: str, codec_name: str = "cpu",
     t_start = time.perf_counter()
     vid = os.path.basename(base_name)  # the spans' `vid`
 
-    pool: queue.Queue = queue.Queue()
     q: queue.Queue = queue.Queue(maxsize=2)
     stop = threading.Event()
+    # slice buffers from the process's pool, as the encode's (_SlicePool)
+    buffers = _PipelineBuffers("rebuild", slice_size, _REBUILD_SLICES, stop)
 
     def _put(item) -> bool:
         while not stop.is_set():
@@ -831,17 +925,6 @@ def rebuild_ec_files(base_name: str, codec_name: str = "cpu",
         _pread_into(ins[sid].fileno(), dest, off)
         return 0
 
-    def _get_buffer():
-        """Stop-aware pool.get: a failed writer stops recycling buffers,
-        so a bare blocking get could strand this thread forever and wedge
-        the finally's join."""
-        while not stop.is_set():
-            try:
-                return pool.get(timeout=0.1)
-            except queue.Empty:
-                continue
-        return None
-
     part_on = [use_partial]  # sticky: one failure drops to full fetch
 
     def _fetch_partial(off: int, width: int) -> "np.ndarray | None":
@@ -863,11 +946,11 @@ def rebuild_ec_files(base_name: str, codec_name: str = "cpu",
             for off in range(0, shard_size, slice_size):
                 width = min(slice_size, shard_size - off)
                 faultpoint.inject(FP_REBUILD_READ, ctx=base_name)
-                buf = _get_buffer()
+                buf, source = buffers.take()
                 if buf is None:
                     return
                 with trace.stage("ec.pipeline.prefetch", _STAGE_PREFETCH,
-                                 vid=vid, offset=off):
+                                 vid=vid, offset=off, buffer=source):
                     part = _fetch_partial(off, width)
                     if part is not None:
                         # only the LOCAL source rows are read here; the
@@ -911,7 +994,7 @@ def rebuild_ec_files(base_name: str, codec_name: str = "cpu",
                     for row, sid in zip(rebuilt, missing):
                         outs[sid].write(row)
                 _BYTES_WRITE.inc(len(missing) * width)
-                pool.put(buf)  # source slice fully consumed: recycle
+                buffers.give_back(buf)  # source slice fully consumed
                 if progress is not None:
                     progress(off + width)
             except Exception as e:  # surfaced by the main thread
@@ -938,8 +1021,6 @@ def rebuild_ec_files(base_name: str, codec_name: str = "cpu",
         if write_err:
             raise write_err[0]
 
-    from collections import deque
-
     # service submits are queue hops, so two slices ride in flight (the
     # service double-buffers); direct device dispatch keeps one async
     async_mode = is_device_codec or service is not None
@@ -952,10 +1033,6 @@ def rebuild_ec_files(base_name: str, codec_name: str = "cpu",
                 ins[i] = open(base_name + to_ext(i), "rb")
         for i in missing:
             outs[i] = open(base_name + to_ext(i), "wb")
-        # pooled slice buffers: 3 covers one in prefetch, one in compute,
-        # one in the writer, with no per-slice (10, W) allocation churn
-        for _ in range(3):
-            pool.put(np.empty((DATA_SHARDS, slice_size), dtype=np.uint8))
         fetch_pool = MeteredThreadPoolExecutor(
             max_workers=DATA_SHARDS, name="ec_rebuild_read",
             thread_name_prefix="ec-rebuild-read")

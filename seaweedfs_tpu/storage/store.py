@@ -97,6 +97,9 @@ class Store:
         # heartbeat NOW instead of on the next pulse — the master must
         # stop assigning to a full disk within one beat
         self.on_disk_event = None
+        # fired when an EC shard delta is queued (mount / un-mount), so the
+        # volume server's heartbeat can carry it to the master at once
+        self.on_ec_delta = None
         # hot-needle cache: repeated small-file GETs skip needle-map
         # lookup, disk read and CRC parse.  Per-store (never process
         # global: two in-process test clusters may reuse volume ids);
@@ -577,6 +580,12 @@ class Store:
                     shard_size=shard_size,
                 )
             )
+            self._ec_delta_queued()
+
+    def _ec_delta_queued(self) -> None:
+        cb = self.on_ec_delta
+        if cb is not None:
+            cb()
 
     def _location_for_base(self, base: str) -> DiskLocation:
         d = os.path.dirname(base)
@@ -599,6 +608,7 @@ class Store:
                     ec_index_bits=int(_bits(shard_ids)),
                 )
             )
+            self._ec_delta_queued()
             if not ev.shards:
                 for loc in self.locations:
                     if loc.ec_volumes.get(vid) is ev:

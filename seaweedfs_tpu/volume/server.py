@@ -160,7 +160,19 @@ class VolumeServer:
         # master must stop assigning to the full disk within one beat,
         # not one pulse later
         self._beat_now = threading.Event()
-        self.store.on_disk_event = self._beat_now.set
+        # what the heartbeat generator sleeps on.  A disk fault wakes it,
+        # and so does an EC shard delta (mount / un-mount): the master's
+        # view of a volume's shards is then one beat behind the volume
+        # server's, not one tick of up to a second — an `ec.rebuild` that
+        # follows a loss or a repair plans against what is on the disks
+        # (upstream's loop selects on its New/DeletedEcShardsChan)
+        self._wake = threading.Event()
+        self.store.on_disk_event = self._full_beat_now
+        self.store.on_ec_delta = self._wake.set
+
+    def _full_beat_now(self) -> None:
+        self._beat_now.set()
+        self._wake.set()
 
     # -- lifecycle --------------------------------------------------------
 
@@ -331,7 +343,8 @@ class VolumeServer:
             yield self._with_stats(self.store.collect_heartbeat())
             last_full = time.monotonic()
             while not self._stop.is_set():
-                self._beat_now.wait(min(self.pulse_seconds / 3, 1.0))
+                self._wake.wait(min(self.pulse_seconds / 3, 1.0))
+                self._wake.clear()  # before the drain: a later delta re-sets it
                 nv, dv, ne, de = self.store.drain_deltas()
                 if nv or dv or ne or de:
                     yield master_pb2.Heartbeat(

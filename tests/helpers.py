@@ -193,3 +193,16 @@ def run_four_device_child(script: str) -> dict:
         text=True, timeout=600, cwd=root)
     assert proc.returncode == 0, proc.stderr[-3000:]
     return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+def empty_slice_pool(monkeypatch, pool=None) -> None:
+    """Release every free buffer of the EC pipelines' slice pool (the
+    process's, unless one is given), so the gauge
+    `seaweedfs_ec_slice_pool_bytes` reads what a test does next."""
+    from seaweedfs_tpu.storage.ec import encoder
+
+    period = encoder._POOL_IDLE_S
+    monkeypatch.setattr(encoder, "_POOL_IDLE_S", 0.0)
+    (pool or encoder._SLICE_POOL).release_idle()
+    monkeypatch.setattr(encoder, "_POOL_IDLE_S", period)
+

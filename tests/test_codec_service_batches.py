@@ -19,7 +19,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 from benchmark import reference_gf as ref  # noqa: E402
-from helpers import run_four_device_child  # noqa: E402
+from helpers import empty_slice_pool, run_four_device_child  # noqa: E402
 
 from seaweedfs_tpu.ops import codec_service  # noqa: E402
 from seaweedfs_tpu.ops.codec_service import CodecService  # noqa: E402
@@ -27,6 +27,8 @@ from seaweedfs_tpu.stats.metrics import (  # noqa: E402
     EC_ENCODES_INFLIGHT,
     EC_SERVICE_BATCH_JOBS,
     EC_SERVICE_BLOCK_BYTES,
+    EC_SLICE_BUFFERS,
+    EC_SLICE_POOL_BYTES,
 )
 from seaweedfs_tpu.storage.ec import encoder  # noqa: E402
 
@@ -563,6 +565,215 @@ def test_encode_recycles_six_slice_buffers_and_never_one_in_use(
     assert not filled_early
     assert _shard_bytes(str(tmp_path / "pooled")) == _shard_bytes(
         str(tmp_path / "plain"))
+
+
+# -- the process's slice pool (ISSUE 34) ------------------------------------------------
+
+GEOM = dict(large_block_size=8192, small_block_size=512)
+SLICE = 2048  # four 512-byte rows to a slice
+
+
+@pytest.fixture
+def slice_pool(monkeypatch):
+    """A pool of this test's own.  The process's is emptied first and this
+    one afterwards, so the gauge reads this one alone."""
+    empty_slice_pool(monkeypatch)
+    pool = encoder._SlicePool()
+    monkeypatch.setattr(encoder, "_SLICE_POOL", pool)
+    yield pool
+    empty_slice_pool(monkeypatch, pool)
+
+
+def _slices(dat_size: int) -> int:
+    return len(list(encoder._slice_tasks(
+        dat_size, GEOM["large_block_size"], GEOM["small_block_size"], SLICE)))
+
+
+def _free_ids(pool, slice_size=SLICE) -> set:
+    return {id(buf) for _at, buf in pool._free.get(slice_size, ())}
+
+
+def _taken(pipeline: str) -> dict:
+    return {source: EC_SLICE_BUFFERS.labels(pipeline, source).value
+            for source in ("pooled", "fresh")}
+
+
+def _pool_bytes() -> float:
+    return EC_SLICE_POOL_BYTES.labels().value
+
+
+def _filled_buffers(monkeypatch) -> list:
+    """-> the list every fill_stripe_rows call appends its slice buffer to."""
+    filled = []
+    real_fill = encoder.fill_stripe_rows
+    monkeypatch.setattr(encoder, "fill_stripe_rows", lambda f, batch, dest: (
+        filled.append(dest if dest.base is None else dest.base),
+        real_fill(f, batch, dest))[1])
+    return filled
+
+
+def _host_shards(tmp_path, name: str) -> list:
+    """What the host codec (the mmap path: no pool) writes for <name>.dat."""
+    os.link(tmp_path / f"{name}.dat", tmp_path / f"{name}_host.dat")
+    encoder.generate_ec_files(str(tmp_path / f"{name}_host"),
+                              codec_name="cpu", slice_size=SLICE, **GEOM)
+    return _shard_bytes(str(tmp_path / f"{name}_host"))
+
+
+def test_second_encode_reads_into_the_firsts_buffers(
+        tmp_path, monkeypatch, slice_pool):
+    """Two encodes in a row through the service, the pool holding 0xFF
+    buffers before the first and the first volume's bytes before the
+    second, which is shorter and ends past EOF: every byte used is written
+    first, and no buffer is made."""
+    for _ in range(encoder._POOL_SLICES):
+        slice_pool.give_back(np.full((10, SLICE), 0xFF, dtype=np.uint8))
+    ours = _free_ids(slice_pool)
+    _make_dat(str(tmp_path / "first.dat"), 600_000, seed=1)
+    _make_dat(str(tmp_path / "second.dat"), 123_457, seed=2)  # a 1-row tail
+    want = {name: _host_shards(tmp_path, name) for name in ("first", "second")}
+    filled = _filled_buffers(monkeypatch)
+    svc = _one_device_service()
+    before = _taken("encode")
+    used = {}
+    for name in ("first", "second"):
+        del filled[:]
+        encoder.generate_ec_files(str(tmp_path / name), codec_name="tpu_xor",
+                                  slice_size=SLICE, service=svc, **GEOM)
+        used[name] = {id(buf) for buf in filled}
+        assert _shard_bytes(str(tmp_path / name)) == want[name], name
+    svc.close()
+    after = _taken("encode")
+    assert after["fresh"] == before["fresh"]
+    assert after["pooled"] - before["pooled"] == sum(  # every slice
+        _slices(size) for size in (600_000, 123_457))
+    assert used["second"] <= used["first"] <= ours
+    assert _free_ids(slice_pool) == ours
+    assert _pool_bytes() == len(ours) * 10 * SLICE
+
+
+def test_rebuild_takes_the_encodes_buffers(tmp_path, slice_pool):
+    _make_dat(str(tmp_path / "vol.dat"), 300_000, seed=3)
+    base = str(tmp_path / "vol")
+    svc = _one_device_service()
+    encoder.generate_ec_files(base, codec_name="tpu_xor", slice_size=SLICE,
+                              service=svc, **GEOM)
+    want, left = _shard_bytes(base), _free_ids(slice_pool)
+    assert 1 <= len(left) <= encoder._POOL_SLICES
+    for sid in (0, 1, 2, 3):
+        os.remove(f"{base}.ec{sid:02d}")
+    before = _taken("rebuild")
+    assert encoder.rebuild_ec_files(
+        base, codec_name="tpu_xor", slice_size=SLICE, service=svc) == [
+            0, 1, 2, 3]
+    svc.close()
+    after = _taken("rebuild")
+    assert after["fresh"] == before["fresh"]
+    assert after["pooled"] > before["pooled"]
+    assert _free_ids(slice_pool) == left
+    assert _shard_bytes(base) == want
+
+
+@pytest.mark.parametrize("failing", [0, 3])
+def test_failed_encode_gives_back_nothing_it_still_held(
+        tmp_path, monkeypatch, slice_pool, failing):
+    """The service raises for slice `failing`: only slices whose rows the
+    writer had put in the shard files gave their buffers back; what the
+    pipeline held when it failed is dropped; the next encode is right."""
+    from concurrent.futures import Future
+
+    _make_dat(str(tmp_path / "vol.dat"), 300_000, seed=4)
+    want = _host_shards(tmp_path, "vol")
+    filled = _filled_buffers(monkeypatch)
+    svc = _one_device_service()
+    real_submit, calls = svc.submit_parity, []
+
+    def submit(data, out=None, stream=None):
+        calls.append(data)
+        if len(calls) - 1 == failing:
+            fut = Future()
+            fut.set_exception(RuntimeError("the device is gone"))
+            return fut
+        return real_submit(data, out, stream)
+
+    monkeypatch.setattr(svc, "submit_parity", submit)
+    written = []
+    with pytest.raises(RuntimeError, match="the device is gone"):
+        encoder.generate_ec_files(
+            str(tmp_path / "vol"), codec_name="tpu_xor", slice_size=SLICE,
+            service=svc, progress=written.append, **GEOM)
+    assert len(written) <= failing  # slices are written in order
+    held = {id(buf) for buf in filled[len(written):]}
+    assert held and not (_free_ids(slice_pool) & held)
+    assert len(_free_ids(slice_pool)) <= len(written)
+    assert _pool_bytes() == len(_free_ids(slice_pool)) * 10 * SLICE
+    monkeypatch.setattr(svc, "submit_parity", real_submit)
+    encoder.generate_ec_files(str(tmp_path / "vol"), codec_name="tpu_xor",
+                              slice_size=SLICE, service=svc, **GEOM)
+    svc.close()
+    assert _shard_bytes(str(tmp_path / "vol")) == want
+
+
+def test_eight_encodes_at_once_make_at_most_four_buffers_each(
+        tmp_path, slice_pool):
+    """Between the pool and eight pipelines there are never more than
+    8 x _POOL_SLICES buffers: no more than that are ever made, and a
+    second round of eight makes none."""
+    n = 8
+    for k in range(n):
+        _make_dat(str(tmp_path / f"v{k}.dat"), 200_000 + 4321 * k, seed=k)
+    want = [_host_shards(tmp_path, f"v{k}") for k in range(n)]
+    errors = []
+
+    def encode(k):
+        try:  # a device codec with no service: the pipelined path, direct
+            encoder.generate_ec_files(
+                str(tmp_path / f"v{k}"), codec_name="tpu_xor",
+                slice_size=SLICE, **GEOM)
+        except Exception as e:  # noqa: BLE001 — reported below
+            errors.append(e)
+
+    before = _taken("encode")
+    for round_ in range(2):
+        threads = [threading.Thread(target=encode, args=(k,))
+                   for k in range(n)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(300)
+        assert not errors, errors
+        made = _taken("encode")["fresh"] - before["fresh"]
+        free = len(_free_ids(slice_pool))
+        assert n <= free == made <= n * encoder._POOL_SLICES, round_
+        assert _pool_bytes() == free * 10 * SLICE
+        if round_ == 0:
+            made_first = made
+    assert made == made_first
+    for k in range(n):
+        assert _shard_bytes(str(tmp_path / f"v{k}")) == want[k], k
+
+
+def test_idle_pool_is_released_after_the_fixed_period(
+        tmp_path, monkeypatch, slice_pool):
+    monkeypatch.setattr(encoder, "_POOL_IDLE_S", 0.3)
+    _make_dat(str(tmp_path / "vol.dat"), 100_000, seed=5)
+
+    def encode():
+        encoder.generate_ec_files(str(tmp_path / "vol"), codec_name="tpu_xor",
+                                  slice_size=SLICE, **GEOM)
+
+    encode()
+    assert _pool_bytes() > 0 and _free_ids(slice_pool)
+    deadline = time.monotonic() + 10
+    while _pool_bytes() and time.monotonic() < deadline:
+        time.sleep(0.05)
+    assert _pool_bytes() == 0 and not _free_ids(slice_pool)
+    assert slice_pool._timer is None  # nothing free: nothing pending
+    before = _taken("encode")
+    encode()  # an encode an hour later pays what it paid before the pool
+    after = _taken("encode")
+    assert after["fresh"] > before["fresh"]
+    assert sum(after.values()) - sum(before.values()) == _slices(100_000)
 
 
 def test_batch_spans_say_volumes_padding_and_mesh(monkeypatch):

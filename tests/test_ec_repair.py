@@ -475,3 +475,57 @@ def test_degraded_reads_spawn_no_new_threads(encoded_base):
     assert others <= baseline, \
         "degraded reads must not spawn threads per call"
     ev.close()
+
+
+def test_ec_shard_deltas_reach_the_master_without_waiting_for_a_tick(
+        encoded_base, tmp_path):
+    """An un-mount and a mount wake the volume server's heartbeat: the
+    master's view follows within a beat, although the generator's sleep
+    (made 60 s here, where a server's is up to 1 s) never runs out."""
+    import shutil
+
+    from helpers import free_port, start_master_cluster
+    from seaweedfs_tpu.volume.server import VolumeServer
+
+    class NeverTicks(threading.Event):
+        def wait(self, timeout=None):
+            return super().wait(60.0)
+
+    def holders(vid):
+        return {sid for sid, nodes in
+                master.topo.lookup_ec_shards(vid).items() if nodes}
+
+    def wait_for(want):
+        deadline = time.monotonic() + 20
+        while holders(7) != want and time.monotonic() < deadline:
+            time.sleep(0.01)
+        assert holders(7) == want
+
+    master, masters = start_master_cluster(str(tmp_path))
+    vdir = tmp_path / "vs"
+    vdir.mkdir()
+    vs = VolumeServer(directories=[str(vdir)],
+                      master_addresses=[f"127.0.0.1:{master.grpc_port}"],
+                      ip="127.0.0.1", port=free_port())
+    vs._wake = NeverTicks()
+    vs.store.on_ec_delta = vs._wake.set
+    vs.start()
+    try:
+        tbase = vs.store.locations[0].base_name(7, "")
+        shutil.copy(encoded_base + ".ecx", tbase + ".ecx")
+        for sid in range(ecc.TOTAL_SHARDS):
+            shutil.copy(encoded_base + ecc.to_ext(sid), tbase + ecc.to_ext(sid))
+        every = set(range(ecc.TOTAL_SHARDS))
+        vs.store.mount_ec_shards(7, "", sorted(every))
+        wait_for(every)
+        vs.store.unmount_ec_shards(7, [0, 1, 2, 3])
+        wait_for(every - {0, 1, 2, 3})
+        vs.store.mount_ec_shards(7, "", [0, 1, 2, 3])
+        wait_for(every)
+        # a disk fault still forces the full beat, through the same sleep
+        vs.store.on_disk_event()
+        assert vs._beat_now.is_set() and vs._wake.is_set()
+    finally:
+        vs.stop()
+        for m in masters:
+            m.stop()

@@ -22,10 +22,13 @@ sys.path.insert(0, ROOT)
 
 from benchmark import harness as hz  # noqa: E402
 from benchmark import readers, run, trace_reduce  # noqa: E402
+from helpers import empty_slice_pool  # noqa: E402
 from seaweedfs_tpu.ops.codec_service import CodecService  # noqa: E402
 from seaweedfs_tpu.stats.metrics import (  # noqa: E402
     EC_PIPELINE_BYTES,
     EC_SERVICE_STAGE,
+    EC_SLICE_BUFFERS,
+    EC_SLICE_POOL_BYTES,
     HTTPD_DISPATCH_WAIT,
     HTTPD_RESIDENT,
     REGISTRY,
@@ -279,6 +282,74 @@ def test_pipeline_bytes_write_is_1_4_times_prefetch(tmp_path):
     assert rebuild_ec_files(base, codec_name="tpu_xor",
                             slice_size=1 << 19) == [0, 11]
     assert (pre.value - p0, wr.value - w0) == (10 << 20, 2 << 20)
+
+
+# -- the pipelines' slice buffers (ISSUE 34) -----------------------------------
+
+
+def test_prefetch_span_and_counter_say_where_a_slice_buffer_came_from(
+        tmp_path, monkeypatch):
+    """The `ec.pipeline.prefetch` span's `buffer`, the counter's two
+    labels, the gauge, and the one benchmark metric that reads them."""
+    from seaweedfs_tpu.storage.ec import encoder
+
+    empty_slice_pool(monkeypatch)
+    seen = []
+    real_stage = trace.stage
+    monkeypatch.setattr(trace, "stage", lambda name, hist=None, **attrs: (
+        seen.append((name, attrs)), real_stage(name, hist, **attrs))[1])
+    base = str(tmp_path / "7")
+    np.random.default_rng(5).integers(
+        0, 256, 3 << 20, dtype=np.uint8).tofile(base + ".dat")
+    counts = {(p, s): EC_SLICE_BUFFERS.labels(p, s)
+              for p in ("encode", "rebuild") for s in ("pooled", "fresh")}
+    before = {k: c.value for k, c in counts.items()}
+
+    def work():
+        encoder.write_ec_files(base, codec_name="tpu_xor", slice_size=1 << 18)
+        os.remove(base + ".ec05")
+        encoder.rebuild_ec_files(base, codec_name="tpu_xor",
+                                 slice_size=1 << 18)
+
+    obs = _obs_over(work, ("window",))
+    took = {k: c.value - before[k] for k, c in counts.items()}
+    buffers = [attrs["buffer"] for name, attrs in seen
+               if name == "ec.pipeline.prefetch"]
+    assert all("buffer" not in attrs for name, attrs in seen
+               if name != "ec.pipeline.prefetch")
+    # four slices, each of four 1 MiB rows a quarter wide, both ways
+    assert len(buffers) == 8 and set(buffers) == {"pooled", "fresh"}
+    assert Counter(buffers) == {
+        "fresh": took["encode", "fresh"] + took["rebuild", "fresh"],
+        "pooled": took["encode", "pooled"] + took["rebuild", "pooled"]}
+    assert 1 <= took["encode", "fresh"] <= encoder._POOL_SLICES
+    assert took["encode", "fresh"] + took["encode", "pooled"] == 4
+    assert took["rebuild", "fresh"] == 0 and took["rebuild", "pooled"] == 4
+    free = EC_SLICE_POOL_BYTES.labels().value
+    assert free == took["encode", "fresh"] * 10 * (1 << 18)
+    assert 'seaweedfs_ec_slice_buffers_total{pipeline="encode",source="fresh"}' \
+        in REGISTRY.render()
+    entry = BENCH["per_layer"][-1]
+    assert entry == {
+        "name": "ec_slice_fresh_pct.encode", "unit": "%", "better": "lower",
+        "source": "program_counter", "moves": "encode_MBps",
+        "layer": next(m["layer"] for m in BENCH["per_layer"]
+                      if m["name"] == "ec_prefetch_s_per_GB.encode"),
+        "workloads": ["ec-encode-warm"]}
+    spec = readers.metric_spec(entry["name"])
+    assert set(spec) == {"reader", "args"} and spec["reader"] == "prom_ratio"
+    assert readers.read_metric(entry["name"], obs) == pytest.approx(
+        100.0 * took["encode", "fresh"] / 8)
+
+
+def test_slice_fresh_pct_is_left_out_by_a_program_without_the_counter():
+    """The parent has no such family: the reader finds nothing and the
+    line leaves the metric out."""
+    obs = hz.Obs()
+    scrape = hz.parse_metrics(
+        'seaweedfs_ec_pipeline_bytes_total{stage="prefetch"} 5\n')
+    obs.prom["window"] = [scrape, dict(scrape)]
+    assert readers.read_metric("ec_slice_fresh_pct.encode", obs) is None
 
 
 # -- the event-loop front end --------------------------------------------------
