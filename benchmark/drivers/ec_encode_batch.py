@@ -15,7 +15,13 @@ The first volume's output and that of every `keep_every`-th after it (from
 an offset drawn from the seed) are kept for the check.  Every other EC
 volume is dropped (un-mount + delete, the operator's rpcs) on a helper
 thread of its own the moment its rpc returns, so that its 1.4 bytes per
-byte encoded never age in the page cache until the kernel writes them back.
+byte encoded never age in the page cache until the kernel writes them back
+— unless the queue was empty by then: nothing more will be written, and the
+drop waits until the last rpc has returned.  The drops are the harness's
+housekeeping, not the deployment's work, and an un-mount + delete of 14
+files holds the store's lock for a second or more: made at once they would
+stand in the way of the last rpcs' mount and source delete, inside the
+timed span.  In a batch of one round every drop is made after the window.
 
 The warm-up encodes `in_flight` copies of one small volume at once: a
 server that compiles the program of every `(V, width)` batch as soon as it
@@ -92,6 +98,12 @@ class Driver:
         lock = threading.Lock()
         state = {"next": 0, "done": 0, "failed": 0, "t_end": None}
         droppers: list = []
+        all_returned = threading.Event()
+
+        def drop(vid: int, in_the_tail: bool) -> None:
+            if in_the_tail:
+                all_returned.wait()
+            self._drop(cluster, vid)
 
         def caller() -> None:
             while True:
@@ -111,13 +123,14 @@ class Driver:
                     state["done" if ok else "failed"] += 1
                     if ok and keep:
                         self.encoded.append(vid)
+                    in_the_tail = state["next"] >= len(vids)
                 if not ok:
                     continue
                 if keep:
                     self.run.fault.ec_files(self.vols.bases[vid], ALL_SHARDS)
                 else:
-                    th = threading.Thread(target=self._drop,
-                                          args=(cluster, vid))
+                    th = threading.Thread(target=drop,
+                                          args=(vid, in_the_tail))
                     th.start()
                     with lock:
                         droppers.append(th)
@@ -125,10 +138,13 @@ class Driver:
         callers = [threading.Thread(target=caller, name=f"caller-{k}")
                    for k in range(min(self.in_flight, len(vids)))]
         t0 = time.monotonic()
-        for th in callers:
-            th.start()
-        for th in callers:
-            th.join()
+        try:
+            for th in callers:
+                th.start()
+            for th in callers:
+                th.join()
+        finally:
+            all_returned.set()
         for th in droppers:
             th.join()
         done, failed = state["done"], state["failed"]

@@ -112,12 +112,12 @@ class Driver:
                 say(f"ec.rebuild failed: {type(e).__name__}: {e}")
                 out, failed = "", failed + 1
             t_end = time.monotonic()
-            obs.rpc(rounds, "end")
-            if timer is not None:
-                timer.join()
             made = [(int(v), [int(s) for s in ids.split(",") if s.strip()])
                     for v, ids in _REBUILT.findall(out)]
             made = [(v, ids) for v, ids in made if ids]
+            obs.rpc(rounds, "end" if made else "idle")
+            if timer is not None:
+                timer.join()
             if not made:
                 idle += 1
                 time.sleep(0.02)

@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import json
 import os
+import random
 import shutil
 import socket
 import subprocess
@@ -99,23 +100,36 @@ def log_tail(path: str, n: int = 20) -> str:
 # -- sockets -------------------------------------------------------------------
 
 
+# Both p and its gRPC twin p + 10000 lie below the kernel's ephemeral range
+# (32768-60999), so no client socket of this machine can sit on either
+# between the probe here and the child's bind, and outside the band the
+# repo's other tests draw from (tests/helpers.py: 20000-22767 and its twins
+# 30000-32767), which run beside the rehearsals under several workers.
+PORT_BAND = (12768, 20000)
+# never the same port twice in one process: pb/rpc.py keeps one channel per
+# address process-wide, and a dead server's backed-off channel would serve
+# the next server on its port
+_PORTS_HANDED_OUT: set = set()
+BIND_FAILED = ("Address already in use", "Failed to bind to address")
+
+
 def free_port_pair() -> int:
-    """A port p with p and p+10000 (the gRPC twin) both free."""
-    for _ in range(200):
-        with socket.socket() as s:
-            s.bind(("127.0.0.1", 0))
-            p = s.getsockname()[1]
-        if p + 10000 > 65000:
-            p -= 20000
-        if p < 1024:
+    """A port p with p and p+10000 (the gRPC twin) both free, drawn at
+    random (two processes that start servers at once draw apart) and
+    never handed out twice by this process."""
+    rng = random.Random()
+    for _ in range(2000):
+        p = rng.randrange(*PORT_BAND)
+        if p in _PORTS_HANDED_OUT:
             continue
         try:
             for q in (p, p + 10000):
                 with socket.socket() as s:
-                    s.bind(("127.0.0.1", q))
-            return p
+                    s.bind(("0.0.0.0", q))
         except OSError:
             continue
+        _PORTS_HANDED_OUT.add(p)
+        return p
     raise BenchFailure("no free port pair")
 
 
@@ -261,19 +275,23 @@ class Cluster:
                  control_dir: str = ""):
         self.dirs, self.codec, self.log_dir = dirs, codec, log_dir
         self.traced, self.control_dir = traced, control_dir
+        self.env_extra = env_extra
+        self.log_path = os.path.join(log_dir, "server.log")
+        self._env = None
+        self._launch()
+
+    def _launch(self) -> None:
         self.mport = free_port_pair()
         self.vport = free_port_pair()
-        self.log_path = os.path.join(log_dir, "server.log")
-        args = ["server", "-dir", ",".join(dirs), "-ip", "127.0.0.1",
+        args = ["server", "-dir", ",".join(self.dirs), "-ip", "127.0.0.1",
                 "-masterPort", str(self.mport), "-port", str(self.vport),
-                "-ec.codec", codec]
-        if traced:
+                "-ec.codec", self.codec]
+        if self.traced:
             argv = [sys.executable, os.path.join(BENCH_DIR, "server_entry.py"),
-                    "--control-dir", control_dir, "--"] + args
+                    "--control-dir", self.control_dir, "--"] + args
         else:
             argv = [sys.executable, "-m", "seaweedfs_tpu"] + args
-        self.proc = spawn(argv, self.log_path, env_extra)
-        self._env = None
+        self.proc = spawn(argv, self.log_path, self.env_extra)
 
     master = property(lambda self: f"127.0.0.1:{self.mport}")
     volume = property(lambda self: f"127.0.0.1:{self.vport}")
@@ -290,8 +308,17 @@ class Cluster:
         """-> /status once the volume server answers and the master can
         assign.  A cold TPU initialisation takes as long as it takes."""
         t_end = time.monotonic() + deadline_s
-        status = None
+        status, relaunches = None, 0
         while time.monotonic() < t_end:
+            if self.proc.poll() is not None and relaunches < 3 and any(
+                    why in log_tail(self.log_path, 40) for why in BIND_FAILED):
+                # another process took a port between the probe and the
+                # child's bind: the one race a probe cannot close
+                relaunches += 1
+                say(f"the server could not bind {self.mport} / {self.vport} "
+                    f"or a twin: started again on another pair")
+                os.replace(self.log_path, f"{self.log_path}.bind{relaunches}")
+                self._launch()
             self.alive()
             try:
                 if status is None:
@@ -436,8 +463,17 @@ class Obs:
             return None
         return metric_delta(pair[0], pair[1], name, *label_bits)
 
+    def has_series(self, phase: str, name: str) -> bool:
+        """Whether the program exports a series of `name` at all (by the
+        scrape that closed the phase): a counter that stood still is not a
+        counter the program lacks."""
+        pair = self.prom.get(phase)
+        return bool(pair and pair[1]) and any(
+            key.split("{", 1)[0] == name for key in pair[1])
+
     def rpc(self, index: int, edge: str) -> None:
-        """A driver says its timed rpc `index` starts or has ended."""
+        """A driver says its timed rpc `index` starts ("start"), has ended
+        ("end"), or has ended having found nothing to do ("idle")."""
         for fn in self.rpc_listeners:
             fn(index, edge)
 

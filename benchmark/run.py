@@ -101,9 +101,12 @@ class Run:
 
 class Tracer:
     """Starts and stops the profiler in the server around a slice of the
-    window: one whole timed rpc (`{"mode": "rpc", "index": k}`, so the
-    slice holds whole jobs and the service's byte counter is exact for it)
-    or a stretch of time (`{"mode": "time", "start_s": a, "length_s": b}`).
+    window: one whole timed rpc (`{"mode": "rpc", "index": k}`: the first
+    rpc at or after `k` that did work; one that found nothing to do is
+    dropped and the next is traced), the whole window (`{"mode":
+    "window"}`), or a stretch of time (`{"mode": "time", "start_s": a,
+    "length_s": b}`).  The first two hold whole jobs, so the service's byte
+    counter is exact for the slice.
     """
 
     def __init__(self, spec: dict, cluster, obs):
@@ -115,6 +118,8 @@ class Tracer:
             obs.rpc_listeners.append(self._on_rpc)
 
     def _start(self) -> None:
+        if self._t is not None:
+            return
         self.obs.prom_begin("trace")
         took = self.cluster.control("trace_start")["seconds"]
         self._t = time.monotonic()
@@ -129,12 +134,27 @@ class Tracer:
         hz.say(f"profiler stopped after a {self.window_s:.2f}s slice "
                f"(stop took {took:.2f}s)")
 
+    def _drop(self) -> None:
+        """The traced rpc did no work: its slice holds no device plane and
+        is thrown away, so that the next start records a slice of its own."""
+        if self._t is None or self.window_s is not None:
+            return
+        self.cluster.control("trace_stop", 300.0)
+        shutil.rmtree(os.path.join(self.cluster.control_dir, "trace"),
+                      ignore_errors=True)
+        self._t = None
+        hz.say("the traced rpc found nothing to do: slice dropped, the "
+               "next rpc is traced")
+
     def _on_rpc(self, index: int, edge: str) -> None:
-        if index == self.spec["index"]:
-            self._start() if edge == "start" else self._stop()
+        if index < self.spec["index"]:
+            return
+        {"start": self._start, "end": self._stop, "idle": self._drop}[edge]()
 
     def window_opens(self) -> None:
-        if self.spec["mode"] == "time":
+        if self.spec["mode"] == "window":
+            self._start()
+        elif self.spec["mode"] == "time":
             def timed():
                 time.sleep(self.spec["start_s"])
                 self._start()

@@ -100,7 +100,7 @@ def _drivers_of(spec):
 def test_cell_resolves_by_name(cell):
     assert cell["config"] in [c["name"] for c in BENCH["configs"]]
     traffic = run.load_json("traffic", cell["traffic"] + ".json")
-    assert traffic["trace"]["mode"] in ("rpc", "time")
+    assert traffic["trace"]["mode"] in ("rpc", "time", "window")
     for name in _drivers_of(traffic):
         mod = importlib.import_module(f"benchmark.drivers.{name}")
         for method in ("prepare", "warm", "run_window", "check_live",

@@ -98,6 +98,25 @@ def test_trace_readers():
         obs, {"rows_in": 10, "rows_out": 4}) == pytest.approx(2.8)
 
 
+@pytest.mark.parametrize("chips,busy_s,module_s,want", [
+    (1, 0.5, 0.5, 2.8),
+    # a mesh: a module's span holds its wait for the slowest chip's input
+    # (three traced runs of one tree read 0.076-0.164 s of modules over the
+    # same 0.050 s of ops); the kernel's time is the ops'
+    (4, 0.125, 0.4, 2.8),
+    (4, 0.125, 0.2, 2.8),
+])
+def test_roofline_is_over_the_time_ops_ran_not_the_modules_spans(
+        chips, busy_s, module_s, want):
+    obs = Obs()
+    obs.trace = {"chips": chips, "window_s": 2.0, "busy_s": busy_s,
+                 "module_s": module_s}
+    obs.peaks = {"hbm_bytes_per_s": 819e9}
+    obs.prom["trace"] = [{}, {"seaweedfs_ec_service_batch_bytes_sum": 8.19e9}]
+    assert gf_hbm_roofline.read(
+        obs, {"rows_in": 10, "rows_out": 4}) == pytest.approx(want)
+
+
 def test_recorded_tpu_trace():
     planes = tr.load_planes(FIXTURE)
     names = [p for p, _ in planes]
