@@ -1787,7 +1787,7 @@ def _service_rates() -> dict:
 
     # best-of-2 (same reasoning as every other stage on this noisy host)
     base_dt = svc_dt = None
-    occ_before = _hist_child_snapshot(EC_SERVICE_BATCH_JOBS)
+    occ_before = _hist_child_snapshot(EC_SERVICE_BATCH_JOBS, "pipeline")
     lat_before = {k: _hist_child_snapshot(EC_SERVICE_JOB_SECONDS, k)
                   for k in ("parity", "apply")}
     for trial in range(2):
@@ -1805,7 +1805,7 @@ def _service_rates() -> dict:
              service_GBps=round(total_bytes / svc_dt / 1e9, 3),
              service_speedup=round(base_dt / svc_dt, 3),
              service_trials=trial + 1)
-    occ_after = _hist_child_snapshot(EC_SERVICE_BATCH_JOBS)
+    occ_after = _hist_child_snapshot(EC_SERVICE_BATCH_JOBS, "pipeline")
     jobs_delta = occ_after[1] - occ_before[1]
     if jobs_delta > 0:
         result["service_batch_occupancy_mean"] = round(

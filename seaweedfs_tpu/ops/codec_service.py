@@ -23,6 +23,12 @@ dispatches the batch as a single compute call:
   batch *k+1* is assembled and dispatched, and *k*'s readback overlaps
   *k+1*'s compute — replacing the encoder's one-async-slice rule with true
   H2D/compute/D2H double buffering.
+  Jobs of class ``read`` (one lost interval of a degraded GET each, a
+  byte to a MiB wide) batch the other way: the queued reads that share
+  the head's decode plan go side by side into ONE staged block of one of
+  four widths (``_READ_BUCKETS``), so a plan has four programs, compiled
+  when a volume loses a shard (``warm_reads``), and reads of any lengths
+  share a batch.
 
 * **host mode**: the SAME scheduler runs on the C++ SIMD codec, so the
   batching and fairness properties hold on TPU-less hosts.  Small jobs
@@ -47,10 +53,8 @@ formulation pinned byte-identical in tests/test_parallel.py.
 Env knobs (all ``SEAWEEDFS_TPU_EC_SERVICE_*``): ``QUEUE`` (bound, 64),
 ``BATCH`` (max jobs/batch, 16), ``BATCH_MB`` (host mode: max input
 MB/batch, 64; a device batch is capped by the devices' memory instead),
-``COALESCE_KB`` (host slab threshold per job, 16), ``DEGRADED`` ("1"
-routes degraded-read interval decodes through the service), and the
-top-level ``SEAWEEDFS_TPU_EC_SERVICE`` ("0" disables every default
-wiring).
+``COALESCE_KB`` (host slab threshold per job, 16), and the top-level
+``SEAWEEDFS_TPU_EC_SERVICE`` ("0" disables every default wiring).
 """
 
 from __future__ import annotations
@@ -83,21 +87,31 @@ from .rs_cpu import ReedSolomon
 DATA_SHARDS = 10
 PARITY_SHARDS = 4
 
-_STAGE_QUEUE_WAIT = EC_SERVICE_STAGE.labels("queue_wait")
-_STAGE_BUILD = EC_SERVICE_STAGE.labels("build")
-_STAGE_ENQUEUE = EC_SERVICE_STAGE.labels("enqueue")
-_STAGE_DEVICE_WAIT = EC_SERVICE_STAGE.labels("device_wait")
-_STAGE_D2H = EC_SERVICE_STAGE.labels("d2h")
-_STAGE_DELIVER = EC_SERVICE_STAGE.labels("deliver")
+# A job's class: `read` is one lost interval of a degraded GET (KB to a
+# MiB, a client waits on it), `pipeline` a slice of an encode or rebuild
+# (and anything else).  A batch holds jobs of one class, and every
+# observation of the batch carries it.
+JOB_CLASSES = ("pipeline", "read")
 # In device mode `compute` and `readback` are the coarser stages of before
 # the split, observed with the extent they always had (`enqueue`, and
 # `device_wait` + `d2h`): the benchmark's svc_devwait_s_per_GB.* reads
 # them, and only a `benchmark` PR may repoint it.
-_STAGE_COMPUTE = EC_SERVICE_STAGE.labels("compute")
-_STAGE_READBACK = EC_SERVICE_STAGE.labels("readback")
+_STAGE = {c: {st: EC_SERVICE_STAGE.labels(st, c) for st in (
+    "queue_wait", "build", "enqueue", "device_wait", "d2h", "deliver",
+    "compute", "readback")} for c in JOB_CLASSES}
 _INPUT_BYTES = {p: EC_SERVICE_INPUT_BYTES.labels(p)
                 for p in ("direct", "staged")}
-_BLOCK_BYTES = EC_SERVICE_BLOCK_BYTES.labels()
+_BLOCK_BYTES = {c: EC_SERVICE_BLOCK_BYTES.labels(c) for c in JOB_CLASSES}
+_BATCH_JOBS = {c: EC_SERVICE_BATCH_JOBS.labels(c) for c in JOB_CLASSES}
+_BATCH_BYTES = {c: EC_SERVICE_BATCH_BYTES.labels(c) for c in JOB_CLASSES}
+# A device batch of read jobs is ONE block: the jobs' intervals side by
+# side in a staging buffer of one of these widths (the sum of theirs, up to
+# the last), so that a decode plan has four programs whatever the interval
+# lengths and however many reads meet in the queue — few enough to compile
+# when a volume loses a shard (`warm_reads`).  The GF work is columnwise:
+# whose column a byte is changes nothing.  Multiples of a lane tile on up
+# to sixteen devices.
+_READ_BUCKETS = (64 << 10, 256 << 10, 1 << 20, 4 << 20)
 
 # What a device batch may occupy, from what the devices can hold, in bytes
 # on the device per byte of one job's padded (S, w_pad) input.  Resident per
@@ -132,10 +146,12 @@ def _env_int(name: str, default: int) -> int:
 
 class _Job:
     __slots__ = ("kind", "key", "rows", "data", "width", "out", "stream",
-                 "event", "result", "error", "t_submit")
+                 "cls", "event", "result", "error", "t_submit")
 
-    def __init__(self, kind, key, rows, data, width, out, stream=None):
+    def __init__(self, kind, key, rows, data, width, out, stream=None,
+                 cls="pipeline"):
         self.kind = kind
+        self.cls = cls
         # jobs of one stream (the consecutive slices of one volume's
         # pipeline) never share a device batch: see _collect_locked
         self.stream = stream
@@ -247,8 +263,6 @@ class CodecService:
         # must not pay registry locks per job
         self._depth_child = EC_SERVICE_QUEUE_DEPTH.labels()
         self._inflight_child = EC_SERVICE_INFLIGHT.labels()
-        self._batch_jobs_child = EC_SERVICE_BATCH_JOBS.labels()
-        self._batch_bytes_child = EC_SERVICE_BATCH_BYTES.labels()
         self._job_ok = {k: EC_SERVICE_JOBS.labels(k, "ok")
                         for k in ("parity", "apply")}
         self._job_err = {k: EC_SERVICE_JOBS.labels(k, "error")
@@ -301,16 +315,19 @@ class CodecService:
             "parity", self.parity_matrix, self._parity_key, datas, outs)
 
     def submit_apply(self, rows: np.ndarray, inputs, out=None,
-                     stream=None) -> CodecFuture:
+                     stream=None, job_class="pipeline") -> CodecFuture:
         """Arbitrary (R, S) GF matrix x S input rows -> future of R rows.
         ``inputs`` is the service's until the future resolves (see the
-        class); ``stream`` as in ``submit_parity``."""
+        class); ``stream`` as in ``submit_parity``; ``job_class`` one of
+        ``JOB_CLASSES``."""
         rows = np.ascontiguousarray(rows, dtype=np.uint8)
         if rows.ndim != 2:
             raise ValueError("rows must be a 2-D GF matrix")
+        if job_class not in JOB_CLASSES:
+            raise ValueError(f"unknown job class {job_class!r}")
         return self._submit_many(
             "apply", rows, (rows.shape, rows.tobytes()), (inputs,), (out,),
-            stream)[0]
+            stream, job_class)[0]
 
     def submit_apply_many(self, rows: np.ndarray, inputs_list,
                           outs=None) -> list[CodecFuture]:
@@ -348,7 +365,7 @@ class CodecService:
         return data, width
 
     def _submit_many(self, kind, rows, key, datas, outs,
-                     stream=None) -> list[CodecFuture]:
+                     stream=None, cls="pipeline") -> list[CodecFuture]:
         r, s = rows.shape
         jobs: list[_Job] = []
         futs: list[CodecFuture] = []
@@ -361,7 +378,7 @@ class CodecService:
                 for o in out:
                     if len(o) != width:
                         raise ValueError("output rows must match input width")
-            job = _Job(kind, key, rows, data, width, out, stream)
+            job = _Job(kind, key, rows, data, width, out, stream, cls)
             futs.append(CodecFuture(job))
             if width == 0:  # nothing to compute: deliver inline
                 job.result = (out if out is not None else
@@ -440,13 +457,16 @@ class CodecService:
         batch = [head]
         s = head.rows.shape[1]
         nbytes = head.width * s
-        # a device batch is one program over V jobs' arrays: it takes jobs
-        # of the head's width bucket only (nothing pads beyond its own
-        # bucket), as many as the devices' memory holds, and one job of a
-        # stream — two slices of one volume in a batch only delay the
-        # first, and a lone pipeline's slices keep going one by one
+        # a device batch of pipeline jobs is one program over V jobs'
+        # arrays: it takes jobs of the head's width bucket only (nothing
+        # pads beyond its own bucket), as many as the devices' memory
+        # holds, and one job of a stream — two slices of one volume in a
+        # batch only delay the first, and a lone pipeline's slices keep
+        # going one by one.  One of read jobs is a single block of their
+        # intervals side by side, up to the widest read bucket.
         device = self.mode == "device"
-        if device:
+        columns = device and head.cls == "read"
+        if device and not columns:
             n_dev = self._device_mesh().size
             bucket = self._pad_width(head.width, n_dev)
             room = self._device_max_volumes(s * bucket)
@@ -454,24 +474,30 @@ class CodecService:
         reason = "ready"
         if self.max_batch > 1:
             for job in self._q:
-                if job.key != head.key or job.kind != head.kind:
+                if (job.key != head.key or job.kind != head.kind
+                        or job.cls != head.cls):
                     continue
-                if device and (
+                if device and not columns and (
                         self._pad_width(job.width, n_dev) != bucket
                         or (job.stream is not None and job.stream in streams)):
                     continue
                 if len(batch) >= self.max_batch:
                     reason = "full"
                     break
-                if (len(batch) >= room if device
-                        else nbytes + job.width * s > self.max_batch_bytes):
+                if columns:
+                    over = nbytes + job.width * s > _READ_BUCKETS[-1] * s
+                elif device:
+                    over = len(batch) >= room
+                else:
+                    over = nbytes + job.width * s > self.max_batch_bytes
+                if over:
                     reason = "bytes"
                     break
                 batch.append(job)
                 nbytes += job.width * s
-                if device:
+                if device and not columns:
                     streams.add(job.stream)
-            if device:
+            if device and not columns:
                 # a power of two of volumes: every (V, width) is a compiled
                 # program, and there must be few enough to warm them all
                 # (_warm); the rest keep their places in the queue
@@ -482,12 +508,12 @@ class CodecService:
                 taken = {id(j) for j in batch}
                 self._q = deque(j for j in self._q if id(j) not in taken)
         self._depth_child.set(len(self._q))
-        self._batch_jobs_child.observe(len(batch))
-        self._batch_bytes_child.observe(nbytes)
+        _BATCH_JOBS[head.cls].observe(len(batch))
+        _BATCH_BYTES[head.cls].observe(nbytes)
         return batch, reason
 
     def _run(self) -> None:
-        # device mode: (jobs, (device array, staging buffers used), tags)
+        # device mode: (jobs, what _dispatch_device returned, tags)
         inflight: deque = deque()
         try:
             if self.mode == "device":
@@ -514,11 +540,13 @@ class CodecService:
                 self._flush_children[reason].inc()
                 popped = time.perf_counter()
                 for job in batch:
-                    _STAGE_QUEUE_WAIT.observe(popped - job.t_submit)
+                    _STAGE[job.cls]["queue_wait"].observe(
+                        popped - job.t_submit)
                 # what every stage span of this batch carries, so one
                 # batch can be followed through a trace by hand
                 self._batch_seq += 1
                 tags = {"batch": self._batch_seq, "jobs": len(batch),
+                        "class": batch[0].cls,
                         "bytes": sum(j.width for j in batch)
                         * batch[0].rows.shape[1]}
                 try:
@@ -587,6 +615,7 @@ class CodecService:
 
         rows = batch[0].rows
         r, s = rows.shape
+        stage = _STAGE[batch[0].cls]
         use_native = native.available()
         mbytes = rows.tobytes()
         try:
@@ -596,7 +625,7 @@ class CodecService:
                 # column-concatenate into the reused input slab -> ONE
                 # kernel call for the whole batch; per-job results are
                 # views of one output slab
-                with trace.stage("ec.svc.build", _STAGE_BUILD, **tags):
+                with trace.stage("ec.svc.build", stage["build"], **tags):
                     total = sum(j.width for j in batch)
                     slab = self._slab_in
                     if (slab is None or slab.shape[0] != s
@@ -613,7 +642,7 @@ class CodecService:
                             for ri in range(s):
                                 slab[ri, at:at + w] = j.data[ri]
                         at += w
-                with trace.stage("ec.svc.compute", _STAGE_COMPUTE, **tags):
+                with trace.stage("ec.svc.compute", stage["compute"], **tags):
                     out_slab = np.empty((r, total), dtype=np.uint8)
                     # row pointers: slab rows are strided by capacity, so
                     # pass each row's view; the kernel reads `total` bytes
@@ -626,7 +655,7 @@ class CodecService:
                     self._deliver(j, out_slab[:, at:at + j.width])
                     at += j.width
                 return
-            with trace.stage("ec.svc.compute", _STAGE_COMPUTE, **tags):
+            with trace.stage("ec.svc.compute", stage["compute"], **tags):
                 for j in batch:
                     w = j.width
                     rows_in = self._rows_of(j.data, s)
@@ -711,69 +740,117 @@ class CodecService:
                     self._warmed[key, w_pad] = v
                     v *= 2
 
+    def warm_reads(self, rows: np.ndarray) -> None:
+        """Compile the device programs a batch of read jobs under this
+        matrix can take: one per read bucket.  Whoever learns that reads
+        under it are coming (a volume that has just lost a shard) calls it
+        before they come; a program already compiled costs a dict lookup,
+        and a host-mode service has none."""
+        if self.mode != "device":
+            return
+        from ..parallel.mesh import compile_jobs_apply
+
+        rows = np.ascontiguousarray(rows, dtype=np.uint8)
+        key = (rows.shape, rows.tobytes())
+        mesh = self._device_mesh()
+        with self._warm_lock:
+            for w_pad in _READ_BUCKETS:
+                if not self._warmed.get((key, w_pad)):
+                    compile_jobs_apply(mesh, rows, 1,
+                                       (rows.shape[1], w_pad))
+                    self._warmed[key, w_pad] = 1
+
+    def _staging_block(self, s: int, w_pad: int) -> np.ndarray:
+        free = self._staging.get(w_pad)
+        return free.pop() if free else np.empty((s, w_pad), dtype=np.uint8)
+
     def _dispatch_device(self, batch: list[_Job], tags: dict):
+        """-> (device array, staging buffers used, where each job's
+        result lies in it: (block, first column))."""
         from ..parallel.mesh import jobs_apply_sharded
 
         mesh = self._device_mesh()
         head = batch[0]
         s = head.rows.shape[1]
-        w_pad = self._pad_width(head.width, mesh.size)  # the batch's bucket
-        # a job that fills its bucket IS its block (an ndarray job is
-        # C-contiguous (S, W) uint8, by _validate) and goes in as it is;
-        # any other is copied into a staging buffer of the bucket's width
-        whole = [isinstance(j.data, np.ndarray) and j.width == w_pad
-                 for j in batch]
-        path = ("direct" if all(whole) else
-                "staged" if not any(whole) else "mixed")
-        tags.update(volumes=len(batch), v_pad=len(batch),
+        stage = _STAGE[head.cls]
+        columns = head.cls == "read"
+        blocks, staged, places = [], [], []
+        if columns:
+            # one block: the jobs' intervals side by side
+            total = sum(j.width for j in batch)
+            w_pad = next((w for w in _READ_BUCKETS if total <= w), None) \
+                or self._pad_width(total, mesh.size)
+            path = "staged"
+        else:
+            w_pad = self._pad_width(head.width, mesh.size)  # the bucket
+            # a job that fills its bucket IS its block (an ndarray job is
+            # C-contiguous (S, W) uint8, by _validate) and goes in as it
+            # is; any other is copied into a staging buffer of the
+            # bucket's width
+            whole = [isinstance(j.data, np.ndarray) and j.width == w_pad
+                     for j in batch]
+            path = ("direct" if all(whole) else
+                    "staged" if not any(whole) else "mixed")
+        n_blocks = 1 if columns else len(batch)
+        tags.update(volumes=len(batch), v_pad=n_blocks,
                     mesh=self.mesh_shape())
-        blocks, staged = [], []
-        with trace.stage("ec.svc.build", _STAGE_BUILD, path=path, **tags):
-            for j, as_it_is in zip(batch, whole):
-                _INPUT_BYTES["direct" if as_it_is else "staged"].inc(
-                    j.width * s)
-                if as_it_is:
-                    blocks.append(j.data)
-                    continue
-                free = self._staging.get(w_pad)
-                block = free.pop() if free else np.empty(
-                    (s, w_pad), dtype=np.uint8)
-                if isinstance(j.data, np.ndarray):
-                    block[:, :j.width] = j.data
-                else:
-                    for ri in range(s):
-                        block[ri, :j.width] = j.data[ri]
-                block[:, j.width:] = 0
+        with trace.stage("ec.svc.build", stage["build"], path=path, **tags):
+            if columns:
+                block = self._staging_block(s, w_pad)
+                at = 0
+                for j in batch:
+                    for ri, row in enumerate(self._rows_of(j.data, s)):
+                        block[ri, at:at + j.width] = row
+                    places.append((0, at))
+                    at += j.width
+                # the columns past `at` hold what the buffer held before:
+                # they are computed and never read
+                _INPUT_BYTES["staged"].inc(total * s)
                 blocks.append(block)
                 staged.append(block)
-            _BLOCK_BYTES.inc(len(batch) * s * w_pad)
-        # H2D dispatch of every job's array (async), a compile on a miss
-        with trace.stage("ec.svc.enqueue", _STAGE_ENQUEUE, layout=_LAYOUT,
+            else:
+                for vi, (j, as_it_is) in enumerate(zip(batch, whole)):
+                    places.append((vi, 0))
+                    _INPUT_BYTES["direct" if as_it_is else "staged"].inc(
+                        j.width * s)
+                    if as_it_is:
+                        blocks.append(j.data)
+                        continue
+                    block = self._staging_block(s, w_pad)
+                    for ri, row in enumerate(self._rows_of(j.data, s)):
+                        block[ri, :j.width] = row
+                    block[:, j.width:] = 0
+                    blocks.append(block)
+                    staged.append(block)
+            _BLOCK_BYTES[head.cls].inc(n_blocks * s * w_pad)
+        # H2D dispatch of every block (async), a compile on a miss
+        with trace.stage("ec.svc.enqueue", stage["enqueue"], layout=_LAYOUT,
                          **tags) as st:
             dev = jobs_apply_sharded(mesh, head.rows, blocks)
-        _STAGE_COMPUTE.observe(st.seconds)
-        return dev, staged
+        stage["compute"].observe(st.seconds)
+        return dev, staged, places
 
     def _complete_device(self, batch: list[_Job], sent, tags: dict) -> None:
-        dev, staged = sent
+        dev, staged, places = sent
+        stage = _STAGE[batch[0].cls]
         try:
             # np.asarray alone would wait just the same: the split only
             # says how much of it is the device and how much the copy out
-            with trace.stage("ec.svc.device_wait", _STAGE_DEVICE_WAIT,
+            with trace.stage("ec.svc.device_wait", stage["device_wait"],
                              **tags) as wait:
                 dev.block_until_ready()
-            with trace.stage("ec.svc.d2h", _STAGE_D2H, layout=_LAYOUT,
+            with trace.stage("ec.svc.d2h", stage["d2h"], layout=_LAYOUT,
                              **tags) as copy:
                 out = np.asarray(dev)  # D2H: a copy, (V, R, w_pad) bytes
-            _STAGE_READBACK.observe(wait.seconds + copy.seconds)
+            stage["readback"].observe(wait.seconds + copy.seconds)
             # the result is here, so the device has read the staged jobs
             for block in staged:
                 free = self._staging.setdefault(block.shape[1], [])
                 if len(free) < _STAGING_KEPT:
                     free.append(block)
-            with trace.stage("ec.svc.deliver", _STAGE_DELIVER, **tags):
-                for vi, j in enumerate(batch):
-                    self._deliver(j, out[vi, :, :j.width])
+            with trace.stage("ec.svc.deliver", stage["deliver"], **tags):
+                for j, (vi, at) in zip(batch, places):
+                    self._deliver(j, out[vi, :, at:at + j.width])
         except Exception as e:
             for j in batch:
                 self._fail(j, e)
@@ -809,7 +886,8 @@ def get_service(codec_name: str = "cpu") -> "CodecService | None":
 
 
 def service_for_codec(codec_name: str) -> "CodecService | None":
-    """Default routing for the bulk encode/rebuild pipelines: a device
+    """Default routing for the bulk encode/rebuild pipelines and for a
+    degraded read's interval decode (storage/ec/volume.py): a device
     codec goes through the (device-mode) service when THIS process's jax
     holds an accelerator; on a CPU backend the per-volume device path
     keeps its direct dispatch, and host codecs their mmap/inline-SIMD
@@ -825,19 +903,6 @@ def service_for_codec(codec_name: str) -> "CodecService | None":
     if held_device()["platform"] == "cpu":
         return None
     return get_service(codec_name)
-
-
-def service_for_degraded() -> "CodecService | None":
-    """Host-mode service for per-needle degraded reads (which must never
-    pay a device dispatch).  Opt-in: a lone read pays one extra thread
-    hop, so this is for hosts expecting degraded-read storms."""
-    if not enabled():
-        return None
-    if os.environ.get(
-            "SEAWEEDFS_TPU_EC_SERVICE_DEGRADED", "0").lower() in (
-            "0", "false", "off", "no"):
-        return None
-    return get_service("cpu")
 
 
 def shutdown_all(timeout: "float | None" = 30.0) -> None:

@@ -232,6 +232,19 @@ class ReedSolomonTPU:
     def reconstruct(self, shards):
         return self._reconstruct(shards, data_only=False)
 
+    def reconstruct_one(self, shards, shard_id: int) -> np.ndarray:
+        """Decode ONLY shard_id from >= data_shards present shards: the one
+        plan row a degraded read wants, dispatched from the caller's
+        thread (a process that holds an accelerator sends the same row to
+        its codec service instead: storage/ec/volume.py)."""
+        if shards[shard_id] is not None:
+            return np.asarray(shards[shard_id], dtype=np.uint8)
+        present = [i for i, s in enumerate(shards) if s is not None]
+        row = gf256.decode_plan_for(
+            self.matrix, self.data_shards, present, (shard_id,))
+        return self._apply_blocking(row, np.stack(
+            [shards[i] for i in present[: self.data_shards]]))[0]
+
     def reconstruct_data(self, shards):
         return self._reconstruct(shards, data_only=True)
 

@@ -575,6 +575,24 @@ EC_SINGLEFLIGHT = REGISTRY.counter(
     "degraded-read interval reconstructions by single-flight role",
     labels=("result",),  # leader | coalesced
 )
+EC_DEGRADED_INTERVALS = REGISTRY.counter(
+    "seaweedfs_ec_degraded_intervals_total",
+    "lost shard intervals a read needed, by how each was answered",
+    # decoded: gathered and decoded here | cached: the interval cache |
+    # coalesced: a single-flight leader's result
+    labels=("outcome",),
+)
+EC_DEGRADED_GETS = REGISTRY.counter(
+    "seaweedfs_ec_degraded_gets_total",
+    "needle reads of an EC volume that needed at least one lost interval",
+)
+EC_DEGRADED_STAGE = REGISTRY.histogram(
+    "seaweedfs_ec_degraded_seconds",
+    "wall time of one lost interval's two stages",
+    # gather: the survivor intervals read (local preads, remote fetches) |
+    # decode: the GF row applied (codec-service submit -> result, or inline)
+    labels=("stage",),
+)
 
 # fault-tolerance layer (util/failsafe.py, util/faultpoint.py) — declared
 # HERE so the metric-family lint can hold one file to "every family
@@ -731,11 +749,13 @@ EC_SERVICE_BATCH_JOBS = REGISTRY.histogram(
     "seaweedfs_ec_service_batch_jobs",
     "jobs coalesced into each codec-service batch (occupancy)",
     buckets=(1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0),
+    labels=("class",),  # read (a degraded read's interval) | pipeline
 )
 EC_SERVICE_BATCH_BYTES = REGISTRY.histogram(
     "seaweedfs_ec_service_batch_bytes",
     "input bytes per codec-service batch",
     buckets=_EC_BYTE_BUCKETS,
+    labels=("class",),
 )
 EC_SERVICE_FLUSH = REGISTRY.counter(
     "seaweedfs_ec_service_flush_total",
@@ -763,14 +783,16 @@ EC_SERVICE_INPUT_BYTES = REGISTRY.counter(
 # bucketing widths adds to what the device is sent
 EC_SERVICE_BLOCK_BYTES = REGISTRY.counter(
     "seaweedfs_ec_service_block_bytes_total",
-    "device-mode bytes sent to the device, every job at its width bucket",
+    "device-mode bytes sent to the device, every batch at its width bucket",
+    labels=("class",),
 )
 EC_SERVICE_STAGE = REGISTRY.histogram(
     "seaweedfs_ec_service_stage_seconds",
     "per-batch wall time in each codec-service stage",
     # queue_wait (per job) | build | enqueue | device_wait | d2h | deliver,
-    # and compute | readback (ops/codec_service.py says which is which)
-    labels=("stage",),
+    # and compute | readback (ops/codec_service.py says which is which);
+    # class: read | pipeline, the batch's (queue_wait: the job's)
+    labels=("stage", "class"),
 )
 
 

@@ -20,10 +20,14 @@ import numpy as np
 from ...ops import codec_service, gf256
 from ...ops.codec import get_codec
 from ...stats.metrics import (
+    EC_DEGRADED_GETS,
+    EC_DEGRADED_INTERVALS,
+    EC_DEGRADED_STAGE,
     EC_PARTIAL_FALLBACK,
     EC_PREADV_BATCHES,
     EC_SINGLEFLIGHT,
 )
+from ...telemetry import trace
 from ...util.chunk_cache import IntervalCache
 from .. import idx as idx_mod
 from .. import types as t
@@ -153,6 +157,14 @@ FetchFn = Callable[[int, int, int], "bytes | None"]
 
 _SF_LEADER = EC_SINGLEFLIGHT.labels("leader")
 _SF_COALESCED = EC_SINGLEFLIGHT.labels("coalesced")
+_LOST = {o: EC_DEGRADED_INTERVALS.labels(o)
+         for o in ("decoded", "cached", "coalesced")}
+_DEGRADED_GETS = EC_DEGRADED_GETS.labels()
+_STAGE_GATHER = EC_DEGRADED_STAGE.labels("gather")
+_STAGE_DECODE = EC_DEGRADED_STAGE.labels("decode")
+# lost intervals the calling thread's reads have needed so far: read_needle
+# tells from it whether its needle needed one
+_TLS = threading.local()
 
 # one bounded process-wide executor for degraded-read remote fetches:
 # the old per-call ThreadPoolExecutor paid thread spawn+teardown on
@@ -166,9 +178,9 @@ _HOST_CODEC = None
 
 
 def _host_codec():
-    """Shared host SIMD codec for the partial-decode local term — the
-    volume's own codec may be a device codec, and a per-needle degraded
-    read must never pay device dispatch."""
+    """Shared host SIMD codec for the partial-decode local term of a
+    process that has no device codec service (a host-codec server, or a
+    device codec on a CPU backend)."""
     global _HOST_CODEC
     if _HOST_CODEC is None:
         _HOST_CODEC = get_codec("cpu")
@@ -222,7 +234,12 @@ class EcVolume:
         self.volume_id = volume_id
         self.collection = collection
         self.version = version
+        self.codec_name = codec_name
         self.codec = get_codec(codec_name)
+        # the codec service a lost interval's decode goes through; None =
+        # the process's own, where it holds an accelerator
+        # (`_decode_service`).  Set by whoever runs its own service.
+        self.decode_service: "codec_service.CodecService | None" = None
         self.large_block_size = large_block_size
         self.small_block_size = small_block_size
         self.shards: dict[int, EcVolumeShard] = {}
@@ -452,7 +469,10 @@ class EcVolume:
         offset, size, intervals = self.locate(needle_id)
         if t.size_is_deleted(size):
             raise NotFoundError(f"needle {needle_id:x} deleted")
+        lost = getattr(_TLS, "lost", 0)
         parts = self._read_intervals(intervals)
+        if getattr(_TLS, "lost", 0) != lost:
+            _DEGRADED_GETS.inc()
         try:
             n = Needle.from_bytes(b"".join(parts), self.version)
         except CorruptNeedleError:
@@ -642,11 +662,13 @@ class EcVolume:
         delete_seq) token — compare-before-publish, so a racing shard
         mount/unmount or delete can never publish a stale interval.
         """
+        _TLS.lost = getattr(_TLS, "lost", 0) + 1
         cache = self._interval_cache
         key = (shard_id, offset, length)
         if cache is not None:
             data = cache.get(key, self._cache_token())
             if data is not None:
+                _LOST["cached"].inc()
                 return data
         with self._sf_lock:
             call = self._sf_calls.get(key)
@@ -664,6 +686,7 @@ class EcVolume:
                 # same staleness discipline as the cache: a shard swap or
                 # delete since the leader's capture voids the hand-off
                 if call.token == self._cache_token():
+                    _LOST["coalesced"].inc()
                     return call.result
             return self._gather_and_decode(shard_id, offset, length)[0]
         _SF_LEADER.inc()
@@ -688,6 +711,41 @@ class EcVolume:
                 self._sf_calls.pop(key, None)
             call.done.set()
 
+    def _decode_service(self) -> "codec_service.CodecService | None":
+        """The codec service lost intervals are decoded through: the one
+        handed to this volume, else the process's device service where it
+        holds an accelerator (a `-ec.codec tpu` server), else none."""
+        return self.decode_service or codec_service.service_for_codec(
+            self.codec_name)
+
+    def _lost_rows(self, shard_id: int) -> "tuple[int, ...]":
+        """The shards ONE decode job of the service re-makes for a read of
+        `shard_id`: every shard this volume does not hold, where it holds
+        ten itself — the rebuild's plan, so that reads of different lost
+        shards share a key, a batch and four programs, and each takes its
+        own row on the host (measured against one row a job: PERF.md §6,
+        PR 37) — else that one shard."""
+        lost = tuple(s for s in range(TOTAL_SHARDS) if s not in self.shards)
+        if shard_id in lost and len(lost) <= TOTAL_SHARDS - DATA_SHARDS:
+            return lost
+        return (shard_id,)
+
+    def warm_decode(self) -> None:
+        """Have the decode service compile what reads of this volume's
+        lost shards will run, from the survivors it holds itself: called
+        when a shard is lost (storage/store.py), so that no GET finds a
+        program uncompiled.  With fewer than ten local shards the
+        survivor set is whatever the peers answer with, and the first
+        read of each compiles."""
+        svc = self._decode_service()
+        local = sorted(self.shards)
+        if svc is None or not DATA_SHARDS <= len(local) < TOTAL_SHARDS:
+            return
+        lost = next(s for s in range(TOTAL_SHARDS) if s not in self.shards)
+        svc.warm_reads(gf256.decode_plan_for(
+            np.asarray(self.codec.matrix), DATA_SHARDS, local,
+            self._lost_rows(lost)))
+
     def _gather_and_decode(
         self, shard_id: int, offset: int, length: int
     ) -> tuple[bytes, tuple[int, int]]:
@@ -700,8 +758,46 @@ class EcVolume:
         store_ec.go:324-378 fans out one goroutine per source shard and
         joins them) — and a degraded-read storm no longer spawns a fresh
         thread pool per interval.
+
+        The decode is the decode plan over the ten survivor intervals.
+        Where the process has a device codec service it is ONE job of it,
+        class `read`, under the plan of `_lost_rows` — a `-ec.codec tpu`
+        server decodes on the device, beside its pipelines' slices, and
+        nothing is dispatched from this thread; elsewhere the volume's
+        own codec applies the one wanted row inline (`reconstruct_one`:
+        the host kernel, or a device codec's direct dispatch on a CPU
+        backend).
         """
         token = self._cache_token()
+        tags = {"volume": self.volume_id, "shard": shard_id, "bytes": length}
+        with trace.stage("ec.degraded.gather", _STAGE_GATHER, **tags):
+            shards = self._gather(shard_id, offset, length)
+        if isinstance(shards, bytes):  # the partial-sum protocol's answer
+            return shards, token
+        with trace.stage("ec.degraded.decode", _STAGE_DECODE, **tags):
+            svc = self._decode_service()
+            if svc is not None:
+                # concurrent reads against the same survivor set (the
+                # same plan) batch into ONE call on the service's
+                # scheduler.  Same plan cache and same GF program as the
+                # rebuild -> byte-identical.
+                present = [i for i, s in enumerate(shards) if s is not None]
+                wanted = self._lost_rows(shard_id)
+                rows = gf256.decode_plan_for(
+                    np.asarray(self.codec.matrix), DATA_SHARDS,
+                    present, wanted)
+                data = svc.submit_apply(
+                    rows, [shards[i] for i in present[:DATA_SHARDS]],
+                    job_class="read").result()[wanted.index(shard_id)]
+            else:
+                data = self.codec.reconstruct_one(shards, shard_id)
+        _LOST["decoded"].inc()
+        return np.asarray(data, dtype=np.uint8).tobytes(), token
+
+    def _gather(self, shard_id: int, offset: int, length: int):
+        """-> the 14 shard slots with at least DATA_SHARDS survivor
+        intervals filled in, or the lost interval's bytes where the
+        partial-sum protocol has answered already."""
         shards: list[np.ndarray | None] = [None] * TOTAL_SHARDS
         have = 0
         # snapshot in one C-level call: mount/unmount rpcs mutate
@@ -728,8 +824,7 @@ class EcVolume:
             # coefficient-weighted rows pre-XOR'd per rack (one 1 x W
             # partial per rack in) instead of 10 raw intervals
             try:
-                return self._partial_decode(
-                    shard_id, offset, length, shards), token
+                return self._partial_decode(shard_id, offset, length, shards)
             except Exception:  # noqa: BLE001 — optimization, never a 5xx
                 EC_PARTIAL_FALLBACK.labels("degraded").inc()
         if have < DATA_SHARDS and self.remote_fetch is not None and missing:
@@ -750,36 +845,17 @@ class EcVolume:
             raise IOError(
                 f"shard {shard_id} interval unreadable: only {have} shards available"
             )
-        svc = codec_service.service_for_degraded()
-        if svc is not None:
-            # degraded-read storms coalesce: concurrent reconstructions
-            # against the same survivor set (same decode-plan row) batch
-            # into ONE SIMD call on the service scheduler.  Same plan
-            # cache + same kernel as reconstruct_one -> byte-identical.
-            present = [i for i, s in enumerate(shards) if s is not None]
-            sub = [np.asarray(shards[i], dtype=np.uint8)
-                   for i in present[:DATA_SHARDS]]
-            row = gf256.decode_plan_for(
-                np.asarray(self.codec.matrix), DATA_SHARDS,
-                present, (shard_id,))
-            return svc.submit_apply(row, sub).result()[0].tobytes(), token
-        if hasattr(self.codec, "reconstruct_one"):
-            # latency path: decode only the wanted row, not all lost shards
-            return np.asarray(
-                self.codec.reconstruct_one(shards, shard_id),
-                dtype=np.uint8).tobytes(), token
-        rebuilt = self.codec.reconstruct(shards)
-        return np.asarray(rebuilt[shard_id], dtype=np.uint8).tobytes(), token
+        return shards
 
     def _partial_decode(
         self, shard_id: int, offset: int, length: int, shards: list
     ) -> bytes:
         """Reconstruct one lost interval via the partial-sum protocol:
         the decode-plan row for `shard_id` splits by source locality —
-        local shards' columns are applied here on the host kernel (a
-        per-needle read must never pay device dispatch), remote columns
-        ship to the holders and return as one pre-XOR'd partial per
-        rack.  GF linearity makes the bytes identical to the gathered
+        local shards' columns are applied here (a `read` job of the
+        decode service where the process has one, else the host kernel),
+        remote columns ship to the holders and return as one pre-XOR'd
+        partial per rack.  GF linearity makes the bytes identical to the gathered
         reconstruct_one path; any failure raises and the caller falls
         back to it."""
         client = self.partial_client
@@ -805,10 +881,11 @@ class EcVolume:
             local_plan = np.ascontiguousarray(plan[:, :len(local_srcs)])
             rows_in = [np.asarray(local_rows[s], dtype=np.uint8)
                        for s in local_srcs]
-            svc = codec_service.service_for_degraded()
+            svc = self._decode_service()
             if svc is not None:
                 out = np.asarray(
-                    svc.submit_apply(local_plan, rows_in).result(),
+                    svc.submit_apply(local_plan, rows_in,
+                                     job_class="read").result(),
                     dtype=np.uint8)
             else:
                 out = np.asarray(

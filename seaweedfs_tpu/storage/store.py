@@ -616,6 +616,11 @@ class Store:
                 ev.close()
                 if self.needle_cache is not None:
                     self.needle_cache.drop_volume(vid)
+                return
+        # reads of the lost shards decode from now on: their programs are
+        # compiled here, by the rpc that lost them, not under a GET (and,
+        # for the unmount rpc, not under the store's lock)
+        ev.warm_decode()
 
     def delete_ec_shards(self, vid: int, collection: str,
                          shard_ids: list[int]) -> None:

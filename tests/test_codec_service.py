@@ -247,7 +247,7 @@ def test_device_block_is_the_job_or_a_staged_copy(case, monkeypatch):
     rs = ReedSolomon()
     rng = np.random.default_rng(28)
     datas = [_rand_block(rng, w) for w in widths]
-    build = EC_SERVICE_STAGE.labels("build")
+    build = EC_SERVICE_STAGE.labels("build", "pipeline")
     counted = codec_service._INPUT_BYTES
     before = {p: c.value for p, c in counted.items()}
     build_before = (build.total, build.count)
@@ -299,7 +299,7 @@ def test_auto_mode_with_device_codec_is_device_mode():
 def test_batches_coalesce_under_load():
     from seaweedfs_tpu.stats.metrics import EC_SERVICE_BATCH_JOBS
 
-    child = EC_SERVICE_BATCH_JOBS.labels()
+    child = EC_SERVICE_BATCH_JOBS.labels("pipeline")
     before_total, before_count = child.total, child.count
     svc = CodecService(mode="host", max_batch=16, coalesce_kb=16)
     rng = np.random.default_rng(8)
@@ -406,7 +406,6 @@ def test_get_service_disabled_by_env(monkeypatch):
     monkeypatch.setenv("SEAWEEDFS_TPU_EC_SERVICE", "0")
     assert codec_service.get_service("cpu") is None
     assert codec_service.service_for_codec("tpu") is None
-    assert codec_service.service_for_degraded() is None
 
 
 def test_get_service_shared_and_recreated_after_shutdown(monkeypatch):
@@ -497,7 +496,14 @@ def test_generate_device_codec_via_device_service(tmp_path):
     svc.close()
 
 
-def test_degraded_read_via_service(tmp_path, monkeypatch):
+@pytest.mark.parametrize("mode,codec", [("host", "cpu"),
+                                        ("device", "tpu_xor")])
+def test_degraded_read_via_service(tmp_path, monkeypatch, mode, codec):
+    """A lost interval goes through the service the volume is handed (the
+    routing a process that holds an accelerator takes by itself), as one
+    `apply` job of class `read`; without one, a host codec decodes
+    inline."""
+    from seaweedfs_tpu.stats.metrics import EC_SERVICE_JOBS
     from seaweedfs_tpu.storage.ec.constants import to_ext
     from seaweedfs_tpu.storage.ec.encoder import (
         generate_ec_files,
@@ -525,16 +531,22 @@ def test_degraded_read_via_service(tmp_path, monkeypatch):
     for sid in (0, 1, 2, 3):
         os.remove(base + to_ext(sid))
 
-    monkeypatch.setenv("SEAWEEDFS_TPU_EC_SERVICE_DEGRADED", "1")
     monkeypatch.setenv("SEAWEEDFS_TPU_EC_INTERVAL_CACHE_MB", "0")
-    codec_service.shutdown_all()
     ev = EcVolume(base, volume_id=1)
+    assert ev._decode_service() is None      # a host codec: inline
+    jobs = EC_SERVICE_JOBS.labels("apply", "ok")
+    svc = CodecService(mode=mode, codec_name=codec)
     try:
+        assert ev.read_needle(1).data == payloads[1]
+        before = jobs.value
+        ev.decode_service = svc
         for i in (1, 5, 9, 20):
             needle = ev.read_needle(i)
             assert needle.data == payloads[i]
+        assert jobs.value - before >= 4
     finally:
         ev.close()
+        svc.close()
 
 
 # -- compile cache placement -------------------------------------------------

@@ -59,7 +59,7 @@ def _hold_scheduler(svc):
 
 
 def _jobs_per_batch():
-    child = EC_SERVICE_BATCH_JOBS.labels()
+    child = EC_SERVICE_BATCH_JOBS.labels("pipeline")
     return child.total, child.count
 
 
@@ -78,7 +78,7 @@ def test_batch_of_v_streams_equals_the_reference_job_by_job(v):
              for w in WIDTHS[:v]]
     svc = _one_device_service()
     jobs0, batches0 = _jobs_per_batch()
-    block0 = EC_SERVICE_BLOCK_BYTES.labels().value
+    block0 = EC_SERVICE_BLOCK_BYTES.labels("pipeline").value
     release = _hold_scheduler(svc)
     try:
         futs = [svc.submit_parity(d, stream=f"vol{i}")
@@ -93,7 +93,7 @@ def test_batch_of_v_streams_equals_the_reference_job_by_job(v):
     # one width bucket and a power of two of volumes to a batch
     assert batches1 - batches0 == _batches_of(WIDTHS[:v])
     # every job is sent at its own bucket's width and no wider
-    sent = EC_SERVICE_BLOCK_BYTES.labels().value - block0
+    sent = EC_SERVICE_BLOCK_BYTES.labels("pipeline").value - block0
     assert sent == 10 * sum(CodecService._pad_width(w, 1) for w in WIDTHS[:v])
 
 
@@ -121,13 +121,13 @@ for v in (2, 3, 8):
     rng = np.random.default_rng(30 + v)
     datas = [rng.integers(0, 256, (10, w), dtype=np.uint8) for w in widths[:v]]
     svc = CodecService(mode="device", codec_name="tpu_xor")  # its own mesh
-    child = EC_SERVICE_BATCH_JOBS.labels()
-    b0, sent0 = child.count, EC_SERVICE_BLOCK_BYTES.labels().value
+    child = EC_SERVICE_BATCH_JOBS.labels("pipeline")
+    b0, sent0 = child.count, EC_SERVICE_BLOCK_BYTES.labels("pipeline").value
     with svc._cond:
         futs = [svc.submit_parity(d, stream=i) for i, d in enumerate(datas)]
     same = [bool(np.array_equal(np.stack([np.asarray(r) for r in f.result(120)]),
                                 ref.parity_of(d))) for f, d in zip(futs, datas)]
-    sent = EC_SERVICE_BLOCK_BYTES.labels().value - sent0
+    sent = EC_SERVICE_BLOCK_BYTES.labels("pipeline").value - sent0
     out[str(v)] = {"same": same, "batches": child.count - b0, "mesh": svc.mesh_shape(),
                    "pad_pct": 100.0 * (sent / (10 * sum(widths[:v])) - 1)}
     # a lone whole-bucket job still goes in as it is, on four devices too

@@ -49,7 +49,7 @@ SPLIT = ("ec.svc.build", "ec.svc.enqueue", "ec.svc.device_wait", "ec.svc.d2h")
 
 
 def _stage(label: str):
-    child = EC_SERVICE_STAGE.labels(label)
+    child = EC_SERVICE_STAGE.labels(label, "pipeline")
     return child.total, child.count
 
 
@@ -70,7 +70,7 @@ def device_service():
 
 
 def test_stage_observes_and_keeps_child_spans_rule_for_the_ring():
-    child = EC_SERVICE_STAGE.labels("test_only")
+    child = EC_SERVICE_STAGE.labels("test_only", "pipeline")
     tracer_before = len(trace.TRACER.spans())
     with trace.stage("ec.test.outside", child, batch=1) as st:
         pass
@@ -86,7 +86,7 @@ def test_stage_observes_and_keeps_child_spans_rule_for_the_ring():
 
 
 def test_stage_observes_when_the_block_raises():
-    child = EC_SERVICE_STAGE.labels("test_only_raises")
+    child = EC_SERVICE_STAGE.labels("test_only_raises", "pipeline")
     with pytest.raises(KeyError):
         with trace.stage("ec.test.raises", child):
             raise KeyError("x")
@@ -101,7 +101,7 @@ def test_stage_without_jax_imports_no_jax():
         "from seaweedfs_tpu.stats.metrics import EC_SERVICE_STAGE, "
         "REQUEST_HISTOGRAM\n"
         "from seaweedfs_tpu.telemetry import record_op, trace\n"
-        "child = EC_SERVICE_STAGE.labels('build')\n"
+        "child = EC_SERVICE_STAGE.labels('build', 'pipeline')\n"
         "with trace.stage('ec.svc.build', child, batch=1, jobs=2) as st:\n"
         "    pass\n"
         "with record_op('master', 'assign', collection='c'):\n"
@@ -329,7 +329,8 @@ def test_prefetch_span_and_counter_say_where_a_slice_buffer_came_from(
     assert free == took["encode", "fresh"] * 10 * (1 << 18)
     assert 'seaweedfs_ec_slice_buffers_total{pipeline="encode",source="fresh"}' \
         in REGISTRY.render()
-    entry = BENCH["per_layer"][-1]
+    entry = next(m for m in BENCH["per_layer"]
+                 if m["name"] == "ec_slice_fresh_pct.encode")
     assert entry == {
         "name": "ec_slice_fresh_pct.encode", "unit": "%", "better": "lower",
         "source": "program_counter", "moves": "encode_MBps",

@@ -165,6 +165,26 @@ def test_batches_up_to_the_cap_fit_hbm_twice(topo, chips, v):
     svc.close()
 
 
+@pytest.mark.parametrize("chips", [1, 4])
+def test_read_programs_compile_at_every_read_bucket(topo, chips):
+    """What `CodecService.warm_reads` compiles when a volume loses
+    shards: its decode plan (4 x 10 with four lost) over one block at each
+    of the four read buckets, on one chip and column-split over four.
+    Small and quick: a read is latency-bound."""
+    from seaweedfs_tpu.ops import codec_service as cs
+
+    mesh = Mesh(np.asarray(topo.devices[:chips]).reshape(1, chips),
+                ("dp", "sp"))
+    row = _decode_rows([0, 1, 2, 3])
+    for width in cs._READ_BUCKETS:
+        fn, jobs = _jobs_program(mesh, row, 1, width)
+        compiled, seconds = _compile(fn, *jobs)
+        mem = compiled.memory_analysis()  # per device
+        assert mem.output_size_in_bytes == 4 * width // chips
+        assert mem.temp_size_in_bytes < 16 * 10 * width // chips
+        assert seconds < 30
+
+
 def test_service_batch_holds_one_width_bucket():
     """A device batch is one program over jobs of ONE width bucket: a wide
     job between narrow ones waits its turn, so nothing is padded beyond
