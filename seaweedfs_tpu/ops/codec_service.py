@@ -14,15 +14,19 @@ dispatches the batch as a single compute call:
   from ``parallel.mesh`` over every device the process holds: each job's
   ``(S, W)`` array is an argument of its own, columns spread over all the
   devices, and the ``(V, R, W)`` stack of results comes back as one
-  array.  Both cross the bus as uint32 lane tiles, views of the host's
-  bytes in the layout the device keeps, so neither way is anything re-laid
-  on the host.  A job that is a whole block already (a contiguous
-  ``(S, W)`` array whose width is its own bucket) goes in as it is; any
-  other job is first copied into a reused staging buffer padded to the
-  bucket.  Up to two batches stay in flight: while batch *k* computes,
-  batch *k+1* is assembled and dispatched, and *k*'s readback overlaps
-  *k+1*'s compute — replacing the encoder's one-async-slice rule with true
-  H2D/compute/D2H double buffering.
+  array: on a mesh the devices gather it among themselves, so the host
+  fetches it whole from one of them.  It sets out for the host when the
+  batch is dispatched (``copy_to_host_async``), on the runtime's threads,
+  and the scheduler's thread only picks up the finished copy: it makes no
+  pass over result bytes of its own.  Both cross the bus as uint32 lane
+  tiles, views of the host's bytes in the layout the device keeps, so
+  neither way is anything re-laid on the host.  A job that is a whole
+  block already (a contiguous ``(S, W)`` array whose width is its own
+  bucket) goes in as it is; any other job is first copied into a reused
+  staging buffer padded to the bucket.  Up to two batches stay in flight:
+  while batch *k* computes, batch *k+1* is assembled and dispatched, and
+  *k*'s readback overlaps *k+1*'s compute — replacing the encoder's
+  one-async-slice rule with true H2D/compute/D2H double buffering.
   Jobs of class ``read`` (one lost interval of a degraded GET each, a
   byte to a MiB wide) batch the other way: the queued reads that share
   the head's decode plan go side by side into ONE staged block of one of
@@ -77,6 +81,7 @@ from ..stats.metrics import (
     EC_SERVICE_JOB_SECONDS,
     EC_SERVICE_JOBS,
     EC_SERVICE_QUEUE_DEPTH,
+    EC_SERVICE_READBACKS,
     EC_SERVICE_STAGE,
 )
 from ..telemetry import trace
@@ -104,6 +109,8 @@ _INPUT_BYTES = {p: EC_SERVICE_INPUT_BYTES.labels(p)
 _BLOCK_BYTES = {c: EC_SERVICE_BLOCK_BYTES.labels(c) for c in JOB_CLASSES}
 _BATCH_JOBS = {c: EC_SERVICE_BATCH_JOBS.labels(c) for c in JOB_CLASSES}
 _BATCH_BYTES = {c: EC_SERVICE_BATCH_BYTES.labels(c) for c in JOB_CLASSES}
+_READBACKS = {c: {st: EC_SERVICE_READBACKS.labels(st, c)
+                  for st in ("ready", "waited")} for c in JOB_CLASSES}
 # A device batch of read jobs is ONE block: the jobs' intervals side by
 # side in a staging buffer of one of these widths (the sum of theirs, up to
 # the last), so that a decode plan has four programs whatever the interval
@@ -125,7 +132,15 @@ _READ_BUCKETS = (64 << 10, 256 << 10, 1 << 20, 4 << 20)
 # block's bytes with two batches in flight at V = 1, 2, 4 and 8 on a v5e,
 # 1.40 a batch) and the temporaries are 7.1 x one job's bytes (PERF.md §6,
 # PR 31).  Two batches are in flight and a batch may take _HBM_SHARE of
-# each device's memory.
+# each device's memory.  On a mesh of n devices the result is gathered
+# (PR 38): a device holds its 1/n of a job's input and ALL of its result,
+# 1 + 0.4 n in these units where the column-sharded result's was 1.4, and
+# the gather keeps a second whole result as a temporary (the compiler's
+# count for a described v5e:2x2, (10, 16 MiB) jobs: 104 MiB of arguments
+# and output a job a chip, temporaries 160 / 513 / 1,026 MiB at V = 1 / 8 /
+# 16): `_resident_per_job_byte` adds both, 2 x 0.4 for every device but
+# one, so the cap still refuses what the compiler would
+# (tests/test_tpu_compile.py).
 _HBM_RESIDENT_PER_JOB_BYTE = 2.05
 _HBM_TEMP_PER_JOB_BYTE = 12.5
 _HBM_SHARE = 0.75
@@ -446,8 +461,18 @@ class CodecService:
             self._device_bytes = int(_HBM_SHARE * mesh.size * stats.get(
                 "bytes_limit", _HBM_BYTES_UNREPORTED))
         room = int((self._device_bytes / job_bytes - _HBM_TEMP_PER_JOB_BYTE)
-                   / (2 * _HBM_RESIDENT_PER_JOB_BYTE))
+                   / (2 * self._resident_per_job_byte()))
         return max(1, min(room, self.max_batch))
+
+    def _resident_per_job_byte(self) -> float:
+        """_HBM_RESIDENT_PER_JOB_BYTE, and on a mesh what the gathered
+        result adds over all its devices: each of the others holds the
+        whole (R, w) of a job too, once as the program's output and once
+        as the gather's own copy of it (a temporary that grows with V,
+        counted for both batches in flight)."""
+        others = self._device_mesh().size - 1
+        return _HBM_RESIDENT_PER_JOB_BYTE + (
+            2 * others * self.parity_shards / self.data_shards)
 
     def _collect_locked(self) -> "tuple[list[_Job], str]":
         """Pop the head job plus every queued job sharing its matrix, up
@@ -559,12 +584,13 @@ class CodecService:
                             self._inflight_child.set(len(inflight))
                     else:
                         self._compute_host(batch, tags)
-                except Exception as e:
-                    # the collected batch is in neither queue nor
-                    # inflight — fail it here or its waiters hang forever
+                except Exception as e:  # noqa: BLE001 — the jobs carry it
+                    # the collected batch is in neither queue nor inflight:
+                    # fail it here or its waiters hang forever.  As with a
+                    # result that fails to come back (_complete_device), it
+                    # is this batch's failure, and the next one still runs
                     for job in batch:
                         self._fail(job, e)
-                    raise
             while inflight:
                 self._complete_device(*inflight.popleft())
                 self._inflight_child.set(len(inflight))
@@ -828,20 +854,28 @@ class CodecService:
                          **tags) as st:
             dev = jobs_apply_sharded(mesh, head.rows, blocks)
         stage["compute"].observe(st.seconds)
+        # the result sets out for the host as soon as the device has it, on
+        # the runtime's threads: _complete_device picks up a finished copy
+        dev.copy_to_host_async()
         return dev, staged, places
 
     def _complete_device(self, batch: list[_Job], sent, tags: dict) -> None:
         dev, staged, places = sent
         stage = _STAGE[batch[0].cls]
         try:
+            # the copy out began at dispatch: was the program done by now?
+            state = "ready" if dev.is_ready() else "waited"
+            _READBACKS[batch[0].cls][state].inc()
             # np.asarray alone would wait just the same: the split only
             # says how much of it is the device and how much the copy out
             with trace.stage("ec.svc.device_wait", stage["device_wait"],
                              **tags) as wait:
                 dev.block_until_ready()
             with trace.stage("ec.svc.d2h", stage["d2h"], layout=_LAYOUT,
-                             **tags) as copy:
-                out = np.asarray(dev)  # D2H: a copy, (V, R, w_pad) bytes
+                             state=state, **tags) as copy:
+                # the pick-up of (V, R, w_pad) bytes the runtime has copied,
+                # or the wait for the rest of that copy
+                out = np.asarray(dev)
             stage["readback"].observe(wait.seconds + copy.seconds)
             # the result is here, so the device has read the staged jobs
             for block in staged:

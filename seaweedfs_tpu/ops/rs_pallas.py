@@ -105,6 +105,14 @@ class PackedRows:
         self.dev.block_until_ready()
         return self
 
+    def copy_to_host_async(self) -> None:
+        """Start the readback now, on the runtime's threads: ``np.asarray``
+        later picks up a host copy that is under way or done."""
+        self.dev.copy_to_host_async()
+
+    def is_ready(self) -> bool:
+        return self.dev.is_ready()
+
     def __array__(self, dtype=None, copy=None):
         out = np.asarray(self.dev).view(np.uint8).reshape(
             *self.dev.shape[:-2], -1)[..., :self.width]

@@ -135,7 +135,11 @@ def _sharded_apply_jobs(mesh: Mesh, rows: tuple[tuple[int, ...], ...],
     runs job after job on the packed words inside the one program, so its
     temporaries are one job's whatever n is (a vmapped block program's
     grow with V: at V = 8 of the encoder's slices the compiler refuses it
-    on one v5e)."""
+    on one v5e).  On more than one device the result is gathered: every
+    device ends with the whole stack (0.4 bytes over ICI per job byte), so
+    the host fetches ONE array from one device, as it does on one chip —
+    fetching a column-sharded result is an `np.empty` of the whole shape
+    and a slice assignment per shard on the thread that asks for it."""
     apply_one = make_apply_xor(rows)
 
     def gf_apply(*tiles: jax.Array) -> jax.Array:
@@ -146,10 +150,11 @@ def _sharded_apply_jobs(mesh: Mesh, rows: tuple[tuple[int, ...], ...],
     # tells the matrix shapes in mixed traffic apart
     gf_apply.__name__ = f"gf_apply_r{len(rows)}_s{len(rows[0])}"
     cols = mesh.axis_names
+    whole = P() if mesh.size > 1 else P(None, None, cols, None)
     return jax.jit(
         gf_apply,
         in_shardings=(NamedSharding(mesh, P(None, cols, None)),) * n,
-        out_shardings=NamedSharding(mesh, P(None, None, cols, None)))
+        out_shardings=NamedSharding(mesh, whole))
 
 
 def _lane_tile_shape(mesh: Mesh, shape: tuple) -> tuple:

@@ -786,6 +786,14 @@ EC_SERVICE_BLOCK_BYTES = REGISTRY.counter(
     "device-mode bytes sent to the device, every batch at its width bucket",
     labels=("class",),
 )
+# a device batch's readback starts at its dispatch (copy_to_host_async):
+# `ready` = the program had finished when the scheduler came for the result,
+# so what was left to it was to pick up a copy under way or done
+EC_SERVICE_READBACKS = REGISTRY.counter(
+    "seaweedfs_ec_service_readbacks_total",
+    "device batches read back, by whether the result was ready at pick-up",
+    labels=("state", "class"),  # ready | waited; read | pipeline
+)
 EC_SERVICE_STAGE = REGISTRY.histogram(
     "seaweedfs_ec_service_stage_seconds",
     "per-batch wall time in each codec-service stage",

@@ -27,6 +27,7 @@ from seaweedfs_tpu.stats.metrics import (  # noqa: E402
     EC_ENCODES_INFLIGHT,
     EC_SERVICE_BATCH_JOBS,
     EC_SERVICE_BLOCK_BYTES,
+    EC_SERVICE_READBACKS,
     EC_SLICE_BUFFERS,
     EC_SLICE_POOL_BYTES,
 )
@@ -42,13 +43,18 @@ def _clean_service_state():
     codec_service.shutdown_all(timeout=10)
 
 
-def _one_device_service(**kw):
+def _device_service(devices: int = 1, **kw):
+    """A device-mode service on a mesh of the suite's first `devices` host
+    devices, all on the column axis as the service's own mesh is."""
     import jax
 
     from seaweedfs_tpu.parallel.mesh import make_mesh
 
     return CodecService(mode="device", codec_name="tpu_xor",
-                        mesh=make_mesh(jax.devices()[:1]), **kw)
+                        mesh=make_mesh(jax.devices()[:devices], dp=1), **kw)
+
+
+_one_device_service = _device_service
 
 
 def _hold_scheduler(svc):
@@ -63,9 +69,12 @@ def _jobs_per_batch():
     return child.total, child.count
 
 
+def _delivered(result, want) -> bool:
+    return np.array_equal(np.stack([np.asarray(r) for r in result]), want)
+
+
 def _same_as_reference(result, data) -> bool:
-    got = np.stack([np.asarray(r) for r in result])
-    return np.array_equal(got, ref.parity_of(data))
+    return _delivered(result, ref.parity_of(data))
 
 
 # -- V volumes in one block -------------------------------------------------------
@@ -140,6 +149,29 @@ for v in (2, 3, 8):
         "seaweedfs_tpu.ops.codec_service", fromlist=["x"])._INPUT_BYTES.items()}
     out[str(v)]["lone_direct"] = after["direct"] - before["direct"] == lone.size
     svc.close()
+# V equal jobs queued at once are ONE batch of V: its result comes back whole
+from seaweedfs_tpu.parallel import mesh as mesh_mod
+results = []
+real = mesh_mod.jobs_apply_sharded
+mesh_mod.jobs_apply_sharded = lambda *a: (results.append(real(*a)), results[-1])[1]
+for v in (1, 2, 4, 8):
+    rng = np.random.default_rng(38 + v)
+    datas = [rng.integers(0, 256, (10, 16384), dtype=np.uint8) for _ in range(v)]
+    svc = CodecService(mode="device", codec_name="tpu_xor")
+    del results[:]
+    with svc._cond:
+        futs = [svc.submit_parity(d, stream=i) for i, d in enumerate(datas)]
+    got = [f.result(120) for f in futs]
+    svc.close()
+    dev = results[0].dev
+    out["whole%%d" %% v] = {
+        "same": [bool(np.array_equal(np.stack([np.asarray(r) for r in g]), ref.parity_of(d)))
+                 for g, d in zip(got, datas)],
+        "batches": len(results), "shape": list(dev.shape),
+        "replicated": bool(dev.sharding.is_fully_replicated),
+        "on": len(dev.sharding.device_set),
+        "one_readback": len({g.base.ctypes.data if g.base is not None else id(g)
+                             for g in got}) == 1 and not any(g.flags["OWNDATA"] for g in got)}
 print(json.dumps(out))
 """
 
@@ -152,15 +184,177 @@ def four_device_run():
         _FOUR_DEVICE_CHILD % {"root": ROOT, "widths": WIDTHS})
 
 
-@pytest.mark.parametrize("v", [2, 3, 8])
+@pytest.mark.parametrize("v", [2, 3, 8, "whole1", "whole2", "whole4",
+                               "whole8"])
 def test_four_device_mesh_batches_equal_the_reference(four_device_run, v):
     assert four_device_run["devices"] == 4
     got = four_device_run[str(v)]
+    if isinstance(v, str):
+        # V equal jobs, one batch: the (V, R, T, 128) stack is gathered, so
+        # every device holds all of it and the host fetches one array, of
+        # which every job's rows are views
+        v = int(v[len("whole"):])
+        assert got["same"] == [True] * v
+        assert got["batches"] == 1 and got["shape"] == [v, 4, 32, 128]
+        assert got["replicated"] is True and got["on"] == 4
+        assert got["one_readback"] is True
+        return
     assert got["same"] == [True] * v
     # columns over all four devices: no padding volume, whatever V is
     assert got["mesh"] == "1x4"
     assert got["batches"] == _batches_of(WIDTHS[:v], 4)
     assert got["lone_direct"] is True
+
+
+# -- the readback starts at dispatch (ISSUE 38) ---------------------------------------
+
+
+def _readbacks(cls: str) -> dict:
+    return {state: EC_SERVICE_READBACKS.labels(state, cls).value
+            for state in ("ready", "waited")}
+
+
+def _decode_rows():
+    """The 4 x 10 plan of a volume that lost shards 0-3, from the plain
+    reference: survivors 4-13 -> the lost rows."""
+    inv = ref.mat_inv([ref.MATRIX[i] for i in range(4, 14)])
+    return np.asarray(inv[:4], dtype=np.uint8)
+
+
+def _submit_batches(svc, cls: str, rng, batches: int):
+    """`batches` device batches of two jobs each -> [(future, want)]."""
+    out = []
+    rows = _decode_rows()
+    for _ in range(batches):
+        datas = [rng.integers(0, 256, (10, w), dtype=np.uint8)
+                 for w in (4096, 4000)]
+        release = _hold_scheduler(svc)
+        try:
+            if cls == "read":
+                futs = [svc.submit_apply(rows, d, job_class="read")
+                        for d in datas]
+                wants = [ref.apply_rows(rows.tolist(), d) for d in datas]
+            else:
+                futs = [svc.submit_parity(d, stream=i)
+                        for i, d in enumerate(datas)]
+                wants = [ref.parity_of(d) for d in datas]
+        finally:
+            release()
+        if cls == "read":
+            # queued reads of one plan all go side by side into one block:
+            # the next two wait until these are through
+            for fut in futs:
+                fut._job.event.wait(120)
+        out.extend(zip(futs, wants))
+    return out
+
+
+@pytest.mark.parametrize("cls", ["pipeline", "read"])
+@pytest.mark.parametrize("devices", [1, 4])
+def test_readback_starts_inside_dispatch_once_a_batch(
+        monkeypatch, cls, devices):
+    """One `copy_to_host_async` a device batch, called before
+    `_dispatch_device` returns — so before `_complete_device` comes for the
+    result — for both job classes; and the counter says of every batch
+    whether its program had finished by then."""
+    from seaweedfs_tpu.ops import rs_pallas
+
+    events, kept = [], []  # kept: no id is given out twice
+    real_async = rs_pallas.PackedRows.copy_to_host_async
+    monkeypatch.setattr(
+        rs_pallas.PackedRows, "copy_to_host_async", lambda self: (
+            kept.append(self), events.append(("async", id(self))),
+            real_async(self))[2])
+    svc = _device_service(devices)
+    real_dispatch, real_complete = svc._dispatch_device, svc._complete_device
+    monkeypatch.setattr(svc, "_dispatch_device", lambda batch, tags: (
+        events.append(("dispatch", None)), real_dispatch(batch, tags),
+        events.append(("dispatched", None)))[1])
+    monkeypatch.setattr(svc, "_complete_device", lambda batch, sent, tags: (
+        events.append(("complete", id(sent[0]))),
+        real_complete(batch, sent, tags))[1])
+    before = _readbacks(cls)
+    batches0 = EC_SERVICE_BATCH_JOBS.labels(cls).count
+    jobs = _submit_batches(svc, cls, np.random.default_rng(38), 3)
+    for fut, want in jobs:
+        assert _delivered(fut.result(120), want)
+    svc.close()
+    started = [e for e in events if e[0] == "async"]
+    assert len(started) == len({ident for _, ident in started}) == 3
+    for _, ident in started:
+        at = events.index(("async", ident))
+        # inside its own dispatch, and before anybody asks for the result
+        assert events[at - 1] == ("dispatch", None)
+        assert events[at + 1] == ("dispatched", None)
+        assert at < events.index(("complete", ident))
+    after = _readbacks(cls)
+    moved = {st: after[st] - before[st] for st in after}
+    assert sum(moved.values()) == 3 == (
+        EC_SERVICE_BATCH_JOBS.labels(cls).count - batches0)
+    assert min(moved.values()) >= 0
+
+
+def test_d2h_span_says_whether_the_result_was_ready(monkeypatch):
+    from seaweedfs_tpu.telemetry import trace
+
+    seen = []
+    real_stage = trace.stage
+    monkeypatch.setattr(codec_service.trace, "stage", lambda name, hist=None, **attrs: (
+        seen.append((name, attrs)), real_stage(name, hist, **attrs))[1])
+    svc = _one_device_service()
+    before = _readbacks("pipeline")
+    # a result that is long there when the scheduler comes for it
+    real_complete = svc._complete_device
+    monkeypatch.setattr(svc, "_complete_device", lambda batch, sent, tags: (
+        sent[0].block_until_ready(), real_complete(batch, sent, tags))[1])
+    for fut, _want in _submit_batches(svc, "pipeline",
+                                      np.random.default_rng(39), 2):
+        fut.result(120)
+    svc.close()
+    states = [attrs["state"] for name, attrs in seen if name == "ec.svc.d2h"]
+    assert states == ["ready", "ready"]
+    after = _readbacks("pipeline")
+    assert (after["ready"] - before["ready"],
+            after["waited"] - before["waited"]) == (2, 0)
+    assert not any("state" in attrs for name, attrs in seen
+                   if name != "ec.svc.d2h")
+
+
+@pytest.mark.parametrize("cls", ["pipeline", "read"])
+def test_failed_readback_start_fails_its_batch_and_the_next_runs(
+        monkeypatch, cls):
+    """`copy_to_host_async` raises for the second of three batches: every
+    job of that batch fails with it, nobody is left waiting, the batch in
+    flight before it and the one after it deliver, and no readback is
+    counted for a batch that never came back."""
+    from seaweedfs_tpu.ops import rs_pallas
+
+    calls = []
+    real_async = rs_pallas.PackedRows.copy_to_host_async
+
+    def start(self):
+        calls.append(self)
+        if len(calls) == 2:
+            raise RuntimeError("the transfer could not start")
+        return real_async(self)
+
+    monkeypatch.setattr(rs_pallas.PackedRows, "copy_to_host_async", start)
+    svc = _one_device_service()
+    before = _readbacks(cls)
+    jobs = _submit_batches(svc, cls, np.random.default_rng(40), 3)
+    for k, (fut, want) in enumerate(jobs):
+        if k // 2 == 1:
+            with pytest.raises(RuntimeError, match="could not start"):
+                fut.result(120)
+        else:
+            assert _delivered(fut.result(120), want)
+    assert not svc.closed and svc._thread_err is None
+    # the service is still there for whoever comes next
+    for fut, want in _submit_batches(svc, cls, np.random.default_rng(41), 1):
+        assert _delivered(fut.result(120), want)
+    svc.close()
+    after = _readbacks(cls)
+    assert sum(after.values()) - sum(before.values()) == 3
 
 
 # -- what a batch may hold ----------------------------------------------------------
@@ -305,27 +499,33 @@ def test_staging_buffers_are_reused_and_their_padding_is_zero(monkeypatch):
             assert not sent[:, width:].any()  # zeroed again on every reuse
 
 
-def test_program_takes_views_and_jobs_get_views_of_one_readback(monkeypatch):
+@pytest.mark.parametrize("devices", [1, 4])
+def test_program_takes_views_and_jobs_get_views_of_one_readback(
+        monkeypatch, devices):
     """Both bus crossings are copies of nothing on the host: the program
     is handed uint32 lane-tile views of the jobs' own bytes (of the staging
     buffer for a job off its bucket), and every job's result rows are
-    C-contiguous views into the ONE array the readback made."""
+    C-contiguous views into the ONE array the readback made — on a mesh
+    too, where the program's result is gathered and the host fetches it
+    from one device: no whole-shape array is made and filled shard by
+    shard on the scheduler's thread."""
     from seaweedfs_tpu.ops import rs_pallas
     from seaweedfs_tpu.parallel import mesh as mesh_mod
 
-    handed, readbacks = [], []
+    handed, readbacks, on_device = [], [], []
     real_program = mesh_mod._sharded_apply_jobs
 
     def program(mesh, rows, n):
         fn = real_program(mesh, rows, n)
-        return lambda *tiles: (handed.append(tiles), fn(*tiles))[1]
+        return lambda *tiles: (handed.append(tiles), on_device.append(
+            fn(*tiles)), on_device[-1])[2]
 
     monkeypatch.setattr(mesh_mod, "_sharded_apply_jobs", program)
     real_array = rs_pallas.PackedRows.__array__
     monkeypatch.setattr(
         rs_pallas.PackedRows, "__array__", lambda self, *a, **kw: (
             readbacks.append(real_array(self, *a, **kw)), readbacks[-1])[1])
-    svc = _one_device_service()
+    svc = _device_service(devices)
     rng = np.random.default_rng(31)
     datas = [rng.integers(0, 256, (10, w), dtype=np.uint8)
              for w in (65536, 65536, 65000, 65536)]
@@ -337,6 +537,9 @@ def test_program_takes_views_and_jobs_get_views_of_one_readback(monkeypatch):
     results = [fut.result(120) for fut in futs]
     svc.close()
     (tiles,), (readback,) = handed, readbacks  # one batch, one readback
+    (dev,) = on_device
+    assert len(dev.sharding.device_set) == devices
+    assert dev.sharding.is_fully_replicated  # whole on every device
     for tile, data in zip(tiles, datas):
         assert tile.dtype == np.uint32 and tile.shape == (10, 128, 128)
         whole = data.shape[1] == 65536

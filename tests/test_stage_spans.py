@@ -353,6 +353,59 @@ def test_slice_fresh_pct_is_left_out_by_a_program_without_the_counter():
     assert readers.read_metric("ec_slice_fresh_pct.encode", obs) is None
 
 
+# -- the codec service's readbacks (ISSUE 38) ----------------------------------
+
+
+def test_readback_ready_pct_reads_the_services_counter(device_service):
+    """`svc_readback_ready_pct.batch4`: the last entry of `per_layer`, a
+    data file for the reader the benchmark has, 100 x `ready` / all of the
+    family the service moves once a device batch."""
+    from seaweedfs_tpu.stats.metrics import EC_SERVICE_READBACKS
+
+    counts = {st: EC_SERVICE_READBACKS.labels(st, "pipeline")
+              for st in ("ready", "waited")}
+    before = {st: c.value for st, c in counts.items()}
+
+    def work():
+        for seed in range(5):
+            device_service.submit_parity(_block(seed)).result(120)
+
+    obs = _obs_over(work, ("window",))
+    moved = {st: c.value - before[st] for st, c in counts.items()}
+    assert sum(moved.values()) == 5
+    for state in moved:
+        assert ('seaweedfs_ec_service_readbacks_total{state="%s",'
+                'class="pipeline"}' % state) in REGISTRY.render()
+    entry = BENCH["per_layer"][-1]
+    assert entry == {
+        "name": "svc_readback_ready_pct.batch4", "unit": "%",
+        "better": "higher", "source": "program_counter",
+        "moves": "encode_MBps",
+        "layer": next(m["layer"] for m in BENCH["per_layer"]
+                      if m["name"] == "svc_d2h_s_per_GB.batch4"),
+        "workloads": ["ec-batch-4chip"]}
+    spec = readers.metric_spec(entry["name"])
+    assert set(spec) == {"reader", "args"} and spec["reader"] == "prom_ratio"
+    assert readers.read_metric(entry["name"], obs) == pytest.approx(
+        100.0 * moved["ready"] / 5)
+
+
+def test_readback_ready_pct_is_left_out_by_a_program_without_the_counter():
+    """The parent has no such family: the reader finds nothing and the
+    line leaves the metric out.  A window in which every batch was waited
+    for reads 0."""
+    obs = hz.Obs()
+    scrape = hz.parse_metrics(
+        'seaweedfs_ec_service_batch_jobs_count{class="pipeline"} 5\n')
+    obs.prom["window"] = [scrape, dict(scrape)]
+    assert readers.read_metric("svc_readback_ready_pct.batch4", obs) is None
+    series = 'seaweedfs_ec_service_readbacks_total{state="%s",class="pipeline"} %d\n'
+    obs.prom["window"] = [
+        hz.parse_metrics(series % ("ready", 0) + series % ("waited", 2)),
+        hz.parse_metrics(series % ("ready", 0) + series % ("waited", 9))]
+    assert readers.read_metric("svc_readback_ready_pct.batch4", obs) == 0.0
+
+
 # -- the event-loop front end --------------------------------------------------
 
 

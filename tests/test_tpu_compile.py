@@ -127,17 +127,20 @@ def test_service_batch_program_fits_hbm_twice(topo, what):
     assert seconds < 60
 
 
-@pytest.mark.parametrize("chips,v", [(1, 1), (1, 8), (4, 8), (4, 16)])
+@pytest.mark.parametrize("chips,v", [(1, 1), (1, 8), (4, 1), (4, 8), (4, 16)])
 def test_batches_up_to_the_cap_fit_hbm_twice(topo, chips, v):
     """Batches up to the largest CodecService._device_max_volumes lets
     through at the encoder's slice width — eight volumes' slices on one
     chip, sixteen (the job cap) over four — fit each chip by the compiler's
     own count: two batches' arrays resident and one program's temporaries,
-    inside the share the cap is derived from.  The two ratios the cap is
-    computed from are upper bounds of the compiler's: measured for the
-    uint8 program of before (2.01 resident, 12.4 = 1,984 MiB of
-    temporaries), where the lane-tile program keeps 1.40 (nothing pads) and
-    7.1 (1,136 MiB on one chip at V = 1-8; 200 MiB a chip over four)."""
+    inside the share the cap is derived from, and no more than the cap
+    itself counts for them.  The two ratios the cap is computed from are
+    upper bounds of the compiler's: measured for the uint8 program of
+    before (2.01 resident, 12.4 = 1,984 MiB of temporaries), where the
+    lane-tile program keeps 1.40 (nothing pads) and 7.1 (1,136 MiB on one
+    chip at V = 1-8).  Over four chips the result is gathered (ISSUE 38):
+    a chip holds a quarter of every input and the WHOLE of every result,
+    and the gather's own copy of the results is a temporary."""
     from seaweedfs_tpu.ops import codec_service as cs
     from seaweedfs_tpu.storage.ec.encoder import DEFAULT_SLICE
 
@@ -154,13 +157,26 @@ def test_batches_up_to_the_cap_fit_hbm_twice(topo, chips, v):
     compiled, seconds = _compile(fn, *jobs)
     mem = compiled.memory_analysis()  # per device
     resident = mem.argument_size_in_bytes + mem.output_size_in_bytes
-    assert 2 * resident + mem.temp_size_in_bytes <= cs._HBM_SHARE * HBM_BYTES
+    held = 2 * resident + mem.temp_size_in_bytes
+    assert held <= cs._HBM_SHARE * HBM_BYTES
     per_job_byte = job_bytes / chips
-    assert resident / v / per_job_byte == pytest.approx(1.4, abs=0.01)
+    # what _device_max_volumes counts for this batch on one device
+    assert held <= per_job_byte * (
+        cs._HBM_TEMP_PER_JOB_BYTE + 2 * v * svc._resident_per_job_byte())
+    # a device's share of a job's ten rows in, four rows out: its share on
+    # one chip, all of them on a mesh
+    assert mem.output_size_in_bytes == v * 4 * DEFAULT_SLICE
+    assert resident / v / per_job_byte == pytest.approx(
+        1 + 0.4 * chips, abs=0.01)
     assert 1.4 < cs._HBM_RESIDENT_PER_JOB_BYTE
-    temps = mem.temp_size_in_bytes / per_job_byte
-    assert 4 < temps < 8 < cs._HBM_TEMP_PER_JOB_BYTE, (
+    gathered = (chips > 1) * mem.output_size_in_bytes
+    temps = (mem.temp_size_in_bytes - gathered) / per_job_byte
+    assert temps < 8 < cs._HBM_TEMP_PER_JOB_BYTE, (
         f"{mem.temp_size_in_bytes / MIB:.0f} MiB of temporaries")
+    if chips == 1:
+        assert 4 < temps
+    else:
+        assert "all-gather" in compiled.as_text()
     assert seconds < 90
     svc.close()
 
@@ -180,7 +196,7 @@ def test_read_programs_compile_at_every_read_bucket(topo, chips):
         fn, jobs = _jobs_program(mesh, row, 1, width)
         compiled, seconds = _compile(fn, *jobs)
         mem = compiled.memory_analysis()  # per device
-        assert mem.output_size_in_bytes == 4 * width // chips
+        assert mem.output_size_in_bytes == 4 * width  # gathered on four
         assert mem.temp_size_in_bytes < 16 * 10 * width // chips
         assert seconds < 30
 
