@@ -1249,11 +1249,9 @@ def _flight_overhead(n: int = 8000, concurrency: int = 16) -> dict:
 
 
 def _hist_child_snapshot(hist, *labels):
-    """(counts[], count, total) for one histogram child — bench-side
-    delta arithmetic over the in-process registry."""
-    child = hist.labels(*labels)
-    with child._lock:
-        return list(child.counts), child.count, child.total
+    """(cumulative counts[], count, total) for one histogram child —
+    bench-side delta arithmetic over the in-process registry."""
+    return hist.labels(*labels).cumulative()
 
 
 def _hist_quantile(buckets, counts, count, q: float) -> float:
@@ -1708,7 +1706,7 @@ def _service_rates() -> dict:
       bulk encode loses nothing by routing through the service
       (`service_bulk_ratio`, expect ~0.9-1.0: kernel-bound either way).
 
-    Occupancy and p50/p99 job latency come from the
+    Occupancy and the p50/p99 of a job's queue wait come from the
     seaweedfs_ec_service_* registry deltas, so the numbers folded into
     the JSON are exactly what /metrics would report.
     """
@@ -1720,7 +1718,7 @@ def _service_rates() -> dict:
     from seaweedfs_tpu.ops.rs_cpu import ReedSolomon
     from seaweedfs_tpu.stats.metrics import (
         EC_SERVICE_BATCH_JOBS,
-        EC_SERVICE_JOB_SECONDS,
+        EC_SERVICE_STAGE,
     )
 
     n_vol = int(os.environ.get("SEAWEEDFS_TPU_BENCH_SERVICE_VOLUMES", "8"))
@@ -1788,8 +1786,8 @@ def _service_rates() -> dict:
     # best-of-2 (same reasoning as every other stage on this noisy host)
     base_dt = svc_dt = None
     occ_before = _hist_child_snapshot(EC_SERVICE_BATCH_JOBS, "pipeline")
-    lat_before = {k: _hist_child_snapshot(EC_SERVICE_JOB_SECONDS, k)
-                  for k in ("parity", "apply")}
+    lat_before = _hist_child_snapshot(EC_SERVICE_STAGE, "queue_wait",
+                                      "pipeline")
     for trial in range(2):
         t0 = time.perf_counter()
         with ThreadPoolExecutor(n_vol) as pool:
@@ -1810,25 +1808,18 @@ def _service_rates() -> dict:
     if jobs_delta > 0:
         result["service_batch_occupancy_mean"] = round(
             (occ_after[2] - occ_before[2]) / jobs_delta, 2)
-    # p50/p99 job latency over the service runs, from the histogram delta
-    lat_counts = None
-    for k in ("parity", "apply"):
-        before, after = lat_before[k], _hist_child_snapshot(
-            EC_SERVICE_JOB_SECONDS, k)
-        d = [a - b for a, b in zip(after[0], before[0])]
-        if lat_counts is None:
-            lat_counts, lat_n = d, after[1] - before[1]
-        else:
-            lat_counts = [x + y for x, y in zip(lat_counts, d)]
-            lat_n += after[1] - before[1]
-    if lat_counts and lat_n:
-        # _HistogramChild.counts are already cumulative (observe bumps
-        # every bucket whose bound >= v), and deltas of cumulative
-        # counts stay cumulative — no further cumsum
-        buckets = EC_SERVICE_JOB_SECONDS.buckets
-        result["service_job_p50_ms"] = round(
+    # p50/p99 of a job's wait for the scheduler (submit -> popped into a
+    # batch) over the service runs, from the histogram delta: deltas of
+    # cumulative counts stay cumulative
+    lat_after = _hist_child_snapshot(EC_SERVICE_STAGE, "queue_wait",
+                                     "pipeline")
+    lat_counts = [a - b for a, b in zip(lat_after[0], lat_before[0])]
+    lat_n = lat_after[1] - lat_before[1]
+    if lat_n:
+        buckets = EC_SERVICE_STAGE.buckets
+        result["service_queue_wait_p50_ms"] = round(
             _hist_quantile(buckets, lat_counts, lat_n, 0.50) * 1000, 3)
-        result["service_job_p99_ms"] = round(
+        result["service_queue_wait_p99_ms"] = round(
             _hist_quantile(buckets, lat_counts, lat_n, 0.99) * 1000, 3)
     svc.close()
 

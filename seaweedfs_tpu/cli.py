@@ -76,10 +76,12 @@ def cmd_master(args) -> None:
 
 def cmd_volume(args) -> None:
     from .util.config import load_configuration
+    from .telemetry.interpreter import start_wait_probe
     from .util.hostmem import keep_freed_memory
     from .volume.server import VolumeServer
 
     keep_freed_memory()  # slices and readbacks reuse the heap's pages
+    start_wait_probe()  # seaweedfs_interpreter_wait_seconds
 
     if getattr(args, "offset5", False):
         from .storage import types as _t
@@ -120,10 +122,12 @@ def cmd_server(args) -> None:
     one process (command/server.go)."""
     from .master.server import MasterServer
     from .util.config import load_configuration
+    from .telemetry.interpreter import start_wait_probe
     from .util.hostmem import keep_freed_memory
     from .volume.server import VolumeServer
 
     keep_freed_memory()  # slices and readbacks reuse the heap's pages
+    start_wait_probe()  # seaweedfs_interpreter_wait_seconds
     codec = getattr(args, "ec_codec", "")
     if not codec:
         codec = load_configuration("master").get_string("codec.type", "cpu")

@@ -83,7 +83,6 @@ from ..stats.metrics import (
     EC_SERVICE_FLUSH,
     EC_SERVICE_INFLIGHT,
     EC_SERVICE_INPUT_BYTES,
-    EC_SERVICE_JOB_SECONDS,
     EC_SERVICE_JOBS,
     EC_SERVICE_QUEUE_DEPTH,
     EC_SERVICE_READBACKS,
@@ -105,10 +104,15 @@ JOB_CLASSES = ("pipeline", "read")
 # In device mode `compute` and `readback` are the coarser stages of before
 # the split, observed with the extent they always had (`enqueue`, and
 # `device_wait` + `d2h`): the benchmark's svc_devwait_s_per_GB.* reads
-# them, and only a `benchmark` PR may repoint it.
+# them, and only a `benchmark` PR may repoint it.  `parked` and `handback`
+# are the two waits of a job's round trip no span covers: a dispatched
+# device batch's wait in `inflight` until the scheduler's thread comes back
+# for it, and a delivered job's wait until its requester runs again.  With
+# them, queue_wait + build + enqueue + parked + device_wait + d2h + deliver
+# + handback is a job's submit -> result().
 _STAGE = {c: {st: EC_SERVICE_STAGE.labels(st, c) for st in (
-    "queue_wait", "build", "enqueue", "device_wait", "d2h", "deliver",
-    "compute", "readback")} for c in JOB_CLASSES}
+    "queue_wait", "build", "enqueue", "parked", "device_wait", "d2h",
+    "deliver", "handback", "compute", "readback")} for c in JOB_CLASSES}
 _INPUT_BYTES = {p: EC_SERVICE_INPUT_BYTES.labels(p)
                 for p in ("direct", "staged")}
 _BLOCK_BYTES = {c: EC_SERVICE_BLOCK_BYTES.labels(c) for c in JOB_CLASSES}
@@ -167,7 +171,8 @@ def _env_int(name: str, default: int) -> int:
 
 class _Job:
     __slots__ = ("kind", "key", "plan", "rows", "data", "width", "out",
-                 "stream", "cls", "event", "result", "error", "t_submit")
+                 "stream", "cls", "event", "result", "error", "t_submit",
+                 "t_set")
 
     def __init__(self, kind, key, plan, rows, data, width, out, stream=None,
                  cls="pipeline"):
@@ -191,6 +196,10 @@ class _Job:
         self.result = None  # (R, W) array-like of rows once delivered
         self.error: "Exception | None" = None
         self.t_submit = time.perf_counter()
+        # perf_counter just before the scheduler sets `event` (_deliver,
+        # _fail); 0.0 once the requester's handback is observed, or for a
+        # job delivered inline
+        self.t_set = 0.0
 
 
 class CodecFuture:
@@ -206,11 +215,18 @@ class CodecFuture:
         return self._job.event.is_set()
 
     def result(self, timeout: "float | None" = None):
-        if not self._job.event.wait(timeout):
+        job = self._job
+        if not job.event.wait(timeout):
             raise TimeoutError("codec service job not done")
-        if self._job.error is not None:
-            raise self._job.error
-        return self._job.result
+        if job.t_set:
+            # the first result() after delivery: the requester's wait from
+            # the scheduler's event.set() until it runs again
+            _STAGE[job.cls]["handback"].observe(
+                time.perf_counter() - job.t_set)
+            job.t_set = 0.0
+        if job.error is not None:
+            raise job.error
+        return job.result
 
 
 class CodecService:
@@ -292,8 +308,6 @@ class CodecService:
                         for k in ("parity", "apply")}
         self._job_err = {k: EC_SERVICE_JOBS.labels(k, "error")
                          for k in ("parity", "apply")}
-        self._job_secs = {k: EC_SERVICE_JOB_SECONDS.labels(k)
-                          for k in ("parity", "apply")}
         self._flush_children = {r: EC_SERVICE_FLUSH.labels(r)
                                 for r in ("full", "bytes", "ready", "drain")}
 
@@ -633,14 +647,15 @@ class CodecService:
             job.result = job.out
         else:
             job.result = result
+        job.t_set = time.perf_counter()
         job.event.set()
         self._job_ok[job.kind].inc()
-        self._job_secs[job.kind].observe(time.perf_counter() - job.t_submit)
 
     def _fail(self, job: _Job, err: Exception) -> None:
         if job.event.is_set():
             return
         job.error = err
+        job.t_set = time.perf_counter()
         job.event.set()
         self._job_err[job.kind].inc()
 
@@ -810,7 +825,8 @@ class CodecService:
 
     def _dispatch_device(self, batch: list[_Job], tags: dict):
         """-> (device array, staging buffers used, where each job's
-        result lies in it: (block, first column))."""
+        result lies in it: (block, first column), perf_counter at the end
+        of its `enqueue`, where its `parked` begins)."""
         from ..parallel.mesh import jobs_apply_sharded
 
         mesh = self._device_mesh()
@@ -872,15 +888,17 @@ class CodecService:
         with trace.stage("ec.svc.enqueue", stage["enqueue"], layout=_LAYOUT,
                          plans=len({j.plan for j in batch}), **tags) as st:
             dev = jobs_apply_sharded(mesh, matrices, blocks)
+        t_enqueued = time.perf_counter()
         stage["compute"].observe(st.seconds)
         # the result sets out for the host as soon as the device has it, on
         # the runtime's threads: _complete_device picks up a finished copy
         dev.copy_to_host_async()
-        return dev, staged, places
+        return dev, staged, places, t_enqueued
 
     def _complete_device(self, batch: list[_Job], sent, tags: dict) -> None:
-        dev, staged, places = sent
+        dev, staged, places, t_enqueued = sent
         stage = _STAGE[batch[0].cls]
+        stage["parked"].observe(time.perf_counter() - t_enqueued)
         try:
             # the copy out began at dispatch: was the program done by now?
             state = "ready" if dev.is_ready() else "waited"

@@ -10,6 +10,7 @@ protocol worth owning.
 
 from __future__ import annotations
 
+import bisect
 import os
 import re
 import threading
@@ -21,6 +22,15 @@ _DEFAULT_BUCKETS = (
     0.0001, 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
     0.25, 0.5, 1.0, 2.5, 5.0, 10.0,
 )
+
+# Bounds for the histograms a tail is read from: quarter-octave steps (each
+# bound x 2^(1/4), about 19 % above the last) from 0.1 ms to 6.55 s, so a
+# p95 interpolated inside one bucket is off by at most that step, where the
+# default bounds' steps of 2-5 x leave it anywhere in a decade.  Merged with
+# _DEFAULT_BUCKETS so that every `le` series the default exports, and an
+# operator's rule reads, is still there.
+TAIL_BUCKETS = tuple(sorted(set(_DEFAULT_BUCKETS) | {
+    float(f"{1e-4 * 2 ** (k / 4):.4g}") for k in range(65)}))
 
 # exemplar rotation window: each histogram bucket remembers the SLOWEST
 # recent observation's trace id for this long before a smaller sample may
@@ -186,14 +196,14 @@ class _HistogramChild:
         self._lock = threading.Lock()
 
     def observe(self, v: float, trace_id: str | None = None) -> None:
+        # the first bound >= v, or len(buckets) (+Inf): counts are per
+        # bucket here and cumulated only when rendered
+        idx = bisect.bisect_left(self.buckets, v)
         with self._lock:
             self.total += v
             self.count += 1
-            idx = len(self.buckets)
-            for i, b in enumerate(self.buckets):
-                if v <= b:
-                    self.counts[i] += 1
-                    idx = min(idx, i)
+            if idx < len(self.counts):
+                self.counts[idx] += 1
             if trace_id:
                 cur = self.exemplars.get(idx)
                 now = time.time()
@@ -203,6 +213,18 @@ class _HistogramChild:
                 if (cur is None or v >= cur[0]
                         or now - cur[2] > EXEMPLAR_WINDOW_S):
                     self.exemplars[idx] = [v, trace_id, now]
+
+    def cumulative(self) -> "tuple[list[int], int, float]":
+        """(cumulative count at each bound, count, sum), one consistent
+        snapshot: what the exposition's `_bucket`, `_count` and `_sum`
+        series say."""
+        with self._lock:
+            counts, count, total = list(self.counts), self.count, self.total
+        run = 0
+        for i, c in enumerate(counts):
+            run += c
+            counts[i] = run
+        return counts, count, total
 
     def time(self):
         return _Timer(self)
@@ -269,7 +291,8 @@ class Histogram(Metric):
             items = list(self._children.items())
         for key, child in items:
             base = dict(zip(self.label_names, key))
-            for b, c in zip(child.buckets, child.counts):
+            counts, count, total = child.cumulative()
+            for b, c in zip(child.buckets, counts):
                 labels = {**base, "le": format_le(b)}
                 pairs = ",".join(
                     f'{n}="{escape_label_value(v)}"'
@@ -280,10 +303,10 @@ class Histogram(Metric):
                 f'{n}="{escape_label_value(v)}"'
                 for n, v in {**base, "le": "+Inf"}.items()
             )
-            out.append(f"{self.name}_bucket{{{inf_pairs}}} {child.count}")
+            out.append(f"{self.name}_bucket{{{inf_pairs}}} {count}")
             ls = self._label_str(key)
-            out.append(f"{self.name}_sum{ls} {child.total}")
-            out.append(f"{self.name}_count{ls} {child.count}")
+            out.append(f"{self.name}_sum{ls} {total}")
+            out.append(f"{self.name}_count{ls} {count}")
         return out
 
 
@@ -592,6 +615,7 @@ EC_DEGRADED_STAGE = REGISTRY.histogram(
     # gather: the survivor intervals read (local preads, remote fetches) |
     # decode: the GF row applied (codec-service submit -> result, or inline)
     labels=("stage",),
+    buckets=TAIL_BUCKETS,
 )
 
 # fault-tolerance layer (util/failsafe.py, util/faultpoint.py) — declared
@@ -774,11 +798,6 @@ EC_SERVICE_JOBS = REGISTRY.counter(
     "codec-service jobs by kind and outcome",
     labels=("kind", "result"),  # parity|apply x ok|error
 )
-EC_SERVICE_JOB_SECONDS = REGISTRY.histogram(
-    "seaweedfs_ec_service_job_seconds",
-    "codec-service job wall time, submit to delivered result",
-    labels=("kind",),
-)
 EC_SERVICE_INPUT_BYTES = REGISTRY.counter(
     "seaweedfs_ec_service_input_bytes_total",
     "device-mode input bytes (unpadded) by how the batch reached the jit",
@@ -804,9 +823,10 @@ EC_SERVICE_READBACKS = REGISTRY.counter(
 EC_SERVICE_STAGE = REGISTRY.histogram(
     "seaweedfs_ec_service_stage_seconds",
     "per-batch wall time in each codec-service stage",
-    # queue_wait (per job) | build | enqueue | device_wait | d2h | deliver,
-    # and compute | readback (ops/codec_service.py says which is which);
-    # class: read | pipeline, the batch's (queue_wait: the job's)
+    # queue_wait (per job) | build | enqueue | parked | device_wait | d2h |
+    # deliver | handback (per job), and compute | readback
+    # (ops/codec_service.py says which is which); class: read | pipeline,
+    # the batch's (queue_wait, handback: the job's)
     labels=("stage", "class"),
 )
 
@@ -1123,6 +1143,26 @@ HTTPD_RESIDENT = REGISTRY.histogram(
     "seaweedfs_httpd_resident_seconds",
     "request head buffered on the event loop -> response flushed",
     labels=("surface", "method"),
+    buckets=TAIL_BUCKETS,
+)
+# before `resident` begins: the kernel's receive timestamp of the read that
+# began a request head -> the loop has the whole head (both CLOCK_REALTIME).
+# It holds the loop thread's wait for the interpreter after select returns,
+# and the re-arm of a kept-alive connection.  A kernel that gives a TCP
+# socket no receive timestamp leaves the family without a series.
+HTTPD_SOCKET_WAIT = REGISTRY.histogram(
+    "seaweedfs_httpd_socket_wait_seconds",
+    "kernel receive time of a request head's first bytes -> head buffered",
+    labels=("surface", "method"),
+    buckets=TAIL_BUCKETS,
+)
+# how late a thread that asked to sleep a fixed period woke
+# (telemetry/interpreter.py): the wait any runnable thread of the process
+# pays for the interpreter, at every hand-over between threads
+INTERPRETER_WAIT = REGISTRY.histogram(
+    "seaweedfs_interpreter_wait_seconds",
+    "a runnable thread's wait for the interpreter, sampled every 10 ms",
+    buckets=TAIL_BUCKETS,
 )
 EC_PREADV_BATCHES = REGISTRY.counter(
     "seaweedfs_ec_preadv_batches_total",

@@ -771,7 +771,10 @@ class EcVolume:
             shards = self._gather(shard_id, offset, length)
         if isinstance(shards, bytes):  # the partial-sum protocol's answer
             return shards, token
-        with trace.stage("ec.degraded.decode", _STAGE_DECODE, **tags):
+        # the decode's round trip is a wait on the codec service's thread:
+        # observed and in the ring, but not on the profiler's clock
+        with trace.stage("ec.degraded.decode", _STAGE_DECODE,
+                         annotated=False, **tags):
             svc = self._decode_service()
             if svc is not None:
                 # concurrent reads against the same survivor set (the

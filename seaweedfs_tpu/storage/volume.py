@@ -23,6 +23,7 @@ import time
 
 from . import types as t
 from ..ops import crc32c
+from ..telemetry import trace
 from ..util import faultpoint, glog
 from .backend import DiskFile, get_backend
 from .disk_health import DiskFullError, classify_write_error
@@ -290,7 +291,10 @@ class Volume:
         flush barrier OUTSIDE the lock — concurrent writers keep
         appending while this one waits, and one fsync acks them all."""
         group = self._group
-        with self._lock:
+        # the stage is the append's own work, on the profiler's clock: it
+        # starts once the lock is held, and the flush barrier's wait below
+        # is outside it
+        with self._lock, trace.stage("volume.write", volume=self.volume_id):
             self._check_writable()
             start = self._dat.file_size()
             offset = start

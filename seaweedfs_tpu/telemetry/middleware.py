@@ -45,7 +45,8 @@ def record_op(server_type: str, op: str, enclosing: bool = False, **attrs):
     The span is also put on the profiler's clock (`trace.annotate`), so
     handler time shows in a device trace's idle gaps — unless the caller
     says it is `enclosing`: a gRPC method holds a whole encode or
-    rebuild, whose stages carry their own spans."""
+    rebuild, and a volume server's request a needle read with its
+    gather and decode round trip, whose stages carry their own spans."""
     REQUEST_COUNTER.labels(server_type, op).inc()
     hist = REQUEST_HISTOGRAM.labels(server_type, op)
     name = f"{server_type}.{op}"
@@ -68,9 +69,12 @@ def record_op(server_type: str, op: str, enclosing: bool = False, **attrs):
 
 
 @contextmanager
-def http_request(handler, server_type: str, op: str):
+def http_request(handler, server_type: str, op: str, enclosing: bool = False):
     """`record_op` for a BaseHTTPRequestHandler request: adopts the
-    caller's `traceparent` (if any) so the span joins their trace."""
+    caller's `traceparent` (if any) so the span joins their trace.
+    `enclosing` as in `record_op`: a request that holds other stages or
+    waits on other threads (the volume server's) stays off the
+    profiler's clock."""
     incoming = handler.headers.get(trace.TRACEPARENT)
     # heavy-hitter attribution: every HTTP request feeds the peer-IP
     # sketch, so "which client is hammering us" is answerable on any
@@ -82,7 +86,7 @@ def http_request(handler, server_type: str, op: str):
         hotkeys.record("peer", addr[0])
     with trace.remote_context(incoming):
         with record_op(
-            server_type, op,
+            server_type, op, enclosing=enclosing,
             method=handler.command, path=handler.path.split("?")[0],
         ) as span:
             yield span

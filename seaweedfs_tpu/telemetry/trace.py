@@ -267,17 +267,20 @@ class _Stage:
 
 
 @contextmanager
-def stage(name: str, hist_child=None, **attrs):
+def stage(name: str, hist_child=None, annotated: bool = True, **attrs):
     """One stage boundary of the served path, seen three ways at once:
     its elapsed `perf_counter` time observed into `hist_child` (a
     histogram child, or None), an `annotate` span on the profiler's
     clock, and `child_span`'s rule for the ring (recorded for
     /debug/traces only inside an active request trace).  Yields a handle
-    whose `.seconds` holds the elapsed time after the block."""
+    whose `.seconds` holds the elapsed time after the block.  A stage in
+    which the thread only waits for another thread says `annotated=False`
+    and stays off the profiler's clock (see `annotate`)."""
     st = _Stage()
+    bridge = annotate(name, **attrs) if annotated else _NO_ANNOTATION
     t0 = time.perf_counter()
     try:
-        with annotate(name, **attrs), child_span(name, **attrs) as st.span:
+        with bridge, child_span(name, **attrs) as st.span:
             yield st
     finally:
         st.seconds = time.perf_counter() - t0

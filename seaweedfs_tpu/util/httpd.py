@@ -45,6 +45,8 @@ from __future__ import annotations
 import os
 import selectors
 import socket
+import struct
+import sys
 import threading
 import time
 from collections import deque
@@ -57,6 +59,35 @@ LISTEN_BACKLOG = 128
 # must never buffer unbounded header bytes for a client that never
 # sends the terminating blank line
 MAX_HEADER_BYTES = 64 << 10
+
+# Kernel receive timestamps on an accepted connection: the control message
+# type (= the socket option) -> seconds per unit of its second field.
+# Linux's SO_TIMESTAMPNS (35: timespec) and SO_TIMESTAMP (29: timeval);
+# Python names neither.  Both are asked for, the older first: where the
+# kernel honours both, the nanosecond one is what it sends.
+_RX_STAMPS = {29: 1e-6, 35: 1e-9} if sys.platform.startswith("linux") else {}
+_RX_CMSG_SPACE = socket.CMSG_SPACE(16)
+
+
+def _enable_rx_stamps(sock: socket.socket) -> None:
+    """Ask the kernel to stamp what this socket receives (a kernel that
+    takes the option may still send no stamp: gVisor's TCP does not)."""
+    for opt in _RX_STAMPS:
+        try:
+            sock.setsockopt(socket.SOL_SOCKET, opt, 1)
+        except OSError:
+            pass
+
+
+def _rx_stamp(ancdata) -> float:
+    """The kernel's receive time (CLOCK_REALTIME seconds) in a recvmsg's
+    control messages, or 0.0 where there is none."""
+    for level, kind, data in ancdata:
+        unit = _RX_STAMPS.get(kind) if level == socket.SOL_SOCKET else None
+        if unit is not None and len(data) >= 16:
+            sec, frac = struct.unpack_from("qq", data)
+            return sec + frac * unit
+    return 0.0
 
 
 def _somaxconn() -> int:
@@ -336,7 +367,7 @@ class _PrefixedRFile:
 
 
 class _Conn:
-    __slots__ = ("sock", "addr", "buf", "last", "t_dispatch")
+    __slots__ = ("sock", "addr", "buf", "last", "t_dispatch", "t_arrived")
 
     def __init__(self, sock, addr):
         self.sock = sock
@@ -344,6 +375,9 @@ class _Conn:
         self.buf = bytearray()
         self.last = time.monotonic()
         self.t_dispatch = 0.0  # perf_counter when the loop handed it over
+        # the kernel's receive time of the read that began the request head
+        # being buffered (0.0: none given); None until that read
+        self.t_arrived: "float | None" = None
 
 
 class EventLoopHTTPServer:
@@ -359,6 +393,7 @@ class EventLoopHTTPServer:
             HTTPD_INFLIGHT,
             HTTPD_OPEN_SOCKETS,
             HTTPD_RESIDENT,
+            HTTPD_SOCKET_WAIT,
         )
 
         self.RequestHandlerClass = handler_cls
@@ -381,6 +416,9 @@ class EventLoopHTTPServer:
         self._listen = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
         self._listen.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
         self._listen.bind(server_address)
+        # on the listening socket too: a connection inherits it, so the
+        # head a client sends before its accept is stamped as well
+        _enable_rx_stamps(self._listen)
         self._listen.listen(listen_backlog())
         self._listen.setblocking(False)
         self.server_address = self._listen.getsockname()
@@ -403,6 +441,11 @@ class EventLoopHTTPServer:
                 HTTPD_RESIDENT.labels(surface, m))
             for m in ["other"] + [n[3:] for n in dir(handler_cls)
                                   if n.startswith("do_")]}
+        # socket-wait children by method, made at a method's first
+        # observation (loop thread only): a kernel that stamps nothing
+        # leaves the family without a series, never a row of zeros
+        self._socket_wait_family = HTTPD_SOCKET_WAIT
+        self._socket_wait: dict = {}
 
     # -- loop thread ------------------------------------------------------
 
@@ -451,6 +494,7 @@ class EventLoopHTTPServer:
             except OSError:
                 pass
             sock.setblocking(False)
+            _enable_rx_stamps(sock)
             conn = _Conn(sock, addr)
             self._conns.add(conn)
             self._open_gauge.set(len(self._conns))
@@ -468,7 +512,8 @@ class EventLoopHTTPServer:
 
     def _readable(self, conn: _Conn) -> None:
         try:
-            data = conn.sock.recv(65536)
+            data, anc, _flags, _addr = conn.sock.recvmsg(
+                65536, _RX_CMSG_SPACE)
         except (BlockingIOError, InterruptedError):
             return
         except OSError:
@@ -477,6 +522,8 @@ class EventLoopHTTPServer:
         if not data:
             self._close_conn(conn)
             return
+        if conn.t_arrived is None:  # this read begins a request head
+            conn.t_arrived = _rx_stamp(anc)
         conn.buf += data
         conn.last = time.monotonic()
         if b"\r\n\r\n" in conn.buf:
@@ -503,6 +550,15 @@ class EventLoopHTTPServer:
             self._sel.unregister(conn.sock)
         except (KeyError, ValueError):
             pass
+        if conn.t_arrived:
+            method = bytes(conn.buf[:conn.buf.find(b" ")]).decode("latin-1")
+            if method not in self._wait_children:
+                method = "other"
+            child = self._socket_wait.get(method)
+            if child is None:
+                child = self._socket_wait[method] = \
+                    self._socket_wait_family.labels(self.surface, method)
+            child.observe(max(0.0, time.time() - conn.t_arrived))
         self._submit(conn)
 
     def _submit(self, conn: _Conn) -> None:
@@ -546,6 +602,7 @@ class EventLoopHTTPServer:
             self._inflight_gauge.dec()
         if keep and not self._shutdown_evt.is_set():
             conn.buf = bytearray(rfile.leftover())
+            conn.t_arrived = None  # the next read begins the next head
             conn.last = time.monotonic()
             try:
                 conn.sock.setblocking(False)

@@ -145,7 +145,10 @@ def test_profiler_session_holds_one_split_span_per_batch(
     jax.profiler.start_trace(str(tmp_path), profiler_options=options)
     try:
         assert rpc(None, Context()) == "done"
-        with record_op("volumeServer", "get", method="GET", path="/3"):
+        with record_op("filer", "get", method="GET", path="/3"):
+            pass
+        with record_op("volumeServer", "get", enclosing=True, method="GET",
+                       path="/3"):
             pass
     finally:
         jax.profiler.stop_trace()
@@ -158,7 +161,8 @@ def test_profiler_session_holds_one_split_span_per_batch(
         assert host[name] == batches, (name, host)
     # (a result is handed over inside `deliver`, which closes just after)
     assert batches - 1 <= host["ec.svc.deliver"] <= batches
-    assert host["volumeServer.get"] == 1          # request spans are bridged
+    assert host["filer.get"] == 1                 # request spans are bridged
+    assert "volumeServer.get" not in host         # the volume server's enclose
     assert not [n for n in host if "VolumeEcShardsGenerate" in n]  # enclosing
     seen = {name: [] for name in SPLIT}
     for plane in ProfileData.from_file(path).planes:
@@ -177,10 +181,11 @@ def test_profiler_session_holds_one_split_span_per_batch(
 
 
 def test_split_adds_up_to_the_stages_the_benchmark_reads(device_service):
-    """(c) enqueue == compute, device_wait + d2h == readback, and one
-    queue_wait observation per job."""
-    labels = ("queue_wait", "build", "enqueue", "device_wait", "d2h",
-              "deliver", "compute", "readback")
+    """(c) enqueue == compute, device_wait + d2h == readback, one
+    queue_wait and one handback observation per job, one parked per
+    batch."""
+    labels = ("queue_wait", "build", "enqueue", "parked", "device_wait",
+              "d2h", "deliver", "handback", "compute", "readback")
     before = {lb: _stage(lb) for lb in labels}
     jobs = 5
     futs = [device_service.submit_parity(_block(20 + i)) for i in range(jobs)]
@@ -188,10 +193,11 @@ def test_split_adds_up_to_the_stages_the_benchmark_reads(device_service):
         f.result(120)
     d = {lb: (_stage(lb)[0] - before[lb][0], _stage(lb)[1] - before[lb][1])
          for lb in labels}
-    assert d["queue_wait"][1] == jobs
+    assert d["queue_wait"][1] == d["handback"][1] == jobs
     batches = d["build"][1]
     assert 1 <= batches <= jobs
-    for lb in ("enqueue", "device_wait", "d2h", "compute", "readback"):
+    for lb in ("enqueue", "parked", "device_wait", "d2h", "compute",
+               "readback"):
         assert d[lb][1] == batches, lb
     # (a result is handed over inside `deliver`, which closes just after)
     assert batches - 1 <= d["deliver"][1] <= batches
