@@ -80,7 +80,9 @@ class FlightRecorder:
         # one capture at a time; alert storms coalesce into the capture
         # already in flight (its bundle holds the same evidence)
         self._capture_lock = threading.Lock()
-        self._last_capture = 0.0
+        # no capture yet: the monotonic clock starts near zero at boot, so
+        # 0.0 would hold a fresh host's first page back for the cooldown
+        self._last_capture = float("-inf")
         self._seq = 0
         self._seq_lock = threading.Lock()
         # in-memory ring when no debug_dir is configured

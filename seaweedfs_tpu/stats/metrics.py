@@ -609,6 +609,11 @@ EC_DEGRADED_GETS = REGISTRY.counter(
     "seaweedfs_ec_degraded_gets_total",
     "needle reads of an EC volume that needed at least one lost interval",
 )
+EC_DEGRADED_OVERLAPPED = REGISTRY.counter(
+    "seaweedfs_ec_degraded_overlapped_total",
+    "lost intervals decoded by a codec-service job that was in flight "
+    "beside another job of the same needle read",
+)
 EC_DEGRADED_STAGE = REGISTRY.histogram(
     "seaweedfs_ec_degraded_seconds",
     "wall time of one lost interval's two stages",

@@ -12,6 +12,8 @@ from __future__ import annotations
 import mmap
 import os
 import threading
+import time
+from collections import deque
 from dataclasses import dataclass
 from typing import Callable
 
@@ -22,6 +24,7 @@ from ...ops.codec import get_codec
 from ...stats.metrics import (
     EC_DEGRADED_GETS,
     EC_DEGRADED_INTERVALS,
+    EC_DEGRADED_OVERLAPPED,
     EC_DEGRADED_STAGE,
     EC_PARTIAL_FALLBACK,
     EC_PREADV_BATCHES,
@@ -160,8 +163,16 @@ _SF_COALESCED = EC_SINGLEFLIGHT.labels("coalesced")
 _LOST = {o: EC_DEGRADED_INTERVALS.labels(o)
          for o in ("decoded", "cached", "coalesced")}
 _DEGRADED_GETS = EC_DEGRADED_GETS.labels()
+_OVERLAPPED = EC_DEGRADED_OVERLAPPED.labels()
 _STAGE_GATHER = EC_DEGRADED_STAGE.labels("gather")
 _STAGE_DECODE = EC_DEGRADED_STAGE.labels("decode")
+# the most decodes one needle read keeps in flight (`_reconstruct_intervals`);
+# each holds its gathered survivor rows and then its decoded rows.  A volume
+# that still decodes has lost at most four shards, so a needle of up to one
+# stripe row has all its lost intervals in flight at once; a longer needle
+# waits on its oldest decode before it gathers a fifth, and holds at most
+# four intervals' rows, not all of them
+_DECODES_IN_FLIGHT = 4
 # lost intervals the calling thread's reads have needed so far: read_needle
 # tells from it whether its needle needed one
 _TLS = threading.local()
@@ -215,6 +226,27 @@ class _SingleFlight:
         self.result: bytes | None = None
         self.err: Exception | None = None
         self.token: "tuple[int, int] | None" = None
+
+
+class _Led:
+    """One lost interval a needle read leads (`_reconstruct_intervals`):
+    its place in the read, its single-flight call and, once gathered,
+    either the bytes the partial-sum protocol answered or its decode in
+    flight — the service's future, the row it takes, the `perf_counter`
+    at submit, and whether another decode of the read was in flight beside
+    it."""
+
+    __slots__ = ("k", "key", "call", "token", "data", "future", "row", "t0",
+                 "overlapped")
+
+    def __init__(self, k: int, key: tuple, call: _SingleFlight):
+        self.k, self.key, self.call = k, key, call
+        self.token: "tuple[int, int] | None" = None
+        self.data: "bytes | None" = None
+        self.future = None
+        self.row = 0
+        self.t0 = 0.0
+        self.overlapped = False
 
 
 class EcVolume:
@@ -585,8 +617,10 @@ class EcVolume:
         collapses into a single preadv(2) scatter
         (seaweedfs_ec_preadv_batches_total) instead of a pread per
         interval; any batch shortfall — racing truncate, unmount, missing
-        shard — falls back to the per-interval path, which already
-        degrades local -> remote -> reconstruct."""
+        shard — falls back to the per-interval path, local -> remote.
+        What neither holds is reconstructed last: one lost interval as
+        `read_shard_interval` would, several with all their decodes in
+        flight at once (`_reconstruct_intervals`)."""
         located = [
             iv.to_shard_id_and_offset(
                 self.large_block_size, self.small_block_size)
@@ -620,13 +654,35 @@ class EcVolume:
                 EC_PREADV_BATCHES.inc()
                 for k, blob in zip(run, got):
                     parts[k] = blob
+        lost: list[int] = []
         for k, iv in enumerate(intervals):
             if parts[k] is None:
-                parts[k] = self.read_shard_interval(
+                parts[k] = self._read_held(
                     located[k][0], located[k][1], iv.size)
+                if parts[k] is None:
+                    lost.append(k)
+        if len(lost) == 1:
+            k = lost[0]
+            parts[k] = self._reconstruct_interval(
+                located[k][0], located[k][1], intervals[k].size)
+        elif lost:
+            rebuilt = self._reconstruct_intervals(
+                [(*located[k], intervals[k].size) for k in lost])
+            for k, data in zip(lost, rebuilt):
+                parts[k] = data
         return parts
 
     def read_shard_interval(self, shard_id: int, offset: int, length: int) -> bytes:
+        data = self._read_held(shard_id, offset, length)
+        if data is not None:
+            return data
+        # 3. degraded: reconstruct from any DATA_SHARDS other shards
+        return self._reconstruct_interval(shard_id, offset, length)
+
+    def _read_held(self, shard_id: int, offset: int,
+                   length: int) -> "bytes | None":
+        """The interval from a held copy of its shard, or None where it has
+        to be reconstructed."""
         # 1. local shard; a short pread means a racing truncate/re-copy
         # and a closed fd means a racing unmount — both fall through to
         # remote/reconstruct instead of failing the needle read
@@ -644,8 +700,7 @@ class EcVolume:
             data = self.remote_fetch(shard_id, offset, length)
             if data is not None and len(data) == length:
                 return data
-        # 3. degraded: reconstruct from any DATA_SHARDS other shards
-        return self._reconstruct_interval(shard_id, offset, length)
+        return None
 
     def _cache_token(self) -> tuple[int, int]:
         """Invalidation token for reconstructed intervals: any shard
@@ -663,53 +718,159 @@ class EcVolume:
         mount/unmount or delete can never publish a stale interval.
         """
         _TLS.lost = getattr(_TLS, "lost", 0) + 1
-        cache = self._interval_cache
         key = (shard_id, offset, length)
-        if cache is not None:
-            data = cache.get(key, self._cache_token())
-            if data is not None:
-                _LOST["cached"].inc()
-                return data
-        with self._sf_lock:
-            call = self._sf_calls.get(key)
-            leader = call is None
-            if leader:
-                call = _SingleFlight()
-                self._sf_calls[key] = call
+        data = self._cached(key)
+        if data is not None:
+            return data
+        call, leader = self._join(key)
         if not leader:
-            _SF_COALESCED.inc()
-            # generous bound: a wedged leader (remote fetch hang) must not
-            # strand followers forever — they fall back to their own gather
-            if call.done.wait(timeout=60.0):
-                if call.err is not None:
-                    raise call.err
-                # same staleness discipline as the cache: a shard swap or
-                # delete since the leader's capture voids the hand-off
-                if call.token == self._cache_token():
-                    _LOST["coalesced"].inc()
-                    return call.result
-            return self._gather_and_decode(shard_id, offset, length)[0]
-        _SF_LEADER.inc()
+            return self._follow(call, shard_id, offset, length)
         try:
             data, token = self._gather_and_decode(shard_id, offset, length)
-            call.result = data
-            call.token = token
-            if cache is not None:
-                # publish under the journal lock: delete_seq bumps happen
-                # under the same lock, so a tombstone that raced the
-                # gather either changed the token (no publish) or is
-                # ordered after this put and clears via the token check
-                with self._ecj_lock:
-                    if token == self._cache_token():
-                        cache.put(key, data, token)
+            self._publish(key, call, data, token)
             return data
         except Exception as e:
             call.err = e
             raise
         finally:
-            with self._sf_lock:
-                self._sf_calls.pop(key, None)
-            call.done.set()
+            self._release(key, call)
+
+    def _reconstruct_intervals(
+            self, spans: "list[tuple[int, int, int]]") -> list[bytes]:
+        """Reconstruct the lost intervals `(shard_id, offset, length)` of
+        one needle read with their decodes in flight before the read waits
+        on any, so that N lost intervals cost one codec-service round trip
+        and not N one after another.  Each interval keeps
+        `_reconstruct_interval`'s cache, single-flight and accounting.
+
+        Two phases on the calling thread.  (1) In order, each interval is
+        answered by the cache, joined as a single-flight follower, or led:
+        gathered and submitted, its future kept.  The intervals of a needle
+        share a survivor set, so a plan, and meet in one read block.  With
+        `_DECODES_IN_FLIGHT` decodes out, the oldest lead is resolved before
+        the next interval is looked at.  (2) The calls this read leads are
+        resolved in submission order — all of them, also after one failed,
+        so that no follower is left waiting — and only then does it wait on
+        calls other reads lead: two reads that each lead an interval the
+        other follows never wait on each other.  A process without a device
+        service decodes inline, where there is nothing to overlap: one
+        interval after another."""
+        svc = self._decode_service()
+        if svc is None:
+            return [self._reconstruct_interval(*span) for span in spans]
+        out: "list[bytes | None]" = [None] * len(spans)
+        led: "deque[_Led]" = deque()  # this read's unresolved leads, in order
+        follows: "list[tuple[int, _SingleFlight]]" = []
+        failure: "Exception | None" = None
+        try:
+            for k, key in enumerate(spans):
+                _TLS.lost = getattr(_TLS, "lost", 0) + 1
+                out[k] = self._cached(key)
+                if out[k] is not None:
+                    continue
+                while sum(j.future is not None
+                          for j in led) >= _DECODES_IN_FLIGHT:
+                    self._settle(led.popleft(), out)
+                call, leader = self._join(key)
+                if not leader:
+                    follows.append((k, call))
+                    continue
+                job = _Led(k, key, call)
+                led.append(job)
+                job.token = self._cache_token()
+                shards = self._gather_staged(*key)
+                if isinstance(shards, bytes):  # the partial-sum answer
+                    job.data = shards
+                    continue
+                beside = [j for j in led if j.future is not None]
+                job.t0 = time.perf_counter()
+                job.future, job.row = self._submit_read(svc, shards, key[0])
+                for j in beside:
+                    j.overlapped = job.overlapped = True
+        except Exception as e:
+            failure = e
+        finally:
+            while led:
+                try:
+                    self._settle(led.popleft(), out, failure)
+                except Exception as e:
+                    failure = failure or e
+        if failure is not None:
+            raise failure
+        for k, call in follows:
+            out[k] = self._follow(call, *spans[k])
+        return out
+
+    def _settle(self, job: _Led, out: list,
+                failure: "Exception | None" = None) -> None:
+        """Resolve a lead of `_reconstruct_intervals`: its bytes published
+        to its call and the interval cache and put in `out`, or what failed
+        — its decode, or `failure` where its gather or submit never ended —
+        set on its call and raised; the call is released either way."""
+        try:
+            if job.future is not None:
+                job.data = self._collect(job)
+            if job.data is None:
+                raise failure
+            self._publish(job.key, job.call, job.data, job.token)
+            out[job.k] = job.data
+        except Exception as e:
+            job.call.err = e
+            raise
+        finally:
+            self._release(job.key, job.call)
+
+    def _cached(self, key: tuple) -> "bytes | None":
+        cache = self._interval_cache
+        if cache is None:
+            return None
+        data = cache.get(key, self._cache_token())
+        if data is not None:
+            _LOST["cached"].inc()
+        return data
+
+    def _join(self, key: tuple) -> "tuple[_SingleFlight, bool]":
+        """-> (the single-flight call of `key`, whether this read leads it)."""
+        with self._sf_lock:
+            call = self._sf_calls.get(key)
+            leader = call is None
+            if leader:
+                call = self._sf_calls[key] = _SingleFlight()
+        (_SF_LEADER if leader else _SF_COALESCED).inc()
+        return call, leader
+
+    def _follow(self, call: _SingleFlight, shard_id: int, offset: int,
+                length: int) -> bytes:
+        # generous bound: a wedged leader (remote fetch hang) must not
+        # strand followers forever — they fall back to their own gather
+        if call.done.wait(timeout=60.0):
+            if call.err is not None:
+                raise call.err
+            # same staleness discipline as the cache: a shard swap or
+            # delete since the leader's capture voids the hand-off
+            if call.token == self._cache_token():
+                _LOST["coalesced"].inc()
+                return call.result
+        return self._gather_and_decode(shard_id, offset, length)[0]
+
+    def _publish(self, key: tuple, call: _SingleFlight, data: bytes,
+                 token: "tuple[int, int]") -> None:
+        call.result = data
+        call.token = token
+        cache = self._interval_cache
+        if cache is not None:
+            # publish under the journal lock: delete_seq bumps happen
+            # under the same lock, so a tombstone that raced the
+            # gather either changed the token (no publish) or is
+            # ordered after this put and clears via the token check
+            with self._ecj_lock:
+                if token == self._cache_token():
+                    cache.put(key, data, token)
+
+    def _release(self, key: tuple, call: _SingleFlight) -> None:
+        with self._sf_lock:
+            self._sf_calls.pop(key, None)
+        call.done.set()
 
     def _decode_service(self) -> "codec_service.CodecService | None":
         """The codec service lost intervals are decoded through: the one
@@ -766,33 +927,70 @@ class EcVolume:
         backend).
         """
         token = self._cache_token()
-        tags = {"volume": self.volume_id, "shard": shard_id, "bytes": length}
-        with trace.stage("ec.degraded.gather", _STAGE_GATHER, **tags):
-            shards = self._gather(shard_id, offset, length)
+        shards = self._gather_staged(shard_id, offset, length)
         if isinstance(shards, bytes):  # the partial-sum protocol's answer
             return shards, token
         # the decode's round trip is a wait on the codec service's thread:
         # observed and in the ring, but not on the profiler's clock
         with trace.stage("ec.degraded.decode", _STAGE_DECODE,
-                         annotated=False, **tags):
+                         annotated=False, **self._tags(shard_id, length)):
             svc = self._decode_service()
             if svc is not None:
-                # concurrent reads against the same survivor set (the
-                # same plan) batch into ONE call on the service's
-                # scheduler.  Same plan cache and same GF program as the
-                # rebuild -> byte-identical.
-                present = [i for i, s in enumerate(shards) if s is not None]
-                wanted = self._lost_rows(shard_id)
-                rows = gf256.decode_plan_for(
-                    np.asarray(self.codec.matrix), DATA_SHARDS,
-                    present, wanted)
-                data = svc.submit_apply(
-                    rows, [shards[i] for i in present[:DATA_SHARDS]],
-                    job_class="read").result()[wanted.index(shard_id)]
+                future, row = self._submit_read(svc, shards, shard_id)
+                data = future.result()[row]
             else:
                 data = self.codec.reconstruct_one(shards, shard_id)
+        return self._decoded(data), token
+
+    @staticmethod
+    def _decoded(data) -> bytes:
+        """The decoded row as bytes, counted as a decoded lost interval."""
         _LOST["decoded"].inc()
-        return np.asarray(data, dtype=np.uint8).tobytes(), token
+        return np.asarray(data, dtype=np.uint8).tobytes()
+
+    def _tags(self, shard_id: int, length: int) -> dict:
+        return {"volume": self.volume_id, "shard": shard_id, "bytes": length}
+
+    def _gather_staged(self, shard_id: int, offset: int, length: int):
+        """`_gather` inside its stage, `ec.degraded.gather`."""
+        with trace.stage("ec.degraded.gather", _STAGE_GATHER,
+                         **self._tags(shard_id, length)):
+            return self._gather(shard_id, offset, length)
+
+    def _submit_read(self, svc: "codec_service.CodecService", shards: list,
+                     shard_id: int) -> "tuple[codec_service.CodecFuture, int]":
+        """Submit the decode of `shard_id` from the gathered `shards` as ONE
+        `read` job of the service -> (its future, the row of its result
+        that is `shard_id`).  Concurrent reads against the same survivor set
+        (the same plan) batch into one call on the service's scheduler;
+        the same plan cache and GF program as the rebuild make it
+        byte-identical."""
+        present = [i for i, s in enumerate(shards) if s is not None]
+        wanted = self._lost_rows(shard_id)
+        rows = gf256.decode_plan_for(
+            np.asarray(self.codec.matrix), DATA_SHARDS, present, wanted)
+        future = svc.submit_apply(
+            rows, [shards[i] for i in present[:DATA_SHARDS]],
+            job_class="read")
+        return future, wanted.index(shard_id)
+
+    def _collect(self, job: _Led) -> bytes:
+        """The result of a led interval's decode in flight, accounted as
+        `_gather_and_decode` accounts its own: the decode stage from submit
+        to the result in hand."""
+        err = None
+        try:
+            data = job.future.result()[job.row]
+        except Exception as e:
+            err = e
+            raise
+        finally:
+            trace.stage_ended("ec.degraded.decode", job.t0, _STAGE_DECODE,
+                              error=err, **self._tags(job.key[0], job.key[2]))
+        data = self._decoded(data)
+        if job.overlapped:
+            _OVERLAPPED.inc()
+        return data
 
     def _gather(self, shard_id: int, offset: int, length: int):
         """-> the 14 shard slots with at least DATA_SHARDS survivor

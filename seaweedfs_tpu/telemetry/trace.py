@@ -288,6 +288,26 @@ def stage(name: str, hist_child=None, annotated: bool = True, **attrs):
             hist_child.observe(st.seconds)
 
 
+def stage_ended(name: str, t0: float, hist_child=None,
+                error: "BaseException | None" = None, **attrs) -> float:
+    """A `stage` that began at the `perf_counter()` reading `t0` and ends
+    now, for a wait that does not nest in its thread's other stages (one
+    of several jobs a thread has in flight at once): observed into
+    `hist_child` and recorded in the ring inside a request trace, as
+    `stage` does; never on the profiler's clock.  -> elapsed seconds."""
+    seconds = time.perf_counter() - t0
+    if hist_child is not None:
+        hist_child.observe(seconds)
+    ctx = current_context()
+    if ctx is not None:
+        TRACER.record(Span(
+            trace_id=ctx[0], span_id=_rand_hex(8), parent_id=ctx[1],
+            name=name, start=time.time() - seconds, duration=seconds,
+            attrs=dict(attrs),
+            status="ok" if error is None else f"error: {type(error).__name__}"))
+    return seconds
+
+
 # -- W3C traceparent ---------------------------------------------------------
 
 TRACEPARENT = "traceparent"

@@ -319,6 +319,26 @@ class _ZipfLoad:
 
 
 @pytest.mark.chaos
+def test_a_host_booted_within_the_cooldown_captures_its_first_page(
+        monkeypatch):
+    """The monotonic clock starts near zero at boot: the first firing
+    transition captures however young the host is; the next one within
+    the cooldown does not."""
+    from seaweedfs_tpu.master import flight
+
+    fr = flight.FlightRecorder(master=None, cooldown_s=3600.0)
+    started = []
+    monkeypatch.setattr(flight.time, "monotonic", lambda: 5.0)
+    monkeypatch.setattr(fr, "_capture_safe",
+                        lambda trigger, alert: started.append(trigger))
+    fr.sink({"state": "firing", "slo": "availability"})
+    _wait(lambda: started == ["alert"], 5, "first capture")
+    fr._last_capture = 5.0  # what the capture stamps
+    fr.sink({"state": "firing", "slo": "availability"})
+    time.sleep(0.2)
+    assert started == ["alert"]
+
+
 def test_chaos_page_auto_captures_bundle(tmp_path, monkeypatch):
     """Kill a volume-holding node under canary + zipf-hot client load:
     the availability page fires, the flight recorder auto-captures a
